@@ -381,6 +381,9 @@ def chaos_shard_child(n_shards: int = 2, steps: int = 6) -> None:
 def _leg_shard_loss(verbose: bool) -> Dict:
     n_shards = 2
     env = dict(os.environ)
+    # a CPU simulation by design: fake host devices, never the chip the
+    # parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={n_shards}"
     env["PYTHONPATH"] = f"{REPO}:{os.path.join(REPO, 'src')}"

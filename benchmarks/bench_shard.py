@@ -174,6 +174,9 @@ def child_main(n_shards: int, n_groups: int, steps: int,
 def _run_child(n_shards: int, n_groups: int, steps: int,
                timeout: int = 560) -> dict:
     env = dict(os.environ)
+    # a CPU simulation by design: fake host devices, never the chip the
+    # parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={n_shards}"
     env["PYTHONPATH"] = f"{REPO}:{os.path.join(REPO, 'src')}"

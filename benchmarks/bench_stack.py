@@ -1,5 +1,5 @@
 """One-launch fleet backbone benchmark: fused megakernel vs per-layer
-chain, cross-group super-launch dispatch ceiling, coalesced rim halos,
+chain, cross-group super-launch dispatch ceiling, halo fetch structure,
 and straggler fold-in.
 
 Four panels:
@@ -11,8 +11,8 @@ Four panels:
      the N-1 ``roi_conv_packed`` dispatches it replaces, and the whole
      super-launch step vs the per-group chain loop (min over reps,
      post-warmup).
-  3. rim DMA structure — halo loads per tile per layer: 4 contiguous rim
-     loads in the fused path vs 8 masked strip/corner loads in the chain.
+  3. halo fetch structure — per tile per layer both paths fetch 8 halo
+     edges; the fused path DMAs a block's centers in one copy.
   4. straggler fold — a scripted deadline former: late segments ride the
      next release's packed launch; reclaimed launch chains counted.
 
@@ -127,22 +127,26 @@ def run(verbose: bool = True, quick: bool = False):
     step_wall, per_group_wall = _time_min_interleaved(
         [superlaunch_step, per_group_chain], reps)
 
-    # --- panel 3: rim DMA structure ----------------------------------------
-    # per tile-block per packed layer: the chain issues 8 masked strip/
-    # corner halo DMAs per TILE; the fused conv phase issues 4 contiguous
-    # rim loads per BLOCK.  Counted from the kernel sources so a
-    # regression of the fetch structure changes the panel (and trips the
-    # CI assertions) instead of silently reporting stale constants.
+    # --- panel 3: halo fetch structure -------------------------------------
+    # per packed layer the chain loads its tile plus 8 masked halo strips
+    # per TILE; the megakernel DMAs each block's centers in ONE copy and
+    # each tile's 8 neighbor edges from the zero-row-padded activations.
+    # Counted from the kernel sources so a change of the fetch structure
+    # changes the panel instead of silently reporting stale constants.
     import inspect
     from repro.kernels import roi_conv as roi_conv_mod
-    conv_src = inspect.getsource(roi_conv_mod._roi_conv_stack_kernel)
-    rim_loads = conv_src.count("pl.load(srcs[")
-    chain_src = inspect.getsource(roi_conv_mod._roi_conv_packed_kernel)
-    chain_loads = chain_src.count("_halo_strip(")
+    fetch_src = inspect.getsource(roi_conv_mod._window_fetches)
+    assert "for k in range(8)" in fetch_src
+    stack_halo = len(roi_conv_mod.NEIGHBOR_OFFSETS)
+    chain_src = inspect.getsource(roi_conv_mod.roi_conv_packed)
+    chain_halo = chain_src.count("strip(") - 1     # minus the def
     n_tiles = int(idx.shape[0])
-    tb = max(1, min(128, n_tiles))         # roi_conv_stack's default block
-    halo_dmas_fused = rim_loads * -(-n_tiles // tb) * max(n_layers - 1, 0)
-    halo_dmas_chain = chain_loads * n_tiles * max(n_layers - 1, 0)
+    tb = max(1, min(det.block, n_tiles))   # the detector's stack block
+    layers = max(n_layers - 1, 0)
+    halo_dmas_fused = stack_halo * n_tiles * layers
+    halo_dmas_chain = chain_halo * n_tiles * layers
+    center_dmas_fused = -(-n_tiles // tb) * layers
+    center_loads_chain = n_tiles * layers
 
     # --- panel 4: straggler fold-in ----------------------------------------
     former = DeadlineGroupFormer(det, expected_cams=list(range(3)),
@@ -174,10 +178,12 @@ def run(verbose: bool = True, quick: bool = False):
         "chain_kernel_wall_s": chain_wall,
         "superlaunch_step_wall_s": step_wall,
         "per_group_chain_wall_s": per_group_wall,
-        "rim_halo_loads_per_tile": rim_loads,
-        "chain_halo_loads_per_tile": chain_loads,
+        "stack_halo_dmas_per_tile": stack_halo,
+        "chain_halo_loads_per_tile": chain_halo,
         "halo_dmas_fused": halo_dmas_fused,
         "halo_dmas_chain": halo_dmas_chain,
+        "center_dmas_fused": center_dmas_fused,
+        "center_loads_chain": center_loads_chain,
         "fold_reclaimed_launches": former.reclaimed_launches,
         "fold_folded_frames": folded_frames,
         "fold_total_launches": int(fold_launches),
@@ -191,8 +197,9 @@ def run(verbose: bool = True, quick: bool = False):
              f"{chain_wall:.4f}"],
             ["full step wall (s)", f"{step_wall:.4f}",
              f"{per_group_wall:.4f}"],
-            ["halo loads (blk vs tile)", str(rim_loads),
-             str(chain_loads)],
+            ["halo fetches per tile", str(stack_halo), str(chain_halo)],
+            ["center fetches", str(center_dmas_fused),
+             str(center_loads_chain)],
         ]
         print(f"== one-launch fleet backbone: {K} groups x {cams} cams, "
               f"{n_layers} conv layers, {n_tiles} tiles ==")

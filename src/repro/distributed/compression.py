@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def quantize_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Per-row (last-axis) absmax scaling: tensor-level scales are too
@@ -60,7 +58,7 @@ def int8_allreduce_mean(grads, mesh: Mesh, param_specs):
     def body(g):
         return jax.tree.map(lambda x: _allreduce_one(x, axes), g)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_specs,), out_specs=param_specs,
         check_vma=False)(grads)

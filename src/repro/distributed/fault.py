@@ -17,6 +17,8 @@ from typing import List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.launch.mesh import make_mesh
+
 
 @dataclass
 class ElasticMesh:
@@ -47,7 +49,7 @@ class ElasticMesh:
         devices = devices if devices is not None else jax.devices()
         shape, axes = self.shape_for(len(devices))
         n = int(np.prod(shape))
-        return jax.make_mesh(shape, axes, devices=devices[:n])
+        return make_mesh(shape, axes, devices=devices[:n])
 
 
 @dataclass

@@ -6,7 +6,7 @@ target ever crosses a group boundary, so partitioning groups over a 1-D
 ``jax.sharding.Mesh`` (``launch.mesh.make_fleet_mesh``) needs ZERO
 cross-device collectives on the hot path.  ``ShardedSuperlaunch`` is the
 fleet runtime's super-launch (``RoIDetector.superlaunch_forward_reuse``)
-rebuilt as ONE ``compat.shard_map`` SPMD program over stacked per-shard
+rebuilt as ONE ``jax.shard_map`` SPMD program over stacked per-shard
 state:
 
 * **Placement-free tables + a shard plan.**  ``ops.superlaunch_tables``
@@ -35,7 +35,7 @@ state:
   packed activations, the persistent HEAD-MAP CANVAS ((S, F_max + 1, H,
   W, A) — warm steps scatter only changed tiles' head rows into it,
   padding/margin rows land on the sacrificial camera plane) and the
-  canvas-resident gate references ((S, F_max + 1, H + 2, W + 2, 3) with
+  canvas-resident gate references ((S, F_max + 1, H + 2, W', 3) with
   a host-side (S, n_max) refresh-epoch table) live in a
   ``ShardedActivationCache``, shard axis over the mesh.  A drift
   re-solve invalidates ONLY the owning shard
@@ -66,9 +66,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.distributed.shardings import fleet_state_sharding
 from repro.kernels import ops as kops
+from repro.kernels.blocking import pad_frames, window_width
 from repro.kernels.roi_conv import (roi_conv_entry as _raw_entry,
                                     roi_conv_stack as _raw_stack)
 from repro.kernels.sbnet import (sbnet_scatter_changed as
@@ -169,6 +169,8 @@ class ShardedSuperlaunch:
                             for g in gs)
         self.canvas_w = max(g.shape[1] * t for gs in self.grids.values()
                             for g in gs)
+        # width of the padded frames the gate reads (``pad_frames``)
+        self.padded_w = self.canvas_w + window_width(t) - t
         self._build_tables()
         self._fns: Dict = {}          # jitted shard_map programs
 
@@ -272,9 +274,9 @@ class ShardedSuperlaunch:
     # -- step building blocks ---------------------------------------------
     def _shard_map(self, f, n_in: int, n_out: int, donate=()):
         spec = jax.sharding.PartitionSpec(FLEET_AXIS)
-        sm = compat.shard_map(f, mesh=self.mesh, in_specs=(spec,) * n_in,
-                              out_specs=(spec,) * n_out if n_out > 1
-                              else spec)
+        sm = jax.shard_map(f, mesh=self.mesh, in_specs=(spec,) * n_in,
+                           out_specs=(spec,) * n_out if n_out > 1 else spec,
+                           check_vma=False)
         return jax.jit(sm, donate_argnums=donate)
 
     def _ingest(self, frames: Dict[int, List]) -> jax.Array:
@@ -300,14 +302,14 @@ class ShardedSuperlaunch:
             det, t = self.det, self.det.cfg.tile
 
             def local(x, ref, idx):
-                xp = jnp.pad(x[0], ((0, 0), (1, 1), (1, 1), (0, 0)))
+                xp = pad_frames(x[0], t)
                 # canvas-resident references: the comparison side is the
                 # shard's padded reference canvas, addressed through the
                 # same tile rows — no packed window duplication, stats
                 # rows are the only output
                 stats = _raw_gate_canvas(
                     xp, ref[0], idx[0], t, t, 8.0, COEF_BITS, RUN_BITS,
-                    block=det.block, interpret=kops.INTERPRET)
+                    block=det.block, interpret=kops.interpret_mode())
                 return stats[None]
 
             self._fns[key] = self._shard_map(local, 3, 1)
@@ -320,12 +322,12 @@ class ShardedSuperlaunch:
             w0, ws, head = det.weights[0], det.weights[1:], det.head
 
             def local(x, cidx, cnbr, upd, sidx, wipe, packed, canvas):
-                p = _raw_entry(x[0], w0, cidx[0], t, t,
-                               block=det.chain_block,
-                               interpret=kops.INTERPRET)
+                interpret = kops.interpret_mode()
+                p = _raw_entry(x[0], w0, cidx[0], t, t, block=det.block,
+                               interpret=interpret)
                 if ws:
                     p = _raw_stack(p, tuple(ws), cnbr[0], block=det.block,
-                                   interpret=kops.INTERPRET)
+                                   interpret=interpret)
                 # only changed-OUTPUT rows graduate; margin and padding
                 # rows carry target n_max and drop out of bounds
                 new_packed = packed[0].at[upd[0]].set(p, mode="drop")
@@ -336,13 +338,13 @@ class ShardedSuperlaunch:
                 # camera plane.  A cold shard's plane is wiped to zeros
                 # first (shard-exact canvas invalidation, in-program)
                 k, C = p.shape[0], p.shape[-1]
-                ph = (p.reshape(k * t * t, C) @ head).reshape(
+                ph = jnp.dot(p.reshape(k * t * t, C), head,
+                             precision=jax.lax.Precision.HIGHEST).reshape(
                     k, t, t, head.shape[-1])
                 base = jnp.where(wipe[0][0], jnp.zeros_like(canvas[0]),
                                  canvas[0])
-                new_canvas = _raw_scatter_changed(
-                    ph, sidx[0], base, block=det.chain_block,
-                    interpret=kops.INTERPRET)
+                new_canvas = _raw_scatter_changed(ph, sidx[0], base,
+                                                  interpret=interpret)
                 return new_packed[None], new_canvas[None]
 
             # donate the cache's packed buffer (argument 6): the update
@@ -357,10 +359,10 @@ class ShardedSuperlaunch:
     def _refadv_fn(self):
         key = ("refadv",)
         if key not in self._fns:
+            t = self.det.cfg.tile
 
             def local(ref, x, mask):
-                xp = jnp.pad(x[0], ((0, 0), (1, 1), (1, 1), (0, 0)))
-                return jnp.where(mask[0], xp, ref[0])[None]
+                return jnp.where(mask[0], pad_frames(x[0], t), ref[0])[None]
 
             # pure jnp reference advancement (not a counted kernel
             # dispatch, like ops.gather_windows): advanced rows' full
@@ -382,7 +384,7 @@ class ShardedSuperlaunch:
             self.sharding)
         cache.ref_canvas = jax.device_put(
             jnp.zeros((S, self.F_max + 1, self.canvas_h + 2,
-                       self.canvas_w + 2, 3), jnp.float32), self.sharding)
+                       self.padded_w, 3), jnp.float32), self.sharding)
         cache.canvas = jax.device_put(
             jnp.zeros((S, self.F_max + 1, self.canvas_h, self.canvas_w,
                        a), jnp.float32), self.sharding)
@@ -527,13 +529,13 @@ class ShardedSuperlaunch:
 
     def _adv_canvas_mask(self, adv: np.ndarray) -> np.ndarray:
         """(S, n_max) advance-row mask -> bool (S, F_max + 1, H + 2,
-        W + 2, 1) canvas mask over the advanced rows' haloed window
+        W', 1) canvas mask over the advanced rows' haloed window
         regions (host-built from the static tables; broadcasts over
         channels)."""
         t = self.det.cfg.tile
         S = self.plan.n_shards
         m = np.zeros((S, self.F_max + 1, self.canvas_h + 2,
-                      self.canvas_w + 2, 1), bool)
+                      self.padded_w, 1), bool)
         for s in range(S):
             for cam, ty, tx in self._idx_np[s][adv[s, :self._n_s[s]]]:
                 m[s, cam, ty * t:ty * t + t + 2,

@@ -2,8 +2,10 @@
 
 Handles the framework-facing conveniences: mask -> index-list conversion,
 neighbor-table construction for the packed-resident conv chain, padding to
-hardware-aligned block counts, batching (vmap), and the interpret switch
-(True on CPU; on a real TPU deployment set REPRO_PALLAS_INTERPRET=0).
+hardware-aligned block counts, batching (vmap), and how a kernel runs:
+compiled by Mosaic on a TPU, through the Pallas interpreter when JAX's
+default backend is the CPU (the test suite).  The choice is made per call
+from the backend, so importing this module initialises no backend.
 
 Every public wrapper bumps ``KERNEL_COUNTS[name]`` *outside* the jit
 boundary, so tests and benchmarks can assert structural properties of the
@@ -16,7 +18,6 @@ import collections
 import contextlib
 import contextvars
 import functools
-import os
 import threading
 
 import jax
@@ -24,10 +25,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
+from repro.kernels.blocking import (LANES, VMEM_LIMIT_BYTES, pad_frames,
+                                    round_up, window_width)
 from repro.obs import metrics as obs_metrics
 from repro.kernels.roi_attention import (PAD_POS, block_min_positions,
                                          roi_attention as _roi_attn)
-from repro.kernels.roi_conv import (NEIGHBOR_OFFSETS, roi_conv as _roi_conv,
+from repro.kernels.roi_conv import (MAX_WINDOWS_PER_STEP, NEIGHBOR_OFFSETS,
+                                    roi_conv as _roi_conv,
                                     roi_conv_entry as _roi_conv_entry,
                                     roi_conv_fleet as _roi_conv_fleet,
                                     roi_conv_packed as _roi_conv_packed,
@@ -44,7 +48,13 @@ from repro.kernels.tile_delta import (COEF_BITS, GATE_BODY_BYTES,
                                       _tile_delta_gate_canvas,
                                       tile_delta_halo as _tile_delta_halo)
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def interpret_mode() -> bool:
+    """Run Pallas kernels through the interpreter?  Only when JAX's
+    default backend is the CPU; on a TPU every kernel is compiled by
+    Mosaic.  Asked per call, never at import."""
+    return jax.default_backend() == "cpu"
+
 
 # kernel-dispatch counter: wrapper name -> number of pallas_call launches
 # issued from Python.  Process-lifetime totals; each launch is counted once
@@ -334,32 +344,37 @@ def compact_tables(idx: np.ndarray, nbr: np.ndarray, keep: np.ndarray
 
 
 def choose_block(th: int, tw: int, c: int, n_layers: int,
-                 vmem_bytes: int = 16 * 2 ** 20,
+                 vmem_bytes: int = VMEM_LIMIT_BYTES * 3 // 4,
                  dtype_bytes: int = 4) -> int:
-    """Size the entry/stack/scatter ``block`` (tiles per grid step) from
-    a VMEM budget instead of the hardcoded interpret-mode 128.
+    """Size the entry/stack/gate ``block`` (tiles per grid step) from a
+    VMEM budget.
 
-    Per resident tile the stack kernel's conv phase holds the assembled
-    (th+2, tw+2, C) window, the center in/out activations, the four rim
-    strips it reads and the four edge strips it stores; the weight plane
-    is (3, 3, C, C) ×2 for the pipeline's layer-(l+1) prefetch (layer
-    count does not change residency — weights are block-indexed by
-    layer — but a 1-layer net has no stack weights at all).  The block
-    is the largest power of two whose double-buffered footprint fits,
-    floored at 1 so degenerate budgets still launch."""
-    c = max(int(c), 1)
-    weights = (2 if n_layers > 1 else 1) * 9 * c * c * dtype_bytes
-    per_tile = ((th + 2) * (tw + 2)          # assembled haloed window
-                + 2 * th * tw                # center in + out
-                + 2 * (tw + 2) + 2 * th      # rim strips read
-                + 2 * tw + 2 * th)           # edge strips stored
-    per_tile *= c * dtype_bytes
+    Every buffer occupies whole (8, 128) vector tiles, so the channel
+    dim counts as ``round_up(c, 128)`` lanes and the column dim is
+    rounded up to 8 sublanes.  Per resident tile a kernel holds its
+    haloed window ((th+2) x ``window_width(tw)``) plus about 17 tile-
+    sized buffers: the nine shifted tap patches, their products, the
+    accumulator and the double-buffered output block.  That model
+    matches what Mosaic allocates for the entry and stack kernels at
+    the default widths (about 2.4 MB per 16x16 tile, compiled for a
+    v5e).  The weight plane is (3, 3, C, C) x2 for the pipeline's
+    layer-(l+1) prefetch (a 1-layer net has no stack weights).  The
+    block is the largest power of two that fits, at most
+    ``MAX_WINDOWS_PER_STEP`` (the entry and the gate fetch one pipelined
+    window per tile), floored at 1 so degenerate budgets still
+    launch."""
+    lanes = round_up(max(int(c), 1), LANES)
+    window = (th + 2) * window_width(tw) * lanes
+    tile = th * round_up(tw, 8) * lanes
+    per_tile = (window + 17 * tile) * dtype_bytes
+    weights = ((2 if n_layers > 1 else 1) * 9 * round_up(max(int(c), 1), 8)
+               * lanes * dtype_bytes)
     budget = int(vmem_bytes) - weights
-    if budget < 2 * per_tile:
+    if budget < per_tile:
         return 1
-    tb = budget // (2 * per_tile)            # double-buffered stages
+    tb = budget // per_tile
     block = 1
-    while block * 2 <= tb and block < 1024:
+    while block * 2 <= tb and block < MAX_WINDOWS_PER_STEP:
         block *= 2
     return block
 
@@ -369,155 +384,135 @@ def choose_block(th: int, tw: int, c: int, n_layers: int,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("th", "tw", "interpret"))
-def _sbnet_gather_jit(x, idx, th, tw, interpret=INTERPRET):
+def _sbnet_gather_jit(x, idx, th, tw, interpret):
     return _gather(x, idx, th, tw, interpret=interpret)
 
 
-def sbnet_gather(x: jax.Array, idx: jax.Array, th: int, tw: int,
-                 interpret: bool = INTERPRET) -> jax.Array:
+def sbnet_gather(x: jax.Array, idx: jax.Array, th: int,
+                 tw: int) -> jax.Array:
     """(H, W, C) + (n, 2) tile coords -> packed (n, th, tw, C)."""
     record_dispatch("sbnet_gather")
-    return _sbnet_gather_jit(x, idx, th, tw, interpret)
+    return _sbnet_gather_jit(x, idx, th, tw, interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _sbnet_scatter_jit(packed, idx, base, interpret=INTERPRET):
+def _sbnet_scatter_jit(packed, idx, base, interpret):
     return _scatter(packed, idx, base, interpret=interpret)
 
 
-def sbnet_scatter(packed: jax.Array, idx: jax.Array, base: jax.Array,
-                  interpret: bool = INTERPRET) -> jax.Array:
+def sbnet_scatter(packed: jax.Array, idx: jax.Array,
+                  base: jax.Array) -> jax.Array:
     """Packed tiles -> full map, untouched regions keep ``base`` values."""
     record_dispatch("sbnet_scatter")
-    return _sbnet_scatter_jit(packed, idx, base, interpret)
+    return _sbnet_scatter_jit(packed, idx, base, interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("th", "tw", "interpret"))
-def _roi_conv_jit(x, w, idx, th, tw, interpret=INTERPRET):
+def _roi_conv_jit(x, w, idx, th, tw, interpret):
     return _roi_conv(x, w, idx, th, tw, interpret=interpret)
 
 
-def roi_conv(x: jax.Array, w: jax.Array, idx: jax.Array, th: int, tw: int,
-             interpret: bool = INTERPRET) -> jax.Array:
+def roi_conv(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
+             tw: int) -> jax.Array:
     """Fused gather+3x3 conv on active tiles -> packed (n, th, tw, Cout)."""
     record_dispatch("roi_conv")
-    return _roi_conv_jit(x, w, idx, th, tw, interpret)
+    return _roi_conv_jit(x, w, idx, th, tw, interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _roi_conv_packed_jit(packed, w, nbr, interpret=INTERPRET):
+def _roi_conv_packed_jit(packed, w, nbr, interpret):
     return _roi_conv_packed(packed, w, nbr, interpret=interpret)
 
 
-def roi_conv_packed(packed: jax.Array, w: jax.Array, nbr: jax.Array,
-                    interpret: bool = INTERPRET) -> jax.Array:
+def roi_conv_packed(packed: jax.Array, w: jax.Array,
+                    nbr: jax.Array) -> jax.Array:
     """Packed-resident conv layer: (n, th, tw, Cin) -> (n, th, tw, Cout)
     with halos pulled from neighbor tiles (``neighbor_table``); no
     full-frame materialization between layers."""
     record_dispatch("roi_conv_packed")
-    return _roi_conv_packed_jit(packed, w, nbr, interpret)
+    return _roi_conv_packed_jit(packed, w, nbr, interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("th", "tw", "interpret"))
-def _roi_conv_fleet_jit(x, w, idx, th, tw, interpret=INTERPRET):
+def _roi_conv_fleet_jit(x, w, idx, th, tw, interpret):
     return _roi_conv_fleet(x, w, idx, th, tw, interpret=interpret)
 
 
 def roi_conv_fleet(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
-                   tw: int, interpret: bool = INTERPRET) -> jax.Array:
+                   tw: int) -> jax.Array:
     """Cross-camera fused gather+conv: (C, H, W, Cin) stacked frames +
     (n, 3) (cam, ty, tx) coords -> packed (n, th, tw, Cout) for the whole
     camera group in ONE launch (see ``fleet_indices``)."""
     record_dispatch("roi_conv_fleet")
-    return _roi_conv_fleet_jit(x, w, idx, th, tw, interpret)
+    return _roi_conv_fleet_jit(x, w, idx, th, tw, interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("th", "tw", "block",
                                              "interpret"))
-def _roi_conv_entry_jit(x, w, idx, th, tw, block=1, interpret=INTERPRET):
+def _roi_conv_entry_jit(x, w, idx, th, tw, block, interpret):
     return _roi_conv_entry(x, w, idx, th, tw, block=block,
                            interpret=interpret)
 
 
 def roi_conv_entry(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
-                   tw: int, block: int = 1,
-                   interpret: bool = INTERPRET) -> jax.Array:
+                   tw: int, block: int = 1) -> jax.Array:
     """Fleet-flat fused gather+conv+relu over any number of cameras (and
     groups): (C, H, W, Cin) stacked frames + (n, 3) (flat_cam, ty, tx)
     coords -> relu'd packed (n, th, tw, Cout) — the fused backbone's
-    entry layer, feeding ``roi_conv_stack``.  ``block`` > 1 blocks the
-    tile walk (``choose_block`` sizes it against VMEM): ``block`` haloed
-    windows gathered per grid step, one GEMM per tap per block,
-    bit-identical to the per-tile walk.  An empty compute set is NOT a
-    dispatch: zero tiles return an empty packed tensor with no launch
-    formed and no counter bump."""
+    entry layer, feeding ``roi_conv_stack``.  ``block`` tiles share a
+    grid step (``choose_block`` sizes it against VMEM), one GEMM per tap
+    per block, bit-identical to the per-tile walk.  An empty compute set
+    is NOT a dispatch: zero tiles return an empty packed tensor with no
+    launch formed and no counter bump."""
     if idx.shape[0] == 0:
         return jnp.zeros((0, th, tw, w.shape[-1]), x.dtype)
     record_dispatch("roi_conv_entry")
-    return _roi_conv_entry_jit(x, w, idx, th, tw, int(block), interpret)
+    return _roi_conv_entry_jit(x, w, idx, th, tw, int(block),
+                               interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _roi_conv_stack_jit(packed, ws, nbr, block, interpret=INTERPRET):
+def _roi_conv_stack_jit(packed, ws, nbr, block, interpret):
     return _roi_conv_stack(packed, ws, nbr, block=block,
                            interpret=interpret)
 
 
 def roi_conv_stack(packed: jax.Array, ws, nbr: jax.Array,
-                   block: int = 128,
-                   interpret: bool = INTERPRET) -> jax.Array:
+                   block: int = MAX_WINDOWS_PER_STEP) -> jax.Array:
     """The fused layer-stack megakernel: the whole packed conv chain
-    (conv + relu per layer, double-buffered activations + coalesced rim
-    halos, weight prefetch for layer l+1 during layer l) in ONE dispatch
-    — bit-identical to N-1 ``roi_conv_packed`` + relu rounds."""
+    (conv + relu per layer, halos DMA'd from the neighbor rows, weight
+    prefetch for layer l+1 during layer l) in ONE dispatch —
+    bit-identical to N-1 ``roi_conv_packed`` + relu rounds."""
     record_dispatch("roi_conv_stack")
     return _roi_conv_stack_jit(packed, tuple(ws), nbr, int(block),
-                               interpret)
+                               interpret_mode())
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _sbnet_scatter_fleet_jit(packed, idx, base, block=1,
-                             interpret=INTERPRET):
-    return _scatter_fleet(packed, idx, base, block=block,
-                          interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _sbnet_scatter_fleet_jit(packed, idx, base, interpret):
+    return _scatter_fleet(packed, idx, base, interpret=interpret)
 
 
-def sbnet_scatter_fleet(packed: jax.Array, idx: jax.Array, base: jax.Array,
-                        block: int = 1,
-                        interpret: bool = INTERPRET) -> jax.Array:
+def sbnet_scatter_fleet(packed: jax.Array, idx: jax.Array,
+                        base: jax.Array) -> jax.Array:
     """Cross-camera scatter: packed group tiles -> (C, H, W, Cout) stacked
-    frames in ONE launch; untouched regions keep ``base`` values.
-    ``block`` > 1 blocks the tile walk: ``block`` packed tiles arrive per
-    grid step as one contiguous load, bit-identical to the per-tile
-    walk.  An empty tile set is NOT a dispatch: ``base`` is returned
-    untouched with no launch formed and no counter bump."""
+    frames in ONE launch; untouched regions keep ``base`` values.  An
+    empty tile set is NOT a dispatch: ``base`` is returned untouched with
+    no launch formed and no counter bump."""
     if packed.shape[0] == 0:
         return base
     record_dispatch("sbnet_scatter_fleet")
-    return _sbnet_scatter_fleet_jit(packed, idx, base, int(block),
-                                    interpret)
+    return _sbnet_scatter_fleet_jit(packed, idx, base, interpret_mode())
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _sbnet_scatter_changed_jit(packed, idx, base, block=1,
-                               interpret=INTERPRET):
-    return _scatter_changed(packed, idx, base, block=block,
-                            interpret=interpret)
-
-
-@functools.lru_cache(maxsize=1)
-def _sbnet_scatter_changed_donated_jit():
-    # donate_argnums touches the backend at trace time, so build lazily —
-    # and only off-CPU callers ask for it (CPU jit rejects donation with a
-    # warning, same constraint the serving engine's ring writer handles).
-    return jax.jit(_scatter_changed,
-                   static_argnames=("block", "interpret"),
-                   donate_argnums=(2,))
+@functools.lru_cache(maxsize=2)
+def _sbnet_scatter_changed_jit(donate: bool):
+    return jax.jit(_scatter_changed, static_argnames=("interpret",),
+                   donate_argnums=(2,) if donate else ())
 
 
 def sbnet_scatter_changed(packed: jax.Array, idx: jax.Array,
-                          base: jax.Array, block: int = 1,
-                          interpret: bool = INTERPRET,
+                          base: jax.Array,
                           donate: bool = False) -> jax.Array:
     """Changed-only scatter into the PERSISTENT head-map canvas:
     ``base`` is the previous step's device-resident canvas, ``packed`` /
@@ -532,40 +527,36 @@ def sbnet_scatter_changed(packed: jax.Array, idx: jax.Array,
     if packed.shape[0] == 0:
         return base
     record_dispatch("sbnet_scatter_changed")
-    if donate:
-        return _sbnet_scatter_changed_donated_jit()(
-            packed, idx, base, block=int(block), interpret=interpret)
-    return _sbnet_scatter_changed_jit(packed, idx, base, int(block),
-                                      interpret)
+    return _sbnet_scatter_changed_jit(bool(donate))(
+        packed, idx, base, interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("th", "tw", "qstep",
                                              "coef_bits", "run_bits",
                                              "interpret"))
 def _tile_delta_jit(cur, prev, idx, th, tw, qstep, coef_bits, run_bits,
-                    interpret=INTERPRET):
+                    interpret):
     return _tile_delta(cur, prev, idx, th, tw, qstep, coef_bits, run_bits,
                        interpret=interpret)
 
 
 def tile_delta(cur: jax.Array, prev: jax.Array, idx: jax.Array, th: int,
                tw: int, qstep: float = 8.0, coef_bits: int = COEF_BITS,
-               run_bits: int = RUN_BITS,
-               interpret: bool = INTERPRET) -> jax.Array:
+               run_bits: int = RUN_BITS) -> jax.Array:
     """Per-tile temporal delta stats for the edge rate controller:
     (H, W, C) frame pair + (n, 2) tile coords -> (n, STATS_WIDTH) int32
     rows of [byte_estimate, nnz, zero_runs, sum|q|, 0...] (bit-exact vs
     ``ref.tile_delta``)."""
     record_dispatch("tile_delta")
     return _tile_delta_jit(cur, prev, idx, th, tw, float(qstep),
-                           int(coef_bits), int(run_bits), interpret)
+                           int(coef_bits), int(run_bits), interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("th", "tw", "qstep",
                                              "coef_bits", "run_bits",
                                              "block", "interpret"))
 def _tile_delta_gate_jit(cur_p, ref_win, idx, th, tw, qstep, coef_bits,
-                         run_bits, block=1, interpret=INTERPRET):
+                         run_bits, block, interpret):
     return _tile_delta_gate(cur_p, ref_win, idx, th, tw, qstep, coef_bits,
                             run_bits, block=block, interpret=interpret)
 
@@ -573,8 +564,8 @@ def _tile_delta_gate_jit(cur_p, ref_win, idx, th, tw, qstep, coef_bits,
 def tile_delta_gate(cur_p: jax.Array, ref_win: jax.Array, idx: jax.Array,
                     th: int, tw: int, qstep: float = 8.0,
                     coef_bits: int = COEF_BITS, run_bits: int = RUN_BITS,
-                    block: int = 1, interpret: bool = INTERPRET):
-    """The reuse gate's shared delta dispatch: (C, H+2, W+2, Cin)
+                    block: int = 1):
+    """The reuse gate's shared delta dispatch: (C, H+2, W', Cin)
     zero-padded stacked fleet frames + (n, th+2, tw+2, Cin) PACKED
     per-tile reference windows + (n, 3) (cam, ty, tx) coords ->
     (stats (n, STATS_WIDTH) int32, windows (n, th+2, tw+2, Cin)).
@@ -585,20 +576,19 @@ def tile_delta_gate(cur_p: jax.Array, ref_win: jax.Array, idx: jax.Array,
     estimate (bit-exact vs ``ref.tile_delta_gate``); ``windows`` holds
     the CURRENT haloed windows for on-device reference advancement.
     ONE launch per fleet step serves both the reuse gate and the
-    encoder's static-tile calibration.  ``block`` > 1 blocks the
-    pricing walk like the blocked entry kernel."""
+    encoder's static-tile calibration.  ``block`` tiles share a grid
+    step like the blocked entry kernel."""
     record_dispatch("tile_delta_gate")
     return _tile_delta_gate_jit(cur_p, ref_win, idx, th, tw, float(qstep),
                                 int(coef_bits), int(run_bits),
-                                int(block), interpret)
+                                int(block), interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("th", "tw", "qstep",
                                              "coef_bits", "run_bits",
                                              "block", "interpret"))
 def _tile_delta_gate_canvas_jit(cur_p, ref_c, idx, th, tw, qstep,
-                                coef_bits, run_bits, block=1,
-                                interpret=INTERPRET):
+                                coef_bits, run_bits, block, interpret):
     return _tile_delta_gate_canvas(cur_p, ref_c, idx, th, tw, qstep,
                                    coef_bits, run_bits, block=block,
                                    interpret=interpret)
@@ -607,11 +597,11 @@ def _tile_delta_gate_canvas_jit(cur_p, ref_c, idx, th, tw, qstep,
 def tile_delta_gate_canvas(cur_p: jax.Array, ref_c: jax.Array,
                            idx: jax.Array, th: int, tw: int,
                            qstep: float = 8.0, coef_bits: int = COEF_BITS,
-                           run_bits: int = RUN_BITS, block: int = 1,
-                           interpret: bool = INTERPRET) -> jax.Array:
+                           run_bits: int = RUN_BITS,
+                           block: int = 1) -> jax.Array:
     """The reuse gate against a CANVAS-RESIDENT reference: same stats
     rows as ``tile_delta_gate`` but the reference side is a second
-    (C, H+2, W+2, Cin) padded canvas addressed through the same tile
+    padded canvas of the same shape addressed through the same tile
     rows — no (n, th+2, tw+2) per-tile window duplication (~1.3x the
     canvas bytes on overlap-heavy masks) and no windows output (reference
     advancement writes canvas regions instead).  Counted under the same
@@ -621,14 +611,14 @@ def tile_delta_gate_canvas(cur_p: jax.Array, ref_c: jax.Array,
     return _tile_delta_gate_canvas_jit(cur_p, ref_c, idx, th, tw,
                                        float(qstep), int(coef_bits),
                                        int(run_bits), int(block),
-                                       interpret)
+                                       interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("th", "tw"))
 def gather_windows(xp: jax.Array, idx: jax.Array, th: int,
                    tw: int) -> jax.Array:
     """Gather the packed (n, th+2, tw+2, Cin) haloed windows of the
-    active tiles from a zero-padded (C, H+2, W+2, Cin) stacked canvas —
+    active tiles from a zero-padded (C, H+2, W', Cin) stacked canvas —
     the seed of the gate's per-tile reference windows (pure jnp table
     plumbing, not a counted kernel dispatch; warm steps advance
     references from the gate's own windows output instead)."""
@@ -646,15 +636,15 @@ def gather_windows(xp: jax.Array, idx: jax.Array, th: int,
                                              "coef_bits", "run_bits",
                                              "interpret"))
 def _tile_delta_halo_jit(cur, prev, idx, th, tw, qstep, coef_bits,
-                         run_bits, interpret=INTERPRET):
+                         run_bits, interpret):
     return _tile_delta_halo(cur, prev, idx, th, tw, qstep, coef_bits,
                             run_bits, interpret=interpret)
 
 
 def tile_delta_halo(cur: jax.Array, prev: jax.Array, idx: jax.Array,
                     th: int, tw: int, qstep: float = 8.0,
-                    coef_bits: int = COEF_BITS, run_bits: int = RUN_BITS,
-                    interpret: bool = INTERPRET) -> jax.Array:
+                    coef_bits: int = COEF_BITS,
+                    run_bits: int = RUN_BITS) -> jax.Array:
     """Per-tile temporal delta stats of the HALO STRIPS (the tile's edge
     ring — the pixels duplicated into neighbors when rectangles encode
     independently): (n, STATS_WIDTH) int32 rows, bit-exact vs
@@ -662,14 +652,17 @@ def tile_delta_halo(cur: jax.Array, prev: jax.Array, idx: jax.Array,
     controller."""
     record_dispatch("tile_delta_halo")
     return _tile_delta_halo_jit(cur, prev, idx, th, tw, float(qstep),
-                                int(coef_bits), int(run_bits), interpret)
+                                int(coef_bits), int(run_bits),
+                                interpret_mode())
 
 
 def roi_conv_batched(x: jax.Array, w: jax.Array, idx: jax.Array,
                      th: int, tw: int) -> jax.Array:
     """(B, H, W, Cin) -> (B, n, th, tw, Cout), shared active set."""
     record_dispatch("roi_conv")
-    return jax.vmap(lambda xi: _roi_conv_jit(xi, w, idx, th, tw))(x)
+    interpret = interpret_mode()
+    return jax.vmap(lambda xi: _roi_conv_jit(xi, w, idx, th, tw,
+                                             interpret))(x)
 
 
 def pack_tokens(x: jax.Array, keep: jax.Array, block: int = 128):
@@ -706,9 +699,8 @@ def unpack_tokens(packed: jax.Array, positions: jax.Array, S: int,
 @functools.partial(jax.jit,
                    static_argnames=("block_q", "block_k", "causal_skip",
                                     "return_stats", "interpret"))
-def _roi_attention_jit(q, k, v, positions, block_q=128, block_k=128,
-                       causal_skip=True, return_stats=False,
-                       interpret=INTERPRET):
+def _roi_attention_jit(q, k, v, positions, block_q, block_k, causal_skip,
+                       return_stats, interpret):
     return _roi_attn(q, k, v, positions, block_q=block_q, block_k=block_k,
                      causal_skip=causal_skip, return_stats=return_stats,
                      interpret=interpret)
@@ -717,8 +709,7 @@ def _roi_attention_jit(q, k, v, positions, block_q=128, block_k=128,
 def roi_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   positions: jax.Array, block_q: int = 128,
                   block_k: int = 128, causal_skip: bool = True,
-                  return_stats: bool = False,
-                  interpret: bool = INTERPRET):
+                  return_stats: bool = False):
     """Packed-prefill attention over (S, H, D) with original-position
     causality.  S must already be block-padded (pack_tokens does this).
     ``causal_skip`` bounds the k-block walk at the causal frontier (exact:
@@ -726,7 +717,7 @@ def roi_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     returns the (H, S // block_q) visited-k-block counts."""
     record_dispatch("roi_attention")
     return _roi_attention_jit(q, k, v, positions, block_q, block_k,
-                              causal_skip, return_stats, interpret)
+                              causal_skip, return_stats, interpret_mode())
 
 
 def attention_visit_bound(positions: np.ndarray, block_q: int = 128,
@@ -756,7 +747,8 @@ __all__ = ["mask_to_indices", "neighbor_table", "fleet_indices",
            "roi_conv", "roi_conv_entry", "roi_conv_fleet",
            "roi_conv_packed", "roi_conv_stack", "roi_conv_batched",
            "tile_delta", "tile_delta_gate", "tile_delta_gate_canvas",
-           "gather_windows", "tile_delta_halo",
+           "gather_windows", "pad_frames", "tile_delta_halo",
+           "interpret_mode",
            "GATE_BODY_BYTES",
            "GATE_WIN_BYTES", "GATE_WIN_EXACT", "STATS_WIDTH", "pack_tokens",
            "unpack_tokens", "roi_attention", "attention_visit_bound",

@@ -51,40 +51,6 @@ def roi_conv(x: jax.Array, w: jax.Array, idx: jax.Array,
     return sbnet_gather(full.astype(x.dtype), idx, th, tw)
 
 
-def rims_of_packed(packed, nbr):
-    """Oracle for the coalesced rim halos (kernels/roi_conv.py): assemble
-    each tile's halo strips from the packed tensor + (n, 8) neighbor
-    table.  Returns (rim_top (n+1, tw+2, C), rim_bot (n+1, tw+2, C),
-    rim_left (n+1, th, C), rim_right (n+1, th, C)); slot n is the trash
-    slot and positions with no active donor are zero (the same values the
-    kernels' read-side masking produces — kernels may leave garbage there
-    because consumers always mask by the neighbor table)."""
-    import numpy as np
-    packed = np.asarray(packed)
-    nbr = np.asarray(nbr)
-    n, th, tw, C = packed.shape
-    rt = np.zeros((n + 1, tw + 2, C), packed.dtype)
-    rb = np.zeros((n + 1, tw + 2, C), packed.dtype)
-    rl = np.zeros((n + 1, th, C), packed.dtype)
-    rr = np.zeros((n + 1, th, C), packed.dtype)
-
-    def tgt(i, j):
-        s = int(nbr[i, j])
-        return s if s >= 0 else n
-
-    for i in range(n):
-        o = packed[i]
-        rt[tgt(i, 6), 1:1 + tw] = o[th - 1]        # we are S's N donor
-        rt[tgt(i, 7), 0] = o[th - 1, tw - 1]       # SE's NW corner donor
-        rt[tgt(i, 5), tw + 1] = o[th - 1, 0]       # SW's NE corner donor
-        rb[tgt(i, 1), 1:1 + tw] = o[0]             # N's S donor
-        rb[tgt(i, 2), 0] = o[0, tw - 1]            # NE's SW corner donor
-        rb[tgt(i, 0), tw + 1] = o[0, 0]            # NW's SE corner donor
-        rl[tgt(i, 4)] = o[:, tw - 1]               # E's W donor
-        rr[tgt(i, 3)] = o[:, 0]                    # W's E donor
-    return rt, rb, rl, rr
-
-
 def roi_conv_packed(packed: jax.Array, idx: jax.Array, grid_shape,
                     w: jax.Array) -> jax.Array:
     """Oracle for the packed-resident conv: scatter the packed tiles onto a

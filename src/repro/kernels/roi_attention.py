@@ -6,8 +6,8 @@ into a dense prefix and prefilled in one pass.  Causality must follow the
 tokens' *original* positions, so the kernel carries a positions vector and
 masks with pos_q >= pos_k instead of the block-triangular structure.
 
-Flash-attention structure: grid = (heads, q_blocks); the q block lives in
-VMEM via BlockSpec; K/V stay in ANY/HBM and the kernel walks k-blocks with
+Flash-attention structure: grid = (heads, q_blocks); the q block and the
+head's K/V live in VMEM via BlockSpec and the kernel walks k-blocks with
 dynamic-slice loads, maintaining the online-softmax running max/denominator.
 Padding rows carry position INT32_MAX (never attended, never attending).
 
@@ -36,13 +36,14 @@ _NEG = -1e30
 PAD_POS = jnp.iinfo(jnp.int32).max
 
 
-def _roi_attn_kernel(pos_ref, kmin_ref, q_ref, k_ref, v_ref, o_ref, cnt_ref,
-                     *, block_k: int, scale: float, causal_skip: bool):
+def _roi_attn_kernel(pos_ref, kmin_ref, q_ref, k_ref, v_ref, pq_ref, pk_ref,
+                     o_ref, cnt_ref, *, block_k: int, scale: float,
+                     causal_skip: bool):
     qi = pl.program_id(1)
     bq, D = q_ref.shape[1], q_ref.shape[2]
     S = k_ref.shape[1]
     q = q_ref[0].astype(jnp.float32) * scale          # (bq, D)
-    pos_q = pos_ref[pl.ds(qi * bq, bq)]               # (bq,)
+    pos_q = pq_ref[...]                               # (bq, 1)
 
     nk = S // block_k
 
@@ -50,9 +51,12 @@ def _roi_attn_kernel(pos_ref, kmin_ref, q_ref, k_ref, v_ref, o_ref, cnt_ref,
         # visit k-blocks [0, hi): hi = 1 + last j with min(pos_k_j) <=
         # max(real pos_q).  Correct for any positions vector; for the
         # monotone packed layout it is exactly the causal prefix.  A
-        # q-block of pure padding has no real rows -> hi = 0.
-        real_q = pos_q != PAD_POS
-        pos_q_max = jnp.max(jnp.where(real_q, pos_q, -1))
+        # q-block of pure padding has no real rows -> hi = 0.  Scalar
+        # walks over the SMEM copies of the positions.
+        def row_max(r, m):
+            pr = pos_ref[qi * bq + r]
+            return jnp.where(pr != PAD_POS, jnp.maximum(m, pr), m)
+        pos_q_max = jax.lax.fori_loop(0, bq, row_max, -1)
 
         def scan_last(j, h):
             return jnp.where(kmin_ref[j] <= pos_q_max, j + 1, h)
@@ -62,28 +66,26 @@ def _roi_attn_kernel(pos_ref, kmin_ref, q_ref, k_ref, v_ref, o_ref, cnt_ref,
 
     def body(j, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (pl.ds(0, 1), pl.ds(j * block_k, block_k),
-                            slice(None)))[0].astype(jnp.float32)  # (bk, D)
-        v = pl.load(v_ref, (pl.ds(0, 1), pl.ds(j * block_k, block_k),
-                            slice(None)))[0].astype(jnp.float32)
-        pos_k = pos_ref[pl.ds(j * block_k, block_k)]
+        start = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[0, pl.ds(start, block_k), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(start, block_k), :].astype(jnp.float32)
+        pos_k = pk_ref[:, pl.ds(start, block_k)]      # (1, bk)
         s = q @ k.T                                   # (bq, bk)
-        mask = pos_q[:, None] >= pos_k[None, :]
-        s = jnp.where(mask, s, _NEG)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        s = jnp.where(pos_q >= pos_k, s, _NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1)
-        acc_new = acc * alpha[:, None] + p @ v
+        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = acc * alpha + p @ v
         return acc_new, m_new, l_new
 
     acc0 = jnp.zeros((bq, D), jnp.float32)
-    m0 = jnp.full((bq,), _NEG, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
+    m0 = jnp.full((bq, 1), _NEG, jnp.float32)
+    l0 = jnp.zeros((bq, 1), jnp.float32)
     acc, m, l = jax.lax.fori_loop(0, hi, body, (acc0, m0, l0))
-    out = acc / jnp.maximum(l, 1e-30)[:, None]
+    out = acc / jnp.maximum(l, 1e-30)
     o_ref[0] = out.astype(o_ref.dtype)
-    cnt_ref[0, 0] = jnp.asarray(hi, jnp.int32)
+    cnt_ref[...] = jnp.full(cnt_ref.shape, hi, jnp.int32)
 
 
 def block_min_positions(positions: jax.Array, block_k: int) -> jax.Array:
@@ -99,8 +101,8 @@ def block_min_positions(positions: jax.Array, block_k: int) -> jax.Array:
 def roi_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   positions: jax.Array, *, block_q: int = 128,
                   block_k: int = 128, scale: float | None = None,
-                  causal_skip: bool = True, interpret: bool = True,
-                  return_stats: bool = False):
+                  causal_skip: bool = True, return_stats: bool = False,
+                  interpret: bool):
     """q,k,v: (S, H, D) packed tokens; positions: (S,) int32 original
     positions (padding = PAD_POS).  S must divide by block_q and block_k
     (ops.roi_attention pads).  Returns (S, H, D), or
@@ -123,10 +125,14 @@ def roi_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, block_q, D), lambda h, i, pos, kmin: (h, i, 0)),
             pl.BlockSpec((1, S, D), lambda h, i, pos, kmin: (h, 0, 0)),
             pl.BlockSpec((1, S, D), lambda h, i, pos, kmin: (h, 0, 0)),
+            # positions again as vectors for the mask: a query column
+            # block and the whole key row
+            pl.BlockSpec((block_q, 1), lambda h, i, pos, kmin: (i, 0)),
+            pl.BlockSpec((1, S), lambda h, i, pos, kmin: (0, 0)),
         ],
         out_specs=(
             pl.BlockSpec((1, block_q, D), lambda h, i, pos, kmin: (h, i, 0)),
-            pl.BlockSpec((1, 1), lambda h, i, pos, kmin: (h, i)),
+            pl.BlockSpec((1, 1, 1, 1), lambda h, i, pos, kmin: (h, i, 0, 0)),
         ),
     )
 
@@ -134,10 +140,12 @@ def roi_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((H, S, D), q.dtype),
-                   jax.ShapeDtypeStruct((H, nq), jnp.int32)),
+                   jax.ShapeDtypeStruct((H, nq, 1, 1), jnp.int32)),
         interpret=interpret,
-    )(positions, kmin, qh, kh, vh)
+    )(positions, kmin, qh, kh, vh, positions.reshape(S, 1),
+      positions.reshape(1, S))
     out = jnp.swapaxes(out, 0, 1)
+    visited = visited.reshape(H, nq)
     if return_stats:
         return out, visited
     return out

@@ -1,26 +1,26 @@
 """RoI-sparse 3x3 convolution as Pallas TPU kernels.
 
-Two kernels implement the RoI-YOLO layer (paper §4.4):
+``roi_conv_entry`` — the *entry* layer: convolution evaluated only on
+active tiles, reading straight from the stacked frames.  grid =
+(tile_block,); each grid step receives ``block`` haloed (th+2, tw+2,
+Cin) windows, one element-indexed BlockSpec per window whose index map
+reads the scalar-prefetched (cam, ty, tx) rows, so Mosaic's pipeline
+DMAs the next block's windows while this one computes.  The 3x3 conv is
+9 shifted (block*th*tw, Cin) @ (Cin, Cout) MXU matmuls.  This fuses
+SBNet's gather into the first conv.  ``roi_conv_fleet`` and ``roi_conv``
+are the same kernel without the fused ReLU (one camera group / one
+camera).
 
-``roi_conv`` — the *entry* layer: convolution evaluated only on active
-tiles, reading straight from the full frame.  grid=(n_active,); per step
-the kernel DMAs one *haloed* (th+2, tw+2, Cin) window from the padded
-feature map in HBM (dynamic-start, static-size slice — a block DMA on
-Mosaic), then computes the 3x3 conv as 9 shifted (th*tw, Cin) @ (Cin, Cout)
-matmuls on the MXU.  This fuses SBNet's gather into the first conv.
+``roi_conv_stack`` — every *subsequent* layer in ONE launch: grid =
+(layer, tile_block).  A layer's packed (n, th, tw, C) output stays in
+HBM; the next layer DMAs each tile's center and the 1-deep edges of its
+8 neighbors (an offline (n, 8) neighbor table, scalar-prefetched) into
+a VMEM window, so the sparse representation never round-trips through a
+full-frame scatter between layers.  Inactive or off-frame neighbors map
+to an all-zero row: the zero halo the scatter-into-zeros path produced.
 
-``roi_conv_packed`` — every *subsequent* layer: consumes the previous
-layer's packed (n, th, tw, C) output directly, so the sparse representation
-never round-trips through a full-frame scatter between layers.  Halo rows/
-columns come from neighbor tiles via an offline-computed (n, 8) neighbor
-table (scalar-prefetched into SMEM): entry j holds the packed slot of the
-j-th neighbor (NW, N, NE, W, E, SW, S, SE order) or -1 when that neighbor
-is inactive/off-frame, in which case the halo strip is zero — exactly the
-value the old scatter-into-zeros path produced, so the packed chain is
-bit-compatible with the scatter/gather chain on every tile.
-
-Keep th*tw and channel dims multiples of 128 for full MXU utilization;
-both kernels are functional for any size.
+``roi_conv_packed`` is the per-layer form of one stack layer, kept as
+the bit-identical baseline the megakernel is tested against.
 """
 from __future__ import annotations
 
@@ -31,258 +31,111 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.blocking import balanced_split, pad_repeat_last
-
-# pltpu.TPUMemorySpace was renamed MemorySpace across jax versions
-_MEMSPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
+from repro.kernels.blocking import (LANES, VMEM_LIMIT_BYTES, balanced_split,
+                                    pad_frames, pad_repeat_last, round_up,
+                                    window_width)
 
 # neighbor-table column order: (dy, dx) offsets of the 8 surrounding tiles
 NEIGHBOR_OFFSETS = ((-1, -1), (-1, 0), (-1, 1), (0, -1),
                     (0, 1), (1, -1), (1, 0), (1, 1))
 
-
-def _conv3x3_tile(win: jax.Array, w_ref, th: int, tw: int,
-                  cout: int) -> jax.Array:
-    """(th+2, tw+2, Cin) haloed window -> (th, tw, Cout) via 9 MXU matmuls."""
-    cin = win.shape[-1]
-    acc = jnp.zeros((th * tw, cout), jnp.float32)
-    for dy in range(3):
-        for dx in range(3):
-            patch = win[dy:dy + th, dx:dx + tw, :].reshape(th * tw, cin)
-            acc += patch.astype(jnp.float32) @ w_ref[dy, dx].astype(
-                jnp.float32)
-    return acc.reshape(th, tw, cout)
+# at most this many haloed windows per entry grid step: each window is
+# its own pipelined operand
+MAX_WINDOWS_PER_STEP = 16
 
 
-def _roi_conv_kernel(idx_ref, x_ref, w_ref, o_ref, *, th: int, tw: int):
-    i = pl.program_id(0)
-    ty = idx_ref[i, 0]
-    tx = idx_ref[i, 1]
-    cout = o_ref.shape[-1]
-    # haloed window from the (H+2, W+2, Cin) padded map
-    win = pl.load(x_ref, (pl.ds(ty * th, th + 2), pl.ds(tx * tw, tw + 2),
-                          slice(None)))
-    o_ref[0] = _conv3x3_tile(win, w_ref, th, tw, cout).astype(o_ref.dtype)
+def contraction_width(cin: int) -> int:
+    """Channels a packed-layer tap contracts over: cin rounded up to 8,
+    the extra window lanes and weight rows being zero.  XLA's CPU dot
+    (the interpreter's) sums a 5-wide contraction in an order that
+    depends on the row count; at multiples of 8 it does not, so a tile's
+    bits stay independent of its block there too."""
+    return -(-cin // 8) * 8
 
 
-def roi_conv(x: jax.Array, w: jax.Array, idx: jax.Array, th: int, tw: int,
-             *, interpret: bool = True) -> jax.Array:
-    """x: (H, W, Cin); w: (3, 3, Cin, Cout); idx: (n, 2) int32 tile coords.
-    Returns packed SAME-conv outputs on active tiles: (n, th, tw, Cout)."""
-    H, W, Cin = x.shape
-    Cout = w.shape[-1]
-    n = idx.shape[0]
-    xp = jnp.pad(x, ((1, 1), (1, 1), (0, 0)))
-    kernel = functools.partial(_roi_conv_kernel, th=th, tw=tw)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[
-            # whole padded map stays in ANY/HBM; the kernel slices windows
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-            pl.BlockSpec((3, 3, Cin, Cout), lambda i, idx_ref: (0, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, th, tw, Cout),
-                               lambda i, idx_ref: (i, 0, 0, 0)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, th, tw, Cout), x.dtype),
-        interpret=interpret,
-    )(idx, xp, w)
-
-
-# ---------------------------------------------------------------------------
-# packed-resident conv: halo strips fetched from neighbor tiles
-# ---------------------------------------------------------------------------
-
-def _halo_strip(p_ref, slot, ys, ny, xs, nx):
-    """Load packed[slot, ys:ys+ny, xs:xs+nx, :]; zero when slot == -1.
-
-    The load is issued at the clamped slot (so it is always in-bounds) and
-    masked afterwards — data-dependent *suppression*, not data-dependent
-    control flow, which keeps the DMA schedule static.
-    """
-    safe = jnp.maximum(slot, 0)
-    strip = pl.load(p_ref, (pl.ds(safe, 1), pl.ds(ys, ny), pl.ds(xs, nx),
-                            slice(None)))[0]
-    return jnp.where(slot >= 0, strip, jnp.zeros_like(strip))
-
-
-def _roi_conv_packed_kernel(nbr_ref, p_ref, w_ref, o_ref, *,
-                            th: int, tw: int):
-    i = pl.program_id(0)
-    cout = o_ref.shape[-1]
-    z = jnp.asarray(0, jnp.int32)
-
-    center = pl.load(p_ref, (pl.ds(i, 1), pl.ds(z, th), pl.ds(z, tw),
-                             slice(None)))[0]                 # (th, tw, C)
-
-    # 8 halo strips, indexed by the prefetched neighbor table.  Each strip
-    # is the 1-deep edge of the neighbor facing us: the N neighbor donates
-    # its bottom row, the W neighbor its rightmost column, corners one px.
-    nw = _halo_strip(p_ref, nbr_ref[i, 0], th - 1, 1, tw - 1, 1)  # (1,1,C)
-    n_ = _halo_strip(p_ref, nbr_ref[i, 1], th - 1, 1, 0, tw)      # (1,tw,C)
-    ne = _halo_strip(p_ref, nbr_ref[i, 2], th - 1, 1, 0, 1)       # (1,1,C)
-    w_ = _halo_strip(p_ref, nbr_ref[i, 3], 0, th, tw - 1, 1)      # (th,1,C)
-    e_ = _halo_strip(p_ref, nbr_ref[i, 4], 0, th, 0, 1)           # (th,1,C)
-    sw = _halo_strip(p_ref, nbr_ref[i, 5], 0, 1, tw - 1, 1)       # (1,1,C)
-    s_ = _halo_strip(p_ref, nbr_ref[i, 6], 0, 1, 0, tw)           # (1,tw,C)
-    se = _halo_strip(p_ref, nbr_ref[i, 7], 0, 1, 0, 1)            # (1,1,C)
-
-    top = jnp.concatenate([nw, n_, ne], axis=1)          # (1, tw+2, C)
-    mid = jnp.concatenate([w_, center, e_], axis=1)      # (th, tw+2, C)
-    bot = jnp.concatenate([sw, s_, se], axis=1)          # (1, tw+2, C)
-    win = jnp.concatenate([top, mid, bot], axis=0)       # (th+2, tw+2, C)
-
-    o_ref[0] = _conv3x3_tile(win, w_ref, th, tw, cout).astype(o_ref.dtype)
-
-
-def _roi_conv_fleet_kernel(idx_ref, x_ref, w_ref, o_ref, *, th: int,
-                           tw: int, fuse_relu: bool = False):
-    i = pl.program_id(0)
-    cam = idx_ref[i, 0]
-    ty = idx_ref[i, 1]
-    tx = idx_ref[i, 2]
-    cout = o_ref.shape[-1]
-    # haloed window from camera ``cam``'s padded (H+2, W+2, Cin) plane of
-    # the stacked fleet tensor — cameras are separate leading-dim entries,
-    # so a window can never read another camera's pixels
-    win = pl.load(x_ref, (pl.ds(cam, 1), pl.ds(ty * th, th + 2),
-                          pl.ds(tx * tw, tw + 2), slice(None)))[0]
-    o = _conv3x3_tile(win, w_ref, th, tw, cout)
-    if fuse_relu:
-        o = jnp.maximum(o, 0.0)
-    o_ref[0] = o.astype(o_ref.dtype)
-
-
-def roi_conv_fleet(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
-                   tw: int, *, interpret: bool = True) -> jax.Array:
-    """Cross-camera fused gather+conv: ONE launch for a whole camera group.
-
-    x: (C, H, W, Cin) stacked (zero-padded to common H, W) camera frames;
-    w: (3, 3, Cin, Cout); idx: (n, 3) int32 (cam, ty, tx) active-tile coords
-    over ALL cameras.  Returns packed (n, th, tw, Cout) in idx order — the
-    same packed tensor ``roi_conv`` would produce per camera, concatenated.
-    Per-camera zero padding reproduces each camera's own SAME-conv frame
-    boundary, so the output is bit-compatible with per-camera launches."""
-    return _fleet_conv_call(x, w, idx, th, tw, fuse_relu=False,
-                            interpret=interpret)
-
-
-def _fleet_conv_call(x, w, idx, th, tw, *, fuse_relu, interpret):
-    """Shared launch for the fleet gather+conv (``roi_conv_fleet``) and
-    the fused backbone's entry layer (``roi_conv_entry`` = same kernel
-    with the ReLU fused in)."""
-    C, H, W, Cin = x.shape
-    Cout = w.shape[-1]
-    n = idx.shape[0]
-    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
-    kernel = functools.partial(_roi_conv_fleet_kernel, th=th, tw=tw,
-                               fuse_relu=fuse_relu)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-            pl.BlockSpec((3, 3, Cin, Cout),
-                         lambda i, idx_ref: (0, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, th, tw, Cout),
-                               lambda i, idx_ref: (i, 0, 0, 0)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, th, tw, Cout), x.dtype),
-        interpret=interpret,
-    )(idx, xp, w)
-
-
-# ---------------------------------------------------------------------------
-# coalesced rim halos + the fused layer-stack megakernel
-# ---------------------------------------------------------------------------
-#
-# ``roi_conv_packed`` fetches its halo as 8 masked strip/corner DMAs per
-# tile per layer.  The fused path coalesces them: every layer *emits* the
-# assembled halo strips — "rims" — its successor will read, so the next
-# layer fetches the whole halo of a tile block in 4 contiguous loads:
-#
-#   rim_top[j]  (tw+2, C): the row above tile j  = [NW.br | N.bottom | NE.bl]
-#   rim_bot[j]  (tw+2, C): the row below tile j  = [SW.tr | S.top    | SE.tl]
-#   rim_left[j] (th,   C): the column left of j  =  W.rightmost column
-#   rim_right[j](th,   C): the column right of j =  E.leftmost  column
-#
-# Emission is two-step so every store stays contiguous: a conv phase
-# writes its block's own edge strips (top/bottom rows, left/right
-# columns, producer-indexed), and an interleaved assembly phase gathers
-# those edges donor-by-donor into the consumer-indexed rims above,
-# zero-masking positions whose donor is inactive/off-frame (-1 in the
-# neighbor table) — the same zero-halo contract as ``roi_conv_packed``.
-
-
-def assemble_rims(packed: jax.Array, nbr: jax.Array):
-    """Vectorized rim assembly (pure jnp — runs inside the stack launch,
-    before the megakernel, to seed layer 0's rims from the entry layer's
-    packed output).  Gathers each tile's halo strips from its donors'
-    edges: returns (rim_top (n, tw+2, C), rim_bot (n, tw+2, C), rim_left
-    (n, th, C), rim_right (n, th, C)); positions with no active donor are
-    zero.  Row-for-row equal to ``ref.rims_of_packed``'s first n rows."""
-    n, th, tw, c = packed.shape
-    valid = nbr >= 0
-    safe = jnp.clip(nbr, 0, max(n - 1, 0))
-
-    def gat(edge, j):
-        v = jnp.take(edge, safe[:, j], axis=0)
-        return jnp.where(valid[:, j, None, None], v, jnp.zeros_like(v))
-
-    be, te = packed[:, th - 1], packed[:, 0]              # (n, tw, C)
-    le, re = packed[:, :, 0], packed[:, :, tw - 1]        # (n, th, C)
-    # the row above tile j: [NW.bottom-right | N.bottom row | NE.bottom-left]
-    rt = jnp.concatenate([gat(be, 0)[:, tw - 1:tw], gat(be, 1),
-                          gat(be, 2)[:, 0:1]], axis=1)
-    # the row below: [SW.top-right | S.top row | SE.top-left]
-    rb = jnp.concatenate([gat(te, 5)[:, tw - 1:tw], gat(te, 6),
-                          gat(te, 7)[:, 0:1]], axis=1)
-    rl = gat(re, 3)                                       # W.rightmost col
-    rr = gat(le, 4)                                       # E.leftmost col
-    return rt, rb, rl, rr
-
-
-def _roi_conv_entry_block_kernel(idx_ref, x_ref, w_ref, o_ref, *, th: int,
-                                 tw: int, tb: int):
-    """Blocked entry walk: one grid step gathers ``tb`` haloed windows
-    (each a dynamic-start static-size block DMA off the stacked frames)
-    and convolves them as ONE (tb*th*tw, Cin) GEMM per tap.  Output rows
-    are independent dot products, so every tile's values are bitwise
-    identical to the per-tile walk (``_roi_conv_fleet_kernel``)."""
-    b = pl.program_id(0)
-    cout = o_ref.shape[-1]
-    wins = []
-    for j in range(tb):
-        cam = idx_ref[b * tb + j, 0]
-        ty = idx_ref[b * tb + j, 1]
-        tx = idx_ref[b * tb + j, 2]
-        wins.append(pl.load(
-            x_ref, (pl.ds(cam, 1), pl.ds(ty * th, th + 2),
-                    pl.ds(tx * tw, tw + 2), slice(None)))[0])
-    win = jnp.stack(wins)                       # (tb, th+2, tw+2, cin)
-    cin = win.shape[-1]
+def _conv_taps(win: jax.Array, w: jax.Array, th: int, tw: int,
+               k: int) -> jax.Array:
+    """(tb, th+2, >=tw+2, >=k) haloed windows -> (tb, th, tw, Cout): 9
+    shifted (tb*th*tw, k) @ (k, Cout) float32 matmuls over the first k
+    lanes (w: (3, 3, k, Cout)).  Output rows are independent dot
+    products, so a tile's values do not depend on the block it is
+    computed in."""
+    tb = win.shape[0]
+    cout = w.shape[-1]
     acc = jnp.zeros((tb * th * tw, cout), jnp.float32)
     for dy in range(3):
         for dx in range(3):
-            patch = win[:, dy:dy + th, dx:dx + tw, :].reshape(
-                tb * th * tw, cin)
-            acc += patch.astype(jnp.float32) @ w_ref[dy, dx].astype(
-                jnp.float32)
-    o = jnp.maximum(acc, 0.0).reshape(tb, th, tw, cout)
+            patch = win[:, dy:dy + th, dx:dx + tw, :k].reshape(
+                tb * th * tw, k)
+            acc += jnp.dot(patch.astype(jnp.float32),
+                           w[dy, dx].astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+    return acc.reshape(tb, th, tw, cout)
+
+
+def window_specs(tb: int, th: int, tw: int, cin: int):
+    """``tb`` element-indexed BlockSpecs: spec j fetches the haloed
+    window of row ``b*tb + j`` of the scalar-prefetched, flattened (n*3,)
+    (cam, ty, tx) table from (C, H+2, W', Cin) padded frames.  (Scalar
+    memory pads a 2-D table's rows to 128 words, so tables travel flat.)"""
+    wx = window_width(tw)
+
+    def spec(j):
+        def index_map(b, idx_ref):
+            r = 3 * (b * tb + j)
+            return (idx_ref[r], idx_ref[r + 1] * th, idx_ref[r + 2] * tw, 0)
+        return pl.BlockSpec((pl.Element(1), pl.Element(th + 2),
+                             pl.Element(wx), pl.Element(cin)), index_map)
+    return [spec(j) for j in range(tb)]
+
+
+def _entry_kernel(idx_ref, *refs, th: int, tw: int, tb: int,
+                  fuse_relu: bool):
+    win_refs, w_ref, o_ref = refs[:tb], refs[tb], refs[tb + 1]
+    win = jnp.concatenate([r[...] for r in win_refs], axis=0)
+    o = _conv_taps(win, w_ref[...], th, tw, win.shape[-1])
+    if fuse_relu:
+        o = jnp.maximum(o, 0.0)
     o_ref[...] = o.astype(o_ref.dtype)
 
 
+def _entry_call(x, w, idx, th, tw, *, block, fuse_relu, interpret):
+    """Gather + 3x3 conv (+ ReLU) of the (n, 3) (cam, ty, tx) tiles of
+    (C, H, W, Cin) stacked frames -> packed (n, th, tw, Cout)."""
+    n = idx.shape[0]
+    cout = w.shape[-1]
+    if n == 0:
+        return jnp.zeros((0, th, tw, cout), x.dtype)
+    cin = x.shape[-1]
+    _, tb, n_pad = balanced_split(n, min(max(block, 1),
+                                         MAX_WINDOWS_PER_STEP))
+    idx_p = pad_repeat_last(idx, n_pad)
+    xp = pad_frames(x, tw)
+    kernel = functools.partial(_entry_kernel, th=th, tw=tw, tb=tb,
+                               fuse_relu=fuse_relu)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_pad // tb,),
+        in_specs=window_specs(tb, th, tw, cin) + [
+            pl.BlockSpec((3, 3, cin, cout), lambda b, idx_ref: (0, 0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((tb, th, tw, cout),
+                               lambda b, idx_ref: (b, 0, 0, 0)),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_pad, th, tw, cout), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(idx_p.reshape(-1), *([xp] * tb), w)
+    return out[:n]
+
+
 def roi_conv_entry(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
-                   tw: int, *, block: int = 1,
-                   interpret: bool = True) -> jax.Array:
+                   tw: int, *, block: int, interpret: bool) -> jax.Array:
     """The fused backbone's entry layer: gather + 3x3 conv + ReLU in ONE
     launch for any number of cameras (and camera groups — the (n, 3)
     (flat_cam, ty, tx) index space is oblivious to how cameras are
@@ -291,257 +144,269 @@ def roi_conv_entry(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
     idempotent, so callers may re-apply it bit-identically.  The packed
     output feeds ``roi_conv_stack`` for every remaining layer.
 
-    ``block`` > 1 blocks the tile walk like the stack kernel: grid =
-    (tile_block,), each step gathering ``block`` haloed windows and
-    running (block*th*tw, Cin) GEMMs — fewer grid steps and larger
-    coalesced gather DMAs, bit-identical to the per-tile walk (size it
-    with ``ops.choose_block``).  The index list is padded up with
+    ``block`` tiles (at most ``MAX_WINDOWS_PER_STEP``) share a grid step
+    and one (block*th*tw, Cin) GEMM per tap — bit-identical to the
+    per-tile walk at ``block=1``.  The index list is padded up with
     repeats of its last row; the duplicate rows' outputs land past ``n``
-    and are sliced off.
-
-    An EMPTY tile set short-circuits to a zero-row packed tensor with no
-    pallas_call at all — the per-tile walk used to form a grid=(0,)
-    launch (and the blocked walk a padded >= 1-block launch) here."""
-    n = idx.shape[0]
-    if n == 0:
-        return jnp.zeros((0, th, tw, w.shape[-1]), x.dtype)
-    if block <= 1:
-        return _fleet_conv_call(x, w, idx, th, tw, fuse_relu=True,
-                                interpret=interpret)
-    C, H, W, Cin = x.shape
-    Cout = w.shape[-1]
-    nb, tb, n_pad = balanced_split(n, block)
-    idx_p = pad_repeat_last(idx, n_pad)
-    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
-    kernel = functools.partial(_roi_conv_entry_block_kernel, th=th, tw=tw,
-                               tb=tb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_pad // tb,),
-        in_specs=[
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-            pl.BlockSpec((3, 3, Cin, Cout),
-                         lambda b, idx_ref: (0, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tb, th, tw, Cout),
-                               lambda b, idx_ref: (b, 0, 0, 0)),
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_pad, th, tw, Cout), x.dtype),
-        interpret=interpret,
-    )(idx_p, xp, w)
-    return out[:n]
+    and are sliced off.  An EMPTY tile set returns a zero-row packed
+    tensor with no pallas_call at all."""
+    return _entry_call(x, w, idx, th, tw, block=block, fuse_relu=True,
+                       interpret=interpret)
 
 
-def _roi_conv_stack_kernel(nbr_ref, p0_ref, rt0, rb0, rl0, rr0, w_ref,
-                           o_ref, act_ref, te_ref, be_ref, le_ref, re_ref,
-                           rt_ref, rb_ref, rl_ref, rr_ref, *, th: int,
-                           tw: int, chans, tb: int, n_pad: int):
+def roi_conv_fleet(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
+                   tw: int, *, interpret: bool) -> jax.Array:
+    """Cross-camera fused gather+conv (no ReLU), one launch for a camera
+    group: x (C, H, W, Cin) stacked frames, idx (n, 3) (cam, ty, tx).
+    Per-camera zero padding reproduces each camera's own SAME-conv frame
+    boundary, so the output equals per-camera launches."""
+    return _entry_call(x, w, idx, th, tw, block=1, fuse_relu=False,
+                       interpret=interpret)
+
+
+def roi_conv(x: jax.Array, w: jax.Array, idx: jax.Array, th: int, tw: int,
+             *, interpret: bool) -> jax.Array:
+    """x: (H, W, Cin); w: (3, 3, Cin, Cout); idx: (n, 2) int32 tile coords.
+    Returns packed SAME-conv outputs on active tiles: (n, th, tw, Cout)."""
+    idx3 = jnp.concatenate([jnp.zeros_like(idx[:, :1]), idx], axis=1)
+    return roi_conv_fleet(x[None], w, idx3, th, tw, interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# the fused layer-stack megakernel
+# ---------------------------------------------------------------------------
+
+def _span(d: int, size: int):
+    """(source slice, window slice) along one axis for neighbor offset
+    ``d``: the neighbor above/left donates its last row/column, the one
+    below/right its first, the tile itself its whole extent."""
+    if d < 0:
+        return pl.ds(size - 1, 1), pl.ds(0, 1)
+    if d > 0:
+        return pl.ds(0, 1), pl.ds(size + 1, 1)
+    return pl.ds(0, size), pl.ds(1, size)
+
+
+def _window_fetches(src, nbr_ref, win, sem, base: int, tb: int, th: int,
+                    tw: int):
+    """DMA the haloed windows of packed rows [base, base+tb) of ``src``
+    ((n+1, th, tw, CP), row n all zero) into ``win`` (tb, th+2, >=tw+2,
+    CP): the centers in one copy, then each tile's 8 neighbor edges and
+    corners from the rows the flattened (n*8,) neighbor table names."""
+    halo = []
+    for dy, dx in NEIGHBOR_OFFSETS:
+        (sy, wy), (sx, wx) = _span(dy, th), _span(dx, tw)
+        halo.append(((sy, sx), (wy, wx)))
+
+    def center(start):
+        return pltpu.make_async_copy(
+            src.at[pl.ds(start, tb)],
+            win.at[:, pl.ds(1, th), pl.ds(1, tw)], sem)
+
+    def edge(j, k, row):
+        (sy, sx), (wy, wx) = halo[k]
+        return pltpu.make_async_copy(src.at[row, sy, sx],
+                                     win.at[j, wy, wx], sem)
+
+    def start(j, carry):
+        for k in range(8):
+            edge(j, k, nbr_ref[8 * (base + j) + k]).start()
+        return carry
+
+    def wait(j, carry):
+        for k in range(8):
+            edge(j, k, 0).wait()
+        return carry
+
+    center(base).start()
+    jax.lax.fori_loop(0, tb, start, 0)
+    center(0).wait()
+    jax.lax.fori_loop(0, tb, wait, 0)
+
+
+def _roi_conv_stack_kernel(nbr_ref, p0_hbm, w_ref, o_ref, act_a, act_b,
+                           win, stage, zero, sems, *, th: int, tw: int,
+                           chans, tb: int, n_pad: int):
     p = pl.program_id(0)
     b = pl.program_id(1)
     L = len(chans) - 1
-    sel = (pl.ds(b * tb, tb),)
-    nbrs = pl.load(nbr_ref, sel + (slice(None),))          # (tb, 8)
-    valid = nbrs >= 0
-    safe = jnp.clip(nbrs, 0, n_pad - 1)
+    acts = (act_a, act_b)
+    base = b * tb
 
-    def conv_phase(lc: int):
-        cin, cout = chans[lc], chans[lc + 1]
-        cs = slice(0, cin)
-        if lc == 0:
-            center = p0_ref[...]               # (tb, th, tw, c0) block
-            srcs = (rt0, rb0, rl0, rr0)
-        else:
-            center = pl.load(act_ref, sel + (slice(None), slice(None),
-                                             cs))
-            srcs = (rt_ref, rb_ref, rl_ref, rr_ref)
-        # the whole block halo in 4 contiguous loads — the rims were
-        # assembled (donor-gathered, zero-masked) by the previous phase,
-        # vs 8 masked strip/corner DMAs per tile in roi_conv_packed
-        top = pl.load(srcs[0], sel + (slice(None), cs))    # (tb, tw+2, ·)
-        bot = pl.load(srcs[1], sel + (slice(None), cs))
-        left = pl.load(srcs[2], sel + (slice(None), cs))   # (tb, th, ·)
-        right = pl.load(srcs[3], sel + (slice(None), cs))
-        mid = jnp.concatenate([left[:, :, None], center,
-                               right[:, :, None]], axis=2)
-        win = jnp.concatenate([top[:, None], mid, bot[:, None]],
-                              axis=1)          # (tb, th+2, tw+2, cin)
-        # w_ref's block is layer lc's (prefetched) weight plane; the
-        # static slice recovers the layer's true channel widths.  The
-        # block flattens into the GEMM M dimension — one
-        # (tb*th*tw, cin) @ (cin, cout) per tap; output rows are
-        # independent dot products, so each tile's values are bitwise
-        # identical to ``roi_conv_packed``'s per-tile matmuls
-        w = w_ref[0][:, :, :cin, :cout]
-        acc = jnp.zeros((tb * th * tw, cout), jnp.float32)
-        for dy in range(3):
-            for dx in range(3):
-                patch = win[:, dy:dy + th, dx:dx + tw, :].reshape(
-                    tb * th * tw, cin)
-                acc += patch.astype(jnp.float32) @ w[dy, dx].astype(
-                    jnp.float32)
-        o = jnp.maximum(acc, 0.0).reshape(tb, th, tw, cout).astype(
-            p0_ref.dtype)
-        if lc == L - 1:
-            pl.store(o_ref, sel + (slice(None), slice(None),
-                                   slice(None)), o)
-        else:
-            co = slice(0, cout)
-            pl.store(act_ref, sel + (slice(None), slice(None), co), o)
-            # emit this block's edge strips (contiguous stores) for the
-            # interleaved rim-assembly phase
-            pl.store(te_ref, sel + (slice(None), co), o[:, 0])
-            pl.store(be_ref, sel + (slice(None), co), o[:, th - 1])
-            pl.store(le_ref, sel + (slice(None), co), o[:, :, 0])
-            pl.store(re_ref, sel + (slice(None), co), o[:, :, tw - 1])
+    def emit(o, dst, cout):
+        # lanes past cout stay zero (cleared at each layer's first
+        # block): the next layer contracts over them
+        @pl.when(b == 0)
+        def _():
+            stage[...] = jnp.zeros(stage.shape, stage.dtype)
 
-    def assemble_phase(lc: int):
-        # gather the block's rims for layer lc+1 from layer lc's edges
-        # (the write side of the coalesced-halo scheme: donor gather +
-        # zero masking happens ONCE here, so the conv phase reads clean
-        # assembled strips)
-        co = slice(0, chans[lc + 1])
-        te = pl.load(te_ref, (slice(None), slice(None), co))
-        be = pl.load(be_ref, (slice(None), slice(None), co))
-        le = pl.load(le_ref, (slice(None), slice(None), co))
-        re = pl.load(re_ref, (slice(None), slice(None), co))
+        stage[:, :, :, :cout] = o.astype(stage.dtype)
+        out = pltpu.make_async_copy(stage, dst.at[pl.ds(base, tb)],
+                                    sems.at[1])
+        out.start()
 
-        def gat(edge, j):
-            v = jnp.take(edge, safe[:, j], axis=0)
-            return jnp.where(valid[:, j, None, None], v,
-                             jnp.zeros_like(v))
+        @pl.when(b == 0)
+        def _():
+            # row n_pad is the all-zero halo donor of inactive neighbors
+            zero[...] = jnp.zeros(zero.shape, zero.dtype)
+            z = pltpu.make_async_copy(zero, dst.at[pl.ds(n_pad, 1)],
+                                      sems.at[1])
+            z.start()
+            z.wait()
 
-        rt = jnp.concatenate([gat(be, 0)[:, tw - 1:tw], gat(be, 1),
-                              gat(be, 2)[:, 0:1]], axis=1)
-        rb = jnp.concatenate([gat(te, 5)[:, tw - 1:tw], gat(te, 6),
-                              gat(te, 7)[:, 0:1]], axis=1)
-        pl.store(rt_ref, sel + (slice(None), co), rt)
-        pl.store(rb_ref, sel + (slice(None), co), rb)
-        pl.store(rl_ref, sel + (slice(None), co), gat(re, 3))
-        pl.store(rr_ref, sel + (slice(None), co), gat(le, 4))
+        out.wait()
 
-    # phase sequence: conv 0, assemble 0, conv 1, assemble 1, ..., conv L-1
-    for pc in range(2 * L - 1):
-        @pl.when(p == pc)
-        def _(pc=pc):
-            if pc % 2 == 0:
-                conv_phase(pc // 2)
+    # phase l: layer l reads layer l-1's packed output (the entry
+    # layer's for l = 0) and writes the other ping-pong buffer, so a
+    # block's writes never race a later block's neighbor reads
+    for lc in range(L):
+        @pl.when(p == lc)
+        def _(lc=lc):
+            src = p0_hbm if lc == 0 else acts[(lc - 1) % 2]
+            _window_fetches(src, nbr_ref, win, sems.at[0], base, tb, th, tw)
+            k, cout = contraction_width(chans[lc]), chans[lc + 1]
+            w = w_ref[0][:, :, :k, :cout]
+            o = jnp.maximum(_conv_taps(win[...], w, th, tw, k), 0.0)
+            if lc == L - 1:
+                o_ref[...] = o.astype(o_ref.dtype)
             else:
-                assemble_phase(pc // 2)
+                emit(o, acts[lc % 2], cout)
 
 
-def roi_conv_stack(packed: jax.Array, ws, nbr: jax.Array, *,
-                   block: int = 128, interpret: bool = True) -> jax.Array:
+def roi_conv_stack(packed: jax.Array, ws, nbr: jax.Array, *, block: int,
+                   interpret: bool) -> jax.Array:
     """The fused layer-stack megakernel: the ENTIRE packed conv chain
     (3x3 conv + ReLU per layer) in ONE ``pallas_call`` with grid =
-    (phase, tile_block), replacing N-1 ``roi_conv_packed`` dispatches.
+    (layer, tile_block), replacing N-1 ``roi_conv_packed`` dispatches.
 
     packed: (n, th, tw, C0) the entry layer's (relu'd) packed output;
     ws: list of (3, 3, C_l, C_{l+1}) weights; nbr: (n, 8) neighbor table
-    (``neighbor_table`` / ``fleet_neighbor_table``).  Returns the last
-    layer's packed (n, th, tw, C_last), bit-identical to the per-layer
-    ``relu(roi_conv_packed(...))`` chain:
+    (``neighbor_table`` / ``fleet_neighbor_table``, -1 = zero halo).
+    Returns the last layer's packed (n, th, tw, C_last), bit-identical to
+    the per-layer ``relu(roi_conv_packed(...))`` chain:
 
-    * the phase axis is OUTER and alternates conv / rim-assembly, so
-      every tile of layer l (and its rim assembly) completes before
-      layer l+1 starts — activations, edge strips and assembled rims
-      persist across grid steps in ANY-space buffers;
-    * each conv layer emits its block's edge strips (top/bottom (n, tw, C)
-      rows, left/right (n, th, C) columns) with contiguous stores; the
-      interleaved assembly phase gathers them into per-tile halo rims
-      (top/bottom (n, tw+2, C), left/right (n, th, C), inactive donors
-      zero-masked), which the NEXT layer fetches in 4 contiguous loads
-      per tile block instead of 8 masked strip/corner DMAs per tile;
+    * the layer axis is OUTER, so every tile of layer l completes before
+      layer l+1 starts; intermediate layers live in two HBM ping-pong
+      buffers (n+1, th, tw, CP) whose last row stays zero, CP being the
+      widest layer input rounded up to the 128-lane vector width (the
+      HBM tiles hold 128 lanes either way);
+    * each grid step DMAs ``block`` centers in one copy plus every
+      tile's 8 neighbor edges and corners straight into a VMEM window,
+      inactive neighbors reading the zero row;
     * weights are stacked (L, 3, 3, Cmax_in, Cmax_out) and block-indexed
-      by the phase's layer id, so Pallas's pipeline machinery prefetches
-      layer l+1's weights while layer l computes;
-    * ``block`` tiles are processed per grid step (padded up with inert
-      -1-neighbor tiles), so the matmuls are (block*th*tw, C) MXU shapes.
-    """
+      by layer, so the pipeline prefetches layer l+1's weights while
+      layer l computes; ``block`` tiles flatten into the GEMM M
+      dimension."""
     n, th, tw, c0 = packed.shape
     chans = (c0,) + tuple(w.shape[-1] for w in ws)
     L = len(ws)
     if n == 0:
         return jnp.zeros((0, th, tw, chans[-1]), packed.dtype)
-    tb = max(1, min(block, n))
-    n_pad = -(-n // tb) * tb
-    cmax_i = max(chans[:-1])
+    _, tb, n_pad = balanced_split(n, block)
+    cmax_i = contraction_width(max(chans[:-1]))
     cmax_o = max(chans[1:])
+    cp = round_up(cmax_i, LANES)
+    dt = packed.dtype
     wstack = jnp.stack([
         jnp.pad(w, ((0, 0), (0, 0), (0, cmax_i - w.shape[2]),
                     (0, cmax_o - w.shape[3]))) for w in ws])
-    packed_p = jnp.pad(packed, ((0, n_pad - n), (0, 0), (0, 0), (0, 0)))
+    p0 = jnp.pad(packed, ((0, n_pad + 1 - n), (0, 0), (0, 0),
+                          (0, cp - c0)))
     nbr_p = jnp.pad(nbr, ((0, n_pad - n), (0, 0)), constant_values=-1)
-    rims0 = assemble_rims(packed_p, nbr_p)
-    # edge/rim/act buffers carry INTERMEDIATE layers only (the last
-    # layer's output goes straight to o_ref; its rims are never built)
-    c_mid = max(chans[1:-1]) if L > 1 else 1
-    np_mid = n_pad if L > 1 else 1
-    th_mid = th if L > 1 else 1
-    tw_mid = tw if L > 1 else 1
+    nbr_p = jnp.where(nbr_p >= 0, nbr_p, n_pad).astype(jnp.int32)
+    act_rows = n_pad + 1 if L > 1 else 1
     kernel = functools.partial(_roi_conv_stack_kernel, th=th, tw=tw,
                                chans=chans, tb=tb, n_pad=n_pad)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(2 * L - 1, n_pad // tb),
+        grid=(L, n_pad // tb),
         in_specs=[
-            pl.BlockSpec((tb, th, tw, c0),
-                         lambda p, b, nbr_ref: (b, 0, 0, 0)),
-        ] + [pl.BlockSpec(memory_space=_MEMSPACE.ANY)] * 4 + [
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, 3, 3, cmax_i, cmax_o),
-                         lambda p, b, nbr_ref: (p // 2, 0, 0, 0, 0)),
+                         lambda p, b, nbr_ref: (p, 0, 0, 0, 0)),
         ],
-        out_specs=[pl.BlockSpec(memory_space=_MEMSPACE.ANY)] * 10,
+        out_specs=[
+            # only the last layer writes the output; earlier layers keep
+            # its block index at 0 so nothing is written back for them
+            pl.BlockSpec((tb, th, tw, chans[-1]),
+                         lambda p, b, nbr_ref: (
+                             jnp.where(p == L - 1, b, 0), 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((tb, th + 2, window_width(tw), cp), dt),
+            pltpu.VMEM((tb, th, tw, cp), dt),
+            pltpu.VMEM((1, th, tw, cp), dt),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
     )
-    dt = packed.dtype
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((n_pad, th, tw, chans[-1]), dt),
-            jax.ShapeDtypeStruct((np_mid, th_mid, tw_mid, c_mid), dt),
-            jax.ShapeDtypeStruct((np_mid, tw_mid, c_mid), dt),  # top edge
-            jax.ShapeDtypeStruct((np_mid, tw_mid, c_mid), dt),  # bottom
-            jax.ShapeDtypeStruct((np_mid, th_mid, c_mid), dt),  # left
-            jax.ShapeDtypeStruct((np_mid, th_mid, c_mid), dt),  # right
-            jax.ShapeDtypeStruct((np_mid, tw_mid + 2, c_mid), dt),
-            jax.ShapeDtypeStruct((np_mid, tw_mid + 2, c_mid), dt),
-            jax.ShapeDtypeStruct((np_mid, th_mid, c_mid), dt),
-            jax.ShapeDtypeStruct((np_mid, th_mid, c_mid), dt),
+            jax.ShapeDtypeStruct((act_rows, th, tw, cp), dt),
+            jax.ShapeDtypeStruct((act_rows, th, tw, cp), dt),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(nbr_p, packed_p, *rims0, wstack)
+    )(nbr_p.reshape(-1), p0, wstack)
     return out[0][:n]
 
 
 def roi_conv_packed(packed: jax.Array, w: jax.Array, nbr: jax.Array,
-                    *, interpret: bool = True) -> jax.Array:
-    """packed: (n, th, tw, Cin) previous layer's packed output;
-    w: (3, 3, Cin, Cout); nbr: (n, 8) int32 neighbor slots (-1 = zero halo,
-    NEIGHBOR_OFFSETS order).  Returns packed (n, th, tw, Cout) — the SAME
-    conv each active tile would see on the scattered full frame where
-    inactive tiles are zero."""
-    n, th, tw, Cin = packed.shape
-    Cout = w.shape[-1]
-    kernel = functools.partial(_roi_conv_packed_kernel, th=th, tw=tw)
+                    *, interpret: bool) -> jax.Array:
+    """One packed-resident conv layer (no ReLU): packed (n, th, tw, Cin)
+    previous layer's output; w: (3, 3, Cin, Cout); nbr: (n, 8) int32
+    neighbor slots (-1 = zero halo, NEIGHBOR_OFFSETS order).  Returns
+    packed (n, th, tw, Cout) — the SAME conv each active tile would see
+    on the scattered full frame where inactive tiles are zero.  One tile
+    per grid step, halo strips read from the neighbor rows; the layers
+    of ``roi_conv_stack`` compute exactly this."""
+    n, th, tw, cin = packed.shape
+    cout = w.shape[-1]
+    k = contraction_width(cin)
+    w = jnp.pad(w, ((0, 0), (0, 0), (0, k - cin), (0, 0)))
+
+    def kernel(nbr_ref, p_ref, w_ref, o_ref):
+        i = pl.program_id(0)
+
+        def strip(k, ys, ny, xs, nx):
+            slot = nbr_ref[i, k]
+            s = p_ref[pl.ds(jnp.maximum(slot, 0), 1), pl.ds(ys, ny),
+                      pl.ds(xs, nx), :][0]
+            return jnp.where(slot >= 0, s, jnp.zeros_like(s))
+
+        center = p_ref[pl.ds(i, 1)][0]
+        top = jnp.concatenate([strip(0, th - 1, 1, tw - 1, 1),
+                               strip(1, th - 1, 1, 0, tw),
+                               strip(2, th - 1, 1, 0, 1)], axis=1)
+        mid = jnp.concatenate([strip(3, 0, th, tw - 1, 1), center,
+                               strip(4, 0, th, 0, 1)], axis=1)
+        bot = jnp.concatenate([strip(5, 0, 1, tw - 1, 1),
+                               strip(6, 0, 1, 0, tw),
+                               strip(7, 0, 1, 0, 1)], axis=1)
+        win = jnp.concatenate([top, mid, bot], axis=0)[None]
+        win = jnp.pad(win, ((0, 0), (0, 0), (0, 0), (0, k - cin)))
+        o_ref[...] = _conv_taps(win, w_ref[...], th, tw, k).astype(
+            o_ref.dtype)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
-            # packed tensor stays in ANY/HBM; the kernel pulls its own tile
-            # plus 1-deep neighbor edge strips (the halo DMAs are tiny
-            # compared to re-slicing a full frame per layer)
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-            pl.BlockSpec((3, 3, Cin, Cout), lambda i, nbr_ref: (0, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((3, 3, k, cout), lambda i, nbr_ref: (0, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, th, tw, Cout),
+        out_specs=pl.BlockSpec((1, th, tw, cout),
                                lambda i, nbr_ref: (i, 0, 0, 0)),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, th, tw, Cout), packed.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, th, tw, cout), packed.dtype),
         interpret=interpret,
     )(nbr, packed, w)

@@ -13,17 +13,10 @@ Pallas analogue of SBNet's tile-gather warp loop.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.blocking import balanced_split, pad_repeat_last
-
-# pltpu.TPUMemorySpace was renamed MemorySpace across jax versions
-_MEMSPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
 
 
 def _gather_kernel(idx_ref, x_ref, o_ref):
@@ -33,7 +26,7 @@ def _gather_kernel(idx_ref, x_ref, o_ref):
 
 
 def sbnet_gather(x: jax.Array, idx: jax.Array, th: int, tw: int,
-                 *, interpret: bool = True) -> jax.Array:
+                 *, interpret: bool) -> jax.Array:
     """x: (H, W, C), idx: (n, 2) int32 tile coords -> packed (n, th, tw, C)."""
     H, W, C = x.shape
     n = idx.shape[0]
@@ -56,7 +49,7 @@ def sbnet_gather(x: jax.Array, idx: jax.Array, th: int, tw: int,
 
 
 def sbnet_scatter(packed: jax.Array, idx: jax.Array, base: jax.Array,
-                  *, interpret: bool = True) -> jax.Array:
+                  *, interpret: bool) -> jax.Array:
     """packed: (n, th, tw, C) -> write tiles into ``base`` (H, W, C) at the
     tile positions in ``idx``; untouched regions keep base values (the
     output aliases ``base``)."""
@@ -68,7 +61,7 @@ def sbnet_scatter(packed: jax.Array, idx: jax.Array, base: jax.Array,
             pl.BlockSpec((1, th, tw, C), lambda i, idx_ref: (i, 0, 0, 0)),
             # the base is only here to seed the aliased output; ANY keeps
             # the pipeline from DMAing the whole frame on every grid step
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((th, tw, C),
                                lambda i, idx_ref: (idx_ref[i, 0],
@@ -87,83 +80,37 @@ def sbnet_scatter(packed: jax.Array, idx: jax.Array, base: jax.Array,
     )(idx, packed, base)
 
 
-def _scatter_fleet_block_kernel(idx_ref, p_ref, b_ref, o_ref, *, th: int,
-                                tw: int, tb: int):
-    """Blocked scatter walk: one grid step receives a whole (tb, th, tw,
-    C) packed block as ONE contiguous DMA (the read-side analogue of the
-    stack kernel's contiguous-store rim scheme) and fans it out with
-    ``tb`` per-tile dynamic stores.  Padding rows repeat the last real
-    (idx, tile) pair, so their stores rewrite identical bytes — no trash
-    plane, no masked stores."""
-    b = pl.program_id(0)
-    blk = p_ref[...]                             # (tb, th, tw, C)
-    for j in range(tb):
-        cam = idx_ref[b * tb + j, 0]
-        ty = idx_ref[b * tb + j, 1]
-        tx = idx_ref[b * tb + j, 2]
-        pl.store(o_ref, (pl.ds(cam, 1), pl.ds(ty * th, th),
-                         pl.ds(tx * tw, tw), slice(None)),
-                 blk[j][None])
-
-
 def sbnet_scatter_fleet(packed: jax.Array, idx: jax.Array, base: jax.Array,
-                        *, block: int = 1,
-                        interpret: bool = True) -> jax.Array:
+                        *, interpret: bool) -> jax.Array:
     """Cross-camera scatter: ONE launch materializes a whole camera group.
 
     packed: (n, th, tw, C); idx: (n, 3) int32 (cam, ty, tx); base:
     (num_cams, H, W, C) stacked frames.  Writes tile i into camera
     idx[i, 0]'s plane; untouched regions keep base values.
 
-    ``block`` > 1 blocks the tile walk (grid = (tile_block,)): each step
-    pulls ``block`` packed tiles in one contiguous load and issues their
-    stores back-to-back — same per-tile write pattern, 1/block the grid
-    steps.  Both index list and packed tensor are padded with repeats of
-    their last row, so padding stores are idempotent rewrites of the last
-    real tile (bit-identical to the per-tile walk by construction).
+    One tile per grid step: the output BlockSpec's index map reads the
+    scalar-prefetched (cam, ty, tx) row, so the pipeline writes each
+    (th, tw, C) tile straight to its place in the aliased canvas.
+    Repeated rows (callers pad with repeats of the last tile) rewrite
+    identical bytes.
 
     An EMPTY tile set is a no-op: the base is returned untouched and no
-    pallas_call is formed at all (the per-tile walk used to build a
-    grid=(0,) launch here)."""
+    pallas_call is formed at all."""
     n, th, tw, C = packed.shape
     if n == 0:
         return base
-    if block > 1:
-        nb, tb, n_pad = balanced_split(n, block)
-        idx = pad_repeat_last(idx, n_pad)
-        packed = pad_repeat_last(packed, n_pad)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_pad // tb,),
-            in_specs=[
-                pl.BlockSpec((tb, th, tw, C),
-                             lambda b, idx_ref: (b, 0, 0, 0)),
-                # aliased seed only — ANY avoids a whole-canvas DMA/step
-                pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-            ],
-            out_specs=pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-        )
-        kernel = functools.partial(_scatter_fleet_block_kernel, th=th,
-                                   tw=tw, tb=tb)
-        return pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(base.shape, base.dtype),
-            input_output_aliases={2: 0},   # (idx, packed, base) -> out
-            interpret=interpret,
-        )(idx, packed, base)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
             pl.BlockSpec((1, th, tw, C), lambda i, idx_ref: (i, 0, 0, 0)),
             # aliased seed only — ANY avoids a whole-canvas DMA per step
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, th, tw, C),
-                               lambda i, idx_ref: (idx_ref[i, 0],
-                                                   idx_ref[i, 1],
-                                                   idx_ref[i, 2], 0)),
+                               lambda i, idx_ref: (idx_ref[3 * i],
+                                                   idx_ref[3 * i + 1],
+                                                   idx_ref[3 * i + 2], 0)),
     )
 
     def kernel(idx_ref, p_ref, b_ref, o_ref):
@@ -175,17 +122,16 @@ def sbnet_scatter_fleet(packed: jax.Array, idx: jax.Array, base: jax.Array,
         out_shape=jax.ShapeDtypeStruct(base.shape, base.dtype),
         input_output_aliases={2: 0},   # args: (idx, packed, base) -> out
         interpret=interpret,
-    )(idx, packed, base)
+    )(idx.reshape(-1), packed, base)
 
 
 def sbnet_scatter_changed(packed: jax.Array, idx: jax.Array,
-                          base: jax.Array, *, block: int = 1,
-                          interpret: bool = True) -> jax.Array:
+                          base: jax.Array, *, interpret: bool) -> jax.Array:
     """Changed-only scatter into a PERSISTENT canvas: O(changed) bytes.
 
-    Same store machinery as ``sbnet_scatter_fleet`` (blocked walk,
-    scalar-prefetched (cam, ty, tx) rows, aliased/donated base), but the
-    contract is different: ``base`` is the PREVIOUS step's device-resident
+    Same store machinery as ``sbnet_scatter_fleet`` (scalar-prefetched
+    (cam, ty, tx) rows, aliased/donated base), but the contract is
+    different: ``base`` is the PREVIOUS step's device-resident
     head-map canvas and ``packed``/``idx`` carry ONLY the tiles whose
     content changed this step.  Unchanged tiles pass through untouched —
     their canvas bytes were written by the step that last computed them —
@@ -193,5 +139,4 @@ def sbnet_scatter_changed(packed: jax.Array, idx: jax.Array,
     active set while writing ``n_changed`` tiles instead of ``n_active``.
     An empty changed set returns the canvas with zero launches (the
     all-static step writes 0 canvas bytes)."""
-    return sbnet_scatter_fleet(packed, idx, base, block=block,
-                               interpret=interpret)
+    return sbnet_scatter_fleet(packed, idx, base, interpret=interpret)

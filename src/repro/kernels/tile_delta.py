@@ -5,11 +5,7 @@ tile, how many bytes the tile would cost to ship *this* frame — cheap,
 static tiles are the ones whose quality can be shed under uplink backlog.
 The estimator is the structural core of an inter-frame codec: quantize the
 temporal delta, then price it as entropy-coded (nonzero coefficient,
-zero-run) tokens.
-
-One kernel, grid=(n_active,), scalar-prefetched tile index list exactly
-like the sbnet gather: per grid step it DMAs the (th, tw, C) tile from the
-current AND previous frame (both stay in ANY/HBM), computes
+zero-run) tokens:
 
     q     = round((cur - prev) / qstep)            # int32 coefficients
     nnz   = #(q != 0)
@@ -17,12 +13,14 @@ current AND previous frame (both stay in ANY/HBM), computes
     bytes = ceil((nnz * coef_bits + runs * run_bits) / 8)
 
 entirely in integer ops (bit-exact by construction against the numpy
-reference in ``kernels/ref.py``), and writes one (8,) int32 stats row:
-``[bytes, nnz, runs, sum|q|, 0, 0, 0, 0]`` (lane-padded).
+reference in ``kernels/ref.py``).  Row-independent run counting (a zero
+run never joins across rows) is the *definition* of the estimate: a
+row's runs are its zeros minus its adjacent zero pairs, which the kernel
+counts with masked reductions — no sequential carry.
 
-Row-independent run counting (a zero run never joins across the th rows)
-keeps the scan a pure shifted-compare on the VPU — no sequential carry —
-and is the *definition* of the estimate, mirrored by the reference.
+One kernel, ``tile_delta_gate_canvas``, prices every active tile of the
+stacked fleet for BOTH consumers per step; ``tile_delta`` (one camera,
+body stats only) is the same launch.
 """
 from __future__ import annotations
 
@@ -33,10 +31,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.blocking import balanced_split, pad_repeat_last
-
-# pltpu.TPUMemorySpace was renamed MemorySpace across jax versions
-_MEMSPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
+from repro.kernels.blocking import (VMEM_LIMIT_BYTES, balanced_split,
+                                    pad_frames, pad_repeat_last,
+                                    widen_padded)
+from repro.kernels.roi_conv import MAX_WINDOWS_PER_STEP, window_specs
 
 # entropy-coder token prices (bits): a nonzero coefficient token and a
 # zero-run token.  Calibration constants, not tunables-per-call — keeping
@@ -44,7 +42,7 @@ _MEMSPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
 COEF_BITS = 6
 RUN_BITS = 10
 
-STATS_WIDTH = 8          # output lane padding; cols 0..3 are live
+STATS_WIDTH = 8          # output lane padding; cols 0..5 are live
 
 # ``tile_delta_gate`` stats-row columns.  Cols 0..3 are the BODY stats and
 # match ``tile_delta`` / ``ref.tile_delta`` bit for bit (so the rate
@@ -58,69 +56,6 @@ GATE_WIN_EXACT = 4       # exact count of (th+2, tw+2, C) positions that
 #                          differ bitwise — the threshold-0 gate signal
 GATE_WIN_BYTES = 5       # quantized zero-run byte estimate of the window
 
-
-def _tile_stats(cur: jax.Array, prev: jax.Array, qstep: float,
-                coef_bits: int, run_bits: int) -> jax.Array:
-    """(th, tw, C) pair -> (STATS_WIDTH,) int32 [bytes, nnz, runs, sum|q|]."""
-    th = cur.shape[0]
-    q = jnp.round((cur.astype(jnp.float32) - prev.astype(jnp.float32))
-                  / qstep).astype(jnp.int32)
-    z2 = (q == 0).reshape(th, -1)                   # (th, tw*C) scan rows
-    nnz = jnp.sum((~z2).astype(jnp.int32))
-    # a zero run starts where z is set and the previous lane (same row)
-    # is not; the first lane of every row always starts a run if zero
-    left = jnp.concatenate(
-        [jnp.zeros((th, 1), bool), z2[:, :-1]], axis=1)
-    runs = jnp.sum((z2 & ~left).astype(jnp.int32))
-    sabs = jnp.sum(jnp.abs(q))
-    nbytes = (nnz * coef_bits + runs * run_bits + 7) // 8
-    out = jnp.zeros((STATS_WIDTH,), jnp.int32)
-    return out.at[0].set(nbytes).at[1].set(nnz).at[2].set(runs) \
-              .at[3].set(sabs)
-
-
-def _tile_delta_kernel(idx_ref, cur_ref, prev_ref, o_ref, *, th: int,
-                       tw: int, qstep: float, coef_bits: int,
-                       run_bits: int):
-    i = pl.program_id(0)
-    ty = idx_ref[i, 0]
-    tx = idx_ref[i, 1]
-    sel = (pl.ds(ty * th, th), pl.ds(tx * tw, tw), slice(None))
-    cur = pl.load(cur_ref, sel)
-    prev = pl.load(prev_ref, sel)
-    o_ref[0] = _tile_stats(cur, prev, qstep, coef_bits, run_bits)
-
-
-def tile_delta(cur: jax.Array, prev: jax.Array, idx: jax.Array, th: int,
-               tw: int, qstep: float = 8.0, coef_bits: int = COEF_BITS,
-               run_bits: int = RUN_BITS, *,
-               interpret: bool = True) -> jax.Array:
-    """cur, prev: (H, W, C) frames; idx: (n, 2) int32 active-tile coords.
-    Returns (n, STATS_WIDTH) int32 per-tile stats rows:
-    ``[byte_estimate, nnz, zero_runs, sum_abs_q, 0...]``."""
-    n = idx.shape[0]
-    kernel = functools.partial(_tile_delta_kernel, th=th, tw=tw,
-                               qstep=qstep, coef_bits=coef_bits,
-                               run_bits=run_bits)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[
-            # both frames stay in ANY/HBM; the kernel slices its own tile
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, STATS_WIDTH),
-                               lambda i, idx_ref: (i, 0)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, STATS_WIDTH), jnp.int32),
-        interpret=interpret,
-    )(idx, cur, prev)
-
-
 # ---------------------------------------------------------------------------
 # reuse-gate delta pricing (haloed input windows on the stacked fleet)
 # ---------------------------------------------------------------------------
@@ -133,188 +68,195 @@ def tile_delta(cur: jax.Array, prev: jax.Array, idx: jax.Array, th: int,
 # One kernel prices both views per tile so the rate controller (body
 # stats, cols 0..3, bit-compatible with ``tile_delta``) and the reuse
 # gate (window stats, cols 4..5) share a single dispatch per fleet step.
-# The current frame arrives zero-PADDED (C, H+2, W+2, Cin) so every
-# window load is a static-size in-bounds slice (pad-ring deltas are 0-0;
-# the numpy reference ``ref.tile_delta_gate`` mirrors the padding); the
-# comparison side is a PACKED (n, th+2, tw+2, Cin) per-tile reference —
-# each tile's window content as of ITS last refresh — and the kernel
-# additionally emits the current windows so callers advance refreshed
-# tiles' references with one on-device row update.
+# The frames arrive zero-PADDED (C, H+2, W', Cin) so every window fetch
+# is a static-size in-bounds block (pad-ring deltas are 0-0; the numpy
+# reference ``ref.tile_delta_gate`` mirrors the padding).
 
 
-def _batched_stats(cur, prev, qstep: float, coef_bits: int,
-                   run_bits: int):
-    """(tb, rows, cols, C) window-pair block -> per-tile (bytes, nnz,
-    runs) int32 vectors, the same integer math as ``_tile_stats`` with
-    the tile axis batched (one VPU pass for the whole block instead of
-    ``tb`` unrolled scans)."""
-    tb, rows = cur.shape[0], cur.shape[1]
+def _window_stats(cur, prev, th: int, tw: int, qstep: float,
+                  coef_bits: int, run_bits: int) -> jax.Array:
+    """(tb, th+2, X >= tw+2, C) window pairs -> (tb, 1, 1, STATS_WIDTH)
+    int32 rows [body bytes, nnz, runs, sum|q|, window exact, window
+    bytes, 0, 0].  Columns past tw+2 are masked out."""
+    tb, rows, cols, c = cur.shape
+    i32 = jnp.int32
     q = jnp.round((cur.astype(jnp.float32) - prev.astype(jnp.float32))
-                  / qstep).astype(jnp.int32)
-    z2 = (q == 0).reshape(tb, rows, -1)
-    nnz = jnp.sum((~z2).astype(jnp.int32), axis=(1, 2))
-    left = jnp.concatenate(
-        [jnp.zeros((tb, rows, 1), bool), z2[:, :, :-1]], axis=2)
-    runs = jnp.sum((z2 & ~left).astype(jnp.int32), axis=(1, 2))
-    nbytes = (nnz * coef_bits + runs * run_bits + 7) // 8
-    return nbytes, nnz, runs, jnp.sum(jnp.abs(q), axis=(1, 2, 3))
+                  / qstep).astype(i32)
+    lane = jax.lax.broadcasted_iota(i32, q.shape, 3)
+    zero = (q == 0).astype(i32)
+    # per-pixel channel planes of the zero mask, (tb, rows, cols, 1)
+    zc = [jnp.sum(jnp.where(lane == k, zero, 0), axis=3, keepdims=True)
+          for k in range(c)]
+    zeros_px = functools.reduce(jnp.add, zc)
+    # adjacent zero pairs in a scan row's (x, c) order: channel k-1 -> k
+    # inside a pixel, and the last channel of x-1 -> channel 0 of x
+    within_px = functools.reduce(
+        jnp.add, [zc[k - 1] * zc[k] for k in range(1, c)],
+        jnp.zeros_like(zeros_px))
+    cross = zc[c - 1][:, :, :-1] * zc[0][:, :, 1:]     # ends at x = 1..
+    sabs_px = jnp.sum(jnp.abs(q), axis=3, keepdims=True)
+    diff_px = jnp.sum((cur != prev).astype(i32), axis=3, keepdims=True)
+    ys = jax.lax.broadcasted_iota(i32, zeros_px.shape, 1)
+    xs = jax.lax.broadcasted_iota(i32, zeros_px.shape, 2)
+    yc = jax.lax.broadcasted_iota(i32, cross.shape, 1)
+    xc = jax.lax.broadcasted_iota(i32, cross.shape, 2) + 1
+
+    def total(v, m):
+        # one axis at a time: Mosaic aborts on a 3-axis keepdims reduce
+        v = jnp.where(m, v, 0)
+        for ax in (3, 2, 1):
+            v = jnp.sum(v, axis=ax, keepdims=True)
+        return v
+
+    def region(y0, y1, x0, x1):
+        m = (ys >= y0) & (ys < y1) & (xs >= x0) & (xs < x1)
+        mc = (yc >= y0) & (yc < y1) & (xc > x0) & (xc < x1)
+        zeros = total(zeros_px, m)
+        nnz = total(c - zeros_px, m)
+        runs = zeros - total(within_px, m) - total(cross, mc)
+        nbytes = (nnz * coef_bits + runs * run_bits + 7) // 8
+        return m, nbytes, nnz, runs
+
+    mb, b_bytes, b_nnz, b_runs = region(1, th + 1, 1, tw + 1)
+    mw, w_bytes, _, _ = region(0, th + 2, 0, tw + 2)
+    vals = {GATE_BODY_BYTES: b_bytes, GATE_BODY_NNZ: b_nnz,
+            GATE_BODY_RUNS: b_runs, GATE_BODY_SABS: total(sabs_px, mb),
+            GATE_WIN_EXACT: total(diff_px, mw), GATE_WIN_BYTES: w_bytes}
+    col = jax.lax.broadcasted_iota(i32, (tb, 1, 1, STATS_WIDTH), 3)
+    out = jnp.zeros((tb, 1, 1, STATS_WIDTH), i32)
+    for k, v in vals.items():
+        out = jnp.where(col == k, v, out)
+    return out
 
 
-def _tile_delta_gate_kernel(idx_ref, cur_ref, ref_ref, o_ref, w_ref, *,
-                            th: int, tw: int, tb: int, qstep: float,
-                            coef_bits: int, run_bits: int):
-    b = pl.program_id(0)
-    curs = []
+def _gate_canvas_kernel(idx_ref, *refs, th: int, tw: int, tb: int,
+                        qstep: float, coef_bits: int, run_bits: int):
+    o_ref = refs[2 * tb]
+    # one tile at a time keeps the stats temporaries to one window's VMEM
     for j in range(tb):
-        cam = idx_ref[b * tb + j, 0]
-        ty = idx_ref[b * tb + j, 1]
-        tx = idx_ref[b * tb + j, 2]
-        # the haloed (th+2, tw+2, C) window: on the padded plane the
-        # window of tile (ty, tx) starts at (ty*th, tx*tw)
-        sel = (pl.ds(cam, 1), pl.ds(ty * th, th + 2),
-               pl.ds(tx * tw, tw + 2), slice(None))
-        curs.append(pl.load(cur_ref, sel)[0])
-    cur = jnp.stack(curs)                    # (tb, th+2, tw+2, C)
-    prev = ref_ref[...]                      # the block's PACKED refs
-    body = _batched_stats(cur[:, 1:1 + th, 1:1 + tw],
-                          prev[:, 1:1 + th, 1:1 + tw], qstep, coef_bits,
-                          run_bits)
-    # window stats: quantized byte estimate (rows = th+2 scan rows, same
-    # row-independent run rule as the body) + the EXACT bitwise change
-    # count the threshold-0 gate keys on (quantization rounds small
-    # deltas to zero; bit-identity needs the raw comparison)
-    win_bytes, _, _, _ = _batched_stats(cur, prev, qstep, coef_bits,
-                                        run_bits)
-    exact = jnp.sum((cur != prev).astype(jnp.int32), axis=(1, 2, 3))
-    out = jnp.zeros((tb, STATS_WIDTH), jnp.int32)
-    out = out.at[:, 0].set(body[0]).at[:, 1].set(body[1]) \
-             .at[:, 2].set(body[2]).at[:, 3].set(body[3]) \
-             .at[:, GATE_WIN_EXACT].set(exact) \
-             .at[:, GATE_WIN_BYTES].set(win_bytes)
-    o_ref[...] = out
-    w_ref[...] = cur                         # current windows, packed
+        o_ref[j:j + 1] = _window_stats(refs[j][...], refs[tb + j][...], th,
+                                       tw, qstep, coef_bits, run_bits)
+
+
+def tile_delta_gate_canvas(cur_p: jax.Array, ref_c: jax.Array,
+                           idx: jax.Array, th: int, tw: int,
+                           qstep: float = 8.0, coef_bits: int = COEF_BITS,
+                           run_bits: int = RUN_BITS, *, block: int,
+                           interpret: bool) -> jax.Array:
+    """The reuse gate's shared delta dispatch with CANVAS-RESIDENT
+    references: cur_p and ref_c are zero-padded (C, H+2, W', Cin) frame
+    canvases of the same shape (``blocking.pad_frames``; a plain 1-px
+    ring is widened here), addressed through the same (n, 3) (cam, ty,
+    tx) rows.  Returns (n, STATS_WIDTH) int32 rows — cols 0..3 the BODY
+    delta stats (equal to ``tile_delta`` when the references hold the
+    previous frame), col 4 the exact bitwise change count of the haloed
+    window, col 5 its quantized byte estimate.  Bit-exact vs
+    ``ref.tile_delta_gate``.  ``block`` tiles (at most
+    ``roi_conv.MAX_WINDOWS_PER_STEP``) share a grid step; per-tile
+    refresh epochs are tracked host-side (serving/detector)."""
+    n = idx.shape[0]
+    if n == 0:
+        return jnp.zeros((0, STATS_WIDTH), jnp.int32)
+    cur_p = widen_padded(cur_p, tw)
+    ref_c = widen_padded(ref_c, tw)
+    cin = cur_p.shape[-1]
+    _, tb, n_pad = balanced_split(n, min(max(block, 1),
+                                         MAX_WINDOWS_PER_STEP))
+    idx = pad_repeat_last(idx, n_pad)
+    kernel = functools.partial(_gate_canvas_kernel, th=th, tw=tw, tb=tb,
+                               qstep=qstep, coef_bits=coef_bits,
+                               run_bits=run_bits)
+    specs = window_specs(tb, th, tw, cin)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_pad // tb,),
+        in_specs=specs + specs,
+        out_specs=pl.BlockSpec((tb, 1, 1, STATS_WIDTH),
+                               lambda b, idx_ref: (b, 0, 0, 0)),
+    )
+    stats = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_pad, 1, 1, STATS_WIDTH),
+                                       jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(idx.reshape(-1), *([cur_p] * tb), *([ref_c] * tb))
+    return stats.reshape(n_pad, STATS_WIDTH)[:n]
+
+
+def _gate_packed_kernel(idx_ref, *refs, th: int, tw: int, tb: int,
+                        qstep: float, coef_bits: int, run_bits: int):
+    ref_ref, o_ref, w_ref = refs[tb:]
+    for j in range(tb):
+        cur = refs[j][...][:, :, :tw + 2]
+        o_ref[j:j + 1] = _window_stats(cur, ref_ref[j:j + 1], th, tw, qstep,
+                                       coef_bits, run_bits)
+        w_ref[j:j + 1] = cur                 # current windows, packed
 
 
 def tile_delta_gate(cur_p: jax.Array, ref_win: jax.Array, idx: jax.Array,
                     th: int, tw: int, qstep: float = 8.0,
                     coef_bits: int = COEF_BITS, run_bits: int = RUN_BITS,
-                    *, block: int = 1, interpret: bool = True):
-    """cur_p: (C, H+2, W+2, Cin) zero-padded stacked fleet frames;
-    ref_win: (n, th+2, tw+2, Cin) PACKED per-tile reference windows (each
-    tile's haloed window content as of that tile's last refresh — packed
-    rows, not a canvas, so one tile's reference can never alias a
-    neighbor's through the window overlap); idx: (n, 3) int32
-    (cam, ty, tx) coords.  Returns (stats, windows): stats (n,
-    STATS_WIDTH) int32 rows — cols 0..3 the BODY delta stats (equal to
-    ``tile_delta`` when the references hold the previous frame), col 4
-    the exact bitwise change count of the haloed window, col 5 its
-    quantized byte estimate — and windows (n, th+2, tw+2, Cin), the
-    CURRENT haloed windows, so callers advance references with a pure
-    on-device ``.at[rows].set(windows[rows])`` (no second gather, no
-    host round-trip).  Bit-exact vs ``ref.tile_delta_gate``.  ``block``
-    > 1 blocks the walk exactly like the blocked entry kernel."""
+                    *, block: int, interpret: bool):
+    """The gate against PACKED per-tile references: same stats rows as
+    ``tile_delta_gate_canvas``, but the comparison side is ref_win (n,
+    th+2, tw+2, Cin) — each tile's haloed window content as of that
+    tile's last refresh — so one tile's reference can never alias a
+    neighbor's through the window overlap.  Returns (stats, windows):
+    windows (n, th+2, tw+2, Cin) are the CURRENT haloed windows, so
+    callers advance references with a pure on-device
+    ``.at[rows].set(windows[rows])``.  Bit-exact vs
+    ``ref.tile_delta_gate``."""
     n = idx.shape[0]
-    nb, tb, n_pad = balanced_split(n, block)
+    cur_p = widen_padded(cur_p, tw)
+    cin = cur_p.shape[-1]
+    _, tb, n_pad = balanced_split(n, min(max(block, 1),
+                                         MAX_WINDOWS_PER_STEP))
     idx = pad_repeat_last(idx, n_pad)
     ref_win = pad_repeat_last(ref_win, n_pad)
-    Cin = cur_p.shape[-1]
-    kernel = functools.partial(_tile_delta_gate_kernel, th=th, tw=tw,
-                               tb=tb, qstep=qstep, coef_bits=coef_bits,
+    kernel = functools.partial(_gate_packed_kernel, th=th, tw=tw, tb=tb,
+                               qstep=qstep, coef_bits=coef_bits,
                                run_bits=run_bits)
+    win_spec = pl.BlockSpec((tb, th + 2, tw + 2, cin),
+                            lambda b, idx_ref: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_pad // tb,),
-        in_specs=[
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-            pl.BlockSpec((tb, th + 2, tw + 2, Cin),
-                         lambda b, idx_ref: (b, 0, 0, 0)),
-        ],
+        in_specs=window_specs(tb, th, tw, cin) + [win_spec],
         out_specs=[
-            pl.BlockSpec((tb, STATS_WIDTH), lambda b, idx_ref: (b, 0)),
-            pl.BlockSpec((tb, th + 2, tw + 2, Cin),
+            pl.BlockSpec((tb, 1, 1, STATS_WIDTH),
                          lambda b, idx_ref: (b, 0, 0, 0)),
+            win_spec,
         ],
     )
     stats, wins = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad, STATS_WIDTH), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad, th + 2, tw + 2, Cin),
+            jax.ShapeDtypeStruct((n_pad, 1, 1, STATS_WIDTH), jnp.int32),
+            jax.ShapeDtypeStruct((n_pad, th + 2, tw + 2, cin),
                                  cur_p.dtype),
         ],
         interpret=interpret,
-    )(idx, cur_p, ref_win)
-    return stats[:n], wins[:n]
+    )(idx.reshape(-1), *([cur_p] * tb), ref_win)
+    return stats.reshape(n_pad, STATS_WIDTH)[:n], wins[:n]
 
 
-def _tile_delta_gate_canvas_kernel(idx_ref, cur_ref, refc_ref, o_ref, *,
-                                   th: int, tw: int, tb: int, qstep: float,
-                                   coef_bits: int, run_bits: int):
-    b = pl.program_id(0)
-    curs, prevs = [], []
-    for j in range(tb):
-        cam = idx_ref[b * tb + j, 0]
-        ty = idx_ref[b * tb + j, 1]
-        tx = idx_ref[b * tb + j, 2]
-        sel = (pl.ds(cam, 1), pl.ds(ty * th, th + 2),
-               pl.ds(tx * tw, tw + 2), slice(None))
-        curs.append(pl.load(cur_ref, sel)[0])
-        prevs.append(pl.load(refc_ref, sel)[0])
-    cur = jnp.stack(curs)                    # (tb, th+2, tw+2, C)
-    prev = jnp.stack(prevs)                  # reference windows, canvas
-    body = _batched_stats(cur[:, 1:1 + th, 1:1 + tw],
-                          prev[:, 1:1 + th, 1:1 + tw], qstep, coef_bits,
-                          run_bits)
-    win_bytes, _, _, _ = _batched_stats(cur, prev, qstep, coef_bits,
-                                        run_bits)
-    exact = jnp.sum((cur != prev).astype(jnp.int32), axis=(1, 2, 3))
-    out = jnp.zeros((tb, STATS_WIDTH), jnp.int32)
-    out = out.at[:, 0].set(body[0]).at[:, 1].set(body[1]) \
-             .at[:, 2].set(body[2]).at[:, 3].set(body[3]) \
-             .at[:, GATE_WIN_EXACT].set(exact) \
-             .at[:, GATE_WIN_BYTES].set(win_bytes)
-    o_ref[...] = out
-
-
-def tile_delta_gate_canvas(cur_p: jax.Array, ref_c: jax.Array,
-                           idx: jax.Array, th: int, tw: int,
-                           qstep: float = 8.0, coef_bits: int = COEF_BITS,
-                           run_bits: int = RUN_BITS, *, block: int = 1,
-                           interpret: bool = True) -> jax.Array:
-    """The gate with CANVAS-RESIDENT references: same pricing math as
-    ``tile_delta_gate`` (identical stats columns, bit-exact when both
-    views hold the same reference content), but the comparison side is a
-    (C, H+2, W+2, Cin) reference CANVAS addressed through the same
-    (cam, ty, tx) rows as the current frame — no packed (n, th+2, tw+2)
-    duplication (~1.3x the canvas bytes) and no windows output at all:
-    reference advancement writes window regions of the canvas from the
-    current frame, so the kernel's write side is stats rows only.
-    ``cur_p`` and ``ref_c`` have the SAME padded shape; per-tile refresh
-    epochs are tracked host-side (serving/detector)."""
-    n = idx.shape[0]
-    nb, tb, n_pad = balanced_split(n, block)
-    idx = pad_repeat_last(idx, n_pad)
-    kernel = functools.partial(_tile_delta_gate_canvas_kernel, th=th,
-                               tw=tw, tb=tb, qstep=qstep,
-                               coef_bits=coef_bits, run_bits=run_bits)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_pad // tb,),
-        in_specs=[
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-        ],
-        out_specs=pl.BlockSpec((tb, STATS_WIDTH),
-                               lambda b, idx_ref: (b, 0)),
-    )
-    stats = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_pad, STATS_WIDTH), jnp.int32),
-        interpret=interpret,
-    )(idx, cur_p, ref_c)
-    return stats[:n]
+def tile_delta(cur: jax.Array, prev: jax.Array, idx: jax.Array, th: int,
+               tw: int, qstep: float = 8.0, coef_bits: int = COEF_BITS,
+               run_bits: int = RUN_BITS, *, interpret: bool) -> jax.Array:
+    """cur, prev: (H, W, C) frames; idx: (n, 2) int32 active-tile coords.
+    Returns (n, STATS_WIDTH) int32 per-tile stats rows:
+    ``[byte_estimate, nnz, zero_runs, sum_abs_q, 0...]`` — the body
+    columns of ``tile_delta_gate_canvas`` on the one camera."""
+    idx3 = jnp.concatenate([jnp.zeros_like(idx[:, :1]), idx], axis=1)
+    stats = tile_delta_gate_canvas(
+        pad_frames(cur[None], tw), pad_frames(prev[None], tw), idx3, th,
+        tw, qstep, coef_bits, run_bits, block=1, interpret=interpret)
+    body = jnp.arange(STATS_WIDTH) <= GATE_BODY_SABS
+    return jnp.where(body, stats, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -348,26 +290,26 @@ def _tile_delta_halo_kernel(idx_ref, cur_ref, prev_ref, o_ref, *, th: int,
             (pl.ds(y0, th), pl.ds(x0 + tw - 1, 1))]
     nnz = runs = sabs = jnp.asarray(0, jnp.int32)
     for sel in sels:
-        c = pl.load(cur_ref, sel + (slice(None),))
-        p = pl.load(prev_ref, sel + (slice(None),))
-        dn, dr, ds_ = _halo_strip_stats(c, p, qstep)
+        dn, dr, ds_ = _halo_strip_stats(cur_ref[sel], prev_ref[sel], qstep)
         nnz, runs, sabs = nnz + dn, runs + dr, sabs + ds_
     nbytes = (nnz * coef_bits + runs * run_bits + 7) // 8
-    out = jnp.zeros((STATS_WIDTH,), jnp.int32)
-    o_ref[0] = out.at[0].set(nbytes).at[1].set(nnz).at[2].set(runs) \
-                  .at[3].set(sabs)
+    col = jnp.arange(STATS_WIDTH)
+    o_ref[0] = jnp.where(col == 0, nbytes, 0) + jnp.where(col == 1, nnz, 0) \
+        + jnp.where(col == 2, runs, 0) + jnp.where(col == 3, sabs, 0)
 
 
 def tile_delta_halo(cur: jax.Array, prev: jax.Array, idx: jax.Array,
                     th: int, tw: int, qstep: float = 8.0,
                     coef_bits: int = COEF_BITS, run_bits: int = RUN_BITS,
-                    *, interpret: bool = True) -> jax.Array:
+                    *, interpret: bool) -> jax.Array:
     """Delta stats of each active tile's HALO RING (top/bottom rows +
     left/right columns, corners counted in both — the duplicated boundary
     pixels behind the codec model's ``k/sqrt(area)`` surcharge).  Same
     stats row layout as ``tile_delta``; bit-exact vs
     ``ref.tile_delta_halo``.  Lets the rate controller shed halo rows
-    whose content is temporally static before touching whole tiles."""
+    whose content is temporally static before touching whole tiles.
+    One tile per grid step, strips read from the whole frames (an
+    edge-encoder path, not part of the served fleet step)."""
     n = idx.shape[0]
     kernel = functools.partial(_tile_delta_halo_kernel, th=th, tw=tw,
                                qstep=qstep, coef_bits=coef_bits,
@@ -376,8 +318,8 @@ def tile_delta_halo(cur: jax.Array, prev: jax.Array, idx: jax.Array,
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, STATS_WIDTH),
                                lambda i, idx_ref: (i, 0)),

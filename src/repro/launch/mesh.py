@@ -11,20 +11,30 @@ dryrun.py forces the 512-device platform).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: shardings are propagated by the
+    compiler from the inputs' and constraints' placements, which is what
+    every caller here is written for (``jax.make_mesh`` itself defaults
+    to Explicit axes)."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pod: int = 1):
     """Small mesh for in-test lowering on host platforms with few fake
     devices."""
     if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # fleet-serving mesh axis: camera groups shard over it (zero cross-group
@@ -47,7 +57,7 @@ def make_fleet_mesh(n_shards: int = 0):
             f"make_fleet_mesh({n_shards}): only {avail} device(s) visible; "
             f"set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
             f"before jax initializes to simulate more on CPU")
-    return jax.make_mesh((n,), (FLEET_AXIS,))
+    return make_mesh((n,), (FLEET_AXIS,))
 
 
 # v5e hardware constants for the roofline (per chip)

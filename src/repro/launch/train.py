@@ -11,11 +11,10 @@ from __future__ import annotations
 
 import argparse
 
-import jax
-
 from repro.configs.base import TrainConfig
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.distributed.fault import FaultInjector
+from repro.launch.mesh import make_mesh
 from repro.train.loop import train
 
 
@@ -48,7 +47,7 @@ def main():
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
     injector = FaultInjector((args.fail_at,)) if args.fail_at else None
     report = train(cfg, tcfg, steps=args.steps,
                    batch_shape=(args.batch, args.seq), mesh=mesh,

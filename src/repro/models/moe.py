@@ -28,7 +28,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.dist import DistContext
 from repro.models.layers import glu_mlp
@@ -143,7 +142,7 @@ def moe_layer(
             return (jax.lax.psum(out, maxis),
                     jax.lax.psum(dropped, all_axes) / n_all)
 
-        y, dropped = shard_map(
+        y, dropped = jax.shard_map(
             inner,
             mesh=dist.mesh,
             in_specs=(spec_x, spec_x, spec_x,

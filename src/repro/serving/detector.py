@@ -4,9 +4,9 @@ The online-phase server model (paper §4.4), with the packed representation
 persistent across the whole stack AND the whole launch chain fused to a
 constant number of dispatches: layer 0 is the fused gather+conv+relu
 entry kernel (``roi_conv_entry`` reads haloed windows straight from the
-stacked frames — the *one* gather — and emits coalesced rim halos),
-layers 1..N-1 run inside ONE ``roi_conv_stack`` megakernel (grid over
-(layer, tile), double-buffered activations/rims, per-layer weight
+stacked frames — the *one* gather), layers 1..N-1 run inside ONE
+``roi_conv_stack`` megakernel (grid over (layer, tile block), ping-pong
+activations in HBM, halos DMA'd from the neighbor rows, per-layer weight
 prefetch), and a *single* scatter materializes the full-frame head maps.
 Every RoI forward — one camera, one group, or the WHOLE FLEET via
 ``superlaunch_forward`` — is exactly 3 dispatches (2 for a 1-layer
@@ -53,9 +53,9 @@ class DetectorConfig:
     tile: int = 16                            # feature-map tile (TPU block)
     num_anchors: int = 2
     switch_density: float = 0.70
-    # VMEM budget the entry/stack/scatter tile-block is sized against
-    # (ops.choose_block); 16 MiB = one TPU core's VMEM.
-    vmem_budget_bytes: int = 16 * 2 ** 20
+    # VMEM budget the entry/stack/gate tile-block is sized against
+    # (ops.choose_block): 3/4 of the kernels' 32 MiB scoped-VMEM limit
+    vmem_budget_bytes: int = 24 * 2 ** 20
 
 
 @dataclass
@@ -182,8 +182,8 @@ class PackedActivationCache:
     the delta gate's reference content in one of two modes:
 
     * ``ref_mode="canvas"`` (default): a second padded canvas
-      (``ref_canvas``, (C, H+2, W+2, 3), same shape as the stacked
-      frames the gate reads) holding each tile's window content as of
+      (``ref_canvas``, same shape as the padded stacked frames the
+      gate reads, ``ops.pad_frames``) holding each tile's window content as of
       its last refresh, plus an (n,) per-tile refresh-EPOCH vector
       advanced by ``ref_advance_rows`` — no per-tile window duplication
       (packed windows store every overlap rim twice, ~1.3x the canvas
@@ -220,7 +220,7 @@ class PackedActivationCache:
         self.packed: Optional[jax.Array] = None   # (n, th, tw, C_last)
         self.canvas: Optional[jax.Array] = None   # (C, H, W, A) head maps
         self.ref_win: Optional[jax.Array] = None  # (n, th+2, tw+2, 3)
-        self.ref_canvas: Optional[jax.Array] = None  # (C, H+2, W+2, 3)
+        self.ref_canvas: Optional[jax.Array] = None  # (C, H+2, W', 3)
         self.epoch_np: Optional[np.ndarray] = None   # (n,) last refresh
         self.idx_np: Optional[np.ndarray] = None  # (n, 3) static tables
         self.nbr_np: Optional[np.ndarray] = None  # (n, 8)
@@ -277,7 +277,7 @@ class ShardedActivationCache:
         self.packed = None      # (S, n_max, th, tw, C_last) mesh-sharded
         self.ref_win = None     # (S, n_max, th+2, tw+2, 3) mesh-sharded
         self.canvas = None      # (S, F_max+1, H, W, A) persistent heads
-        self.ref_canvas = None  # (S, F_max+1, H+2, W+2, 3) references
+        self.ref_canvas = None  # (S, F_max+1, H+2, W', 3) references
         self.epoch_np = None    # (S, n_max) per-tile last-refresh step
         self.canvas_bytes_last = 0
         self.canvas_bytes_total = 0
@@ -330,12 +330,13 @@ def _head_rows(packed: jax.Array, head: jax.Array) -> jax.Array:
     step write only the changed tiles' head rows (pure jnp, not a
     counted kernel dispatch, like ``ops.gather_windows``)."""
     n, th, tw, c = packed.shape
-    return (packed.reshape(n * th * tw, c) @ head).reshape(
+    return jnp.dot(packed.reshape(n * th * tw, c), head,
+                   precision=jax.lax.Precision.HIGHEST).reshape(
         n, th, tw, head.shape[-1])
 
 
 def _window_region_mask(idx_rows, t: int, shape) -> np.ndarray:
-    """(m, 3) advanced (cam, ty, tx) rows -> bool (C, H+2, W+2, 1) mask
+    """(m, 3) advanced (cam, ty, tx) rows -> bool (C, H+2, W', 1) mask
     over their haloed window regions on the padded reference canvas
     (broadcasts over channels).  Host-built from the static tables —
     overlapping window writes are safe because every advanced region is
@@ -409,22 +410,11 @@ class RoIDetector:
         self.grid_hash_computes = 0       # digest serializations performed
         self.mask_cache_hits = 0
         self.fleet_cache_hits = 0
-        # tile-block for the blocked walks, sized against the VMEM budget
-        # (closes the "calibrate block vs VMEM" item; the old hardcoded
-        # interpret-mode default was 128)
+        # tiles per grid step of the entry, stack and gate walks, sized
+        # against the VMEM budget (the scatter walks one tile per step)
         self.block = kops.choose_block(
             cfg.tile, cfg.tile, max(chans), len(cfg.channels),
             cfg.vmem_budget_bytes)
-        # entry/scatter block: on hardware the blocked walks are the
-        # point (larger coalesced DMAs, fewer grid steps), but under the
-        # interpreter their in-kernel load/store loops lose to the
-        # per-tile BlockSpec pipeline — keep entry/scatter per-tile
-        # there so the PR-4 super-launch wall clock does not regress.
-        # The stack megakernel keeps its block everywhere (it always had
-        # one), and the gate stays blocked in both modes: its batched
-        # stats make one grid step per block a measured win even
-        # interpreted.
-        self.chain_block = 1 if kops.INTERPRET else self.block
         # whether the persistent head canvas is donated to the changed-
         # only scatter (resolved lazily from the serving engine's shared
         # ring-donation idiom: in-place off-CPU, copy on CPU)
@@ -435,19 +425,39 @@ class RoIDetector:
         Same rule as ``ServingEngine``'s group-cache ring
         (``engine.ring_donate_argnums``): donate off-CPU so the warm-step
         canvas update is in-place (O(changed) traffic), never on CPU
-        (donation is ignored there and tests read pre-step canvases)."""
+        (donation is ignored there).  Callers keep what a step returned:
+        its per-camera heads are slices copied out of the canvas, so a
+        later step's donation never deletes them."""
         if self._donate_canvas_flag is None:
             from repro.serving.engine import ring_donate_argnums
             self._donate_canvas_flag = bool(ring_donate_argnums(0))
         return self._donate_canvas_flag
 
     # -- dense path ----------------------------------------------------------
-    def dense_forward(self, x: jax.Array) -> jax.Array:
+    def dense_forward(self, x: jax.Array,
+                      grid: Optional[np.ndarray] = None) -> jax.Array:
+        """Full-frame forward in plain ``jax.numpy``.  With an RoI
+        ``grid`` it is the reference of the packed path: every layer's
+        output (and the head map) is zeroed outside the active tiles, so
+        the next layer sees exactly the zero halo the packed chain
+        reads; the first layer still reads the whole frame, as the entry
+        kernel's windows do."""
+        mask = None
+        if grid is not None:
+            t = self.cfg.tile
+            px = np.kron(np.asarray(grid, bool), np.ones((t, t), bool))
+            full = np.zeros(x.shape[:2], bool)
+            h, w = min(px.shape[0], x.shape[0]), min(px.shape[1], x.shape[1])
+            full[:h, :w] = px[:h, :w]
+            mask = jnp.asarray(full[..., None])
         for w in self.weights:
             x = jax.nn.relu(jax.lax.conv_general_dilated(
                 x[None], w, (1, 1), "SAME",
                 dimension_numbers=("NHWC", "HWIO", "NHWC"))[0])
-        return x @ self.head
+            if mask is not None:
+                x = jnp.where(mask, x, 0.0)
+        out = x @ self.head
+        return out if mask is None else jnp.where(mask, out, 0.0)
 
     # -- static-table caches ---------------------------------------------------
     def _grid_digest(self, grid) -> bytes:
@@ -511,7 +521,7 @@ class RoIDetector:
         > 1, 1 for a single-layer net."""
         t = self.cfg.tile
         packed = kops.roi_conv_entry(x, self.weights[0], idx3, t, t,
-                                     block=self.chain_block)
+                                     block=self.block)
         if len(self.weights) > 1:
             packed = kops.roi_conv_stack(packed, self.weights[1:], nbr,
                                          block=self.block)
@@ -582,8 +592,7 @@ class RoIDetector:
         packed = self._stack_chain(x, idx, nbr)
         base = jnp.zeros((len(frames), canvas_h, canvas_w,
                           packed.shape[-1]), packed.dtype)
-        full = kops.sbnet_scatter_fleet(packed, idx, base,
-                                        block=self.chain_block)
+        full = kops.sbnet_scatter_fleet(packed, idx, base)
         heads = full @ self.head
         return [heads[c, :f.shape[0], :f.shape[1]]
                 for c, f in enumerate(frames)]
@@ -673,7 +682,7 @@ class RoIDetector:
                                f.dtype) for f in frames],
                     ReuseStats(0, 0, 0, 0, 0, cold=False))
         x, canvas_h, canvas_w = self._stack_frames(frames, grids)
-        xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
+        xp = kops.pad_frames(x, t)
         key = (tuple(self._grid_digest(g) for g in grids),
                len(frames), canvas_h, canvas_w)
         n_layers = self.num_conv_layers
@@ -705,8 +714,7 @@ class RoIDetector:
             base = jnp.zeros((len(frames), canvas_h, canvas_w, A),
                              self.head.dtype)
             cache.canvas = kops.sbnet_scatter_fleet(
-                _head_rows(cache.packed, self.head), idx, base,
-                block=self.chain_block)
+                _head_rows(cache.packed, self.head), idx, base)
             cache.cold_steps += 1
             cache.launched_tiles += n
             stats = ReuseStats(n, n, n, n, n, cold=True,
@@ -778,7 +786,7 @@ class RoIDetector:
                             ph[-1:], (m_pad - m,) + ph.shape[1:])])
                 cache.canvas = kops.sbnet_scatter_changed(
                     ph, jnp.asarray(scidx), cache.canvas,
-                    block=self.chain_block, donate=self._donate_canvas())
+                    donate=self._donate_canvas())
                 cache.launched_tiles += k_pad
                 stats = ReuseStats(n, int(raw.sum()), n_changed, k,
                                    k_pad, cold=False, gate_stats=s,

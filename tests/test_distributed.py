@@ -96,7 +96,8 @@ def test_sharded_train_step_matches_single_device():
         losses0.append(float(m["loss"]))
 
     # 2x4 mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh(2, 4)
     s1 = init_state(cfg, tcfg, jax.random.PRNGKey(0), mesh)
     f1 = make_train_step(cfg, tcfg, mesh)
     losses1 = []
@@ -167,7 +168,8 @@ def test_debug_mesh_dryrun_decode():
     from repro.configs.base import ShapeCell
     from repro.configs.registry import get_config
     from repro.launch.steps import build_decode
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh(2, 4)
     cfg = get_config("h2o-danube3-4b", smoke=True)
     cell = ShapeCell("d", 512, 8, "decode")
     fn, args, _ = build_decode(cfg, cell, mesh)

@@ -169,27 +169,33 @@ def test_compact_tables_remap_and_zero_halo():
 # ---------------------------------------------------------------------------
 
 def test_choose_block_default_budget_and_floors():
-    # the 16 MiB default recovers the calibrated interpret-mode 128 for
-    # the YOLO-lite shapes — the old hardcoded constant, now derived
-    assert ops.choose_block(16, 16, 16, 3) == 128
+    # the 24 MiB default (3/4 of the kernels' scoped-VMEM limit) fits 8
+    # lane-padded YOLO-lite tiles at ~2.4 MB each; half the budget, half
+    # the block
+    assert ops.choose_block(16, 16, 16, 3) == 8
+    assert ops.choose_block(16, 16, 16, 3, vmem_bytes=12 << 20) == 4
     assert ops.choose_block(16, 16, 16, 3, vmem_bytes=1024) == 1
+    # small tiles hit the window-operand cap
+    assert ops.choose_block(8, 8, 6, 2) == ops.MAX_WINDOWS_PER_STEP
     last = 0
     for mb in (1, 2, 4, 8, 16, 32):
         b = ops.choose_block(16, 16, 16, 3, vmem_bytes=mb << 20)
         assert b >= max(last, 1)
         last = b
-    # wider channels shrink the block
-    assert ops.choose_block(16, 16, 64, 3) < ops.choose_block(16, 16, 8, 3)
+    # channels occupy whole 128-lane vregs: only past 128 does a wider
+    # layer shrink the block
+    assert ops.choose_block(16, 16, 64, 3) == ops.choose_block(16, 16, 8, 3)
+    assert ops.choose_block(16, 16, 256, 3) < ops.choose_block(16, 16, 8, 3)
     # detector wires it through
     det = RoIDetector(DetectorConfig(), jax.random.PRNGKey(0))
-    assert det.block == 128
+    assert det.block == ops.choose_block(16, 16, 16, 3)
     det_small = RoIDetector(DetectorConfig(vmem_budget_bytes=1 << 20),
                             jax.random.PRNGKey(0))
     assert 1 <= det_small.block < det.block
 
 
 # ---------------------------------------------------------------------------
-# blocked entry + blocked scatter: bit-identical to the per-tile walks
+# blocked entry: bit-identical to the per-tile walk; scatter padding
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("block", [2, 3, 16, 256])
@@ -204,22 +210,25 @@ def test_blocked_entry_bitwise_vs_per_tile(block):
     assert (np.asarray(out) == np.asarray(base)).all()
 
 
-@pytest.mark.parametrize("block", [2, 5, 64])
-def test_blocked_scatter_bitwise_vs_per_tile(block):
-    """Including the repeat-last padding contract: duplicate stores must
-    rewrite identical bytes, never corrupt a neighbor."""
+@pytest.mark.parametrize("pad", [1, 5, 64])
+def test_scatter_repeat_last_padding_is_idempotent(pad):
+    """The repeat-last padding contract the reuse path's pow-2 buckets
+    rely on: duplicate stores rewrite identical bytes, never corrupt a
+    neighbor, and every real tile lands at its (cam, ty, tx)."""
     rng = _rng(7)
     th = tw = 8
     grids, idx, _ = _fleet_pack(rng, [(4, 5), (3, 3)])
     n = idx.shape[0]
-    packed = jnp.asarray(rng.normal(size=(n, th, tw, 6)), jnp.float32)
-    base = jnp.asarray(rng.normal(size=(2, 4 * th, 5 * tw, 6)),
-                       jnp.float32)
-    legacy = ops.sbnet_scatter_fleet(packed, jnp.asarray(idx), base,
-                                     block=1)
-    out = ops.sbnet_scatter_fleet(packed, jnp.asarray(idx), base,
-                                  block=block)
-    assert (np.asarray(out) == np.asarray(legacy)).all()
+    packed = rng.normal(size=(n, th, tw, 6)).astype(np.float32)
+    base = rng.normal(size=(2, 4 * th, 5 * tw, 6)).astype(np.float32)
+    expect = base.copy()
+    for (cam, ty, tx), tile in zip(idx, packed):
+        expect[cam, ty * th:(ty + 1) * th, tx * tw:(tx + 1) * tw] = tile
+    idx_p = np.concatenate([idx, np.repeat(idx[-1:], pad, axis=0)])
+    packed_p = np.concatenate([packed, np.repeat(packed[-1:], pad, axis=0)])
+    out = ops.sbnet_scatter_fleet(jnp.asarray(packed_p),
+                                  jnp.asarray(idx_p), jnp.asarray(base))
+    np.testing.assert_array_equal(np.asarray(out), expect)
 
 
 # ---------------------------------------------------------------------------

@@ -64,27 +64,28 @@ def test_stack_kernel_bitwise_vs_per_layer_chain(chans):
         "megakernel must be bit-identical to the per-layer chain"
 
 
-def test_assemble_rims_matches_oracle():
-    """The vectorized rim assembly (the seed of the megakernel's
-    coalesced halos) equals the scatter-loop oracle row for row on every
-    real slot."""
-    from repro.kernels.roi_conv import assemble_rims
+def test_stack_matches_scatter_conv_oracle():
+    """Each megakernel layer equals the scatter-into-zeros oracle
+    (``ref.roi_conv_packed``): inactive and off-frame neighbors read as a
+    zero halo, whatever row the kernel fetched them from."""
     rng = _rng(2)
     th = tw = 8
-    grids = [rng.random((4, 4)) < 0.6, rng.random((3, 5)) < 0.5]
-    grids[0][2, 2] = True
-    grids[1][1, 1] = True
-    idx_np, _ = ops.fleet_indices(grids)
-    nbr_np = ops.fleet_neighbor_table(grids)
-    n = idx_np.shape[0]
-    packed = jnp.asarray(rng.normal(size=(n, th, tw, 4)), jnp.float32)
-    rt, rb, rl, rr = [np.asarray(r) for r in
-                      assemble_rims(packed, jnp.asarray(nbr_np))]
-    ert, erb, erl, err_ = ref.rims_of_packed(packed, nbr_np)
-    np.testing.assert_array_equal(rt, ert[:n])
-    np.testing.assert_array_equal(rb, erb[:n])
-    np.testing.assert_array_equal(rl, erl[:n])
-    np.testing.assert_array_equal(rr, err_[:n])
+    grid = rng.random((4, 6)) < 0.5
+    grid[2, 2] = True
+    idx = ops.mask_to_indices(grid)
+    nbr = jnp.asarray(ops.neighbor_table(idx, grid.shape))
+    n = idx.shape[0]
+    packed = jax.nn.relu(
+        jnp.asarray(rng.normal(size=(n, th, tw, 4)), jnp.float32))
+    ws = [jnp.asarray(rng.normal(size=(3, 3, 4, 6)) * 0.3, jnp.float32),
+          jnp.asarray(rng.normal(size=(3, 3, 6, 5)) * 0.3, jnp.float32)]
+    fused = ops.roi_conv_stack(packed, ws, nbr, block=4)
+    expect = packed
+    for w in ws:
+        expect = jax.nn.relu(ref.roi_conv_packed(expect, jnp.asarray(idx),
+                                                 grid.shape, w))
+    np.testing.assert_allclose(np.asarray(fused), np.asarray(expect),
+                               atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("block", [1, 3, 16, 256])

@@ -10,11 +10,10 @@ Four panels:
   2. bit-compatibility — over the enabled run, the
      ``kernel_dispatches`` metric family equals the legacy
      ``ops.count_kernels`` region Counter exactly.
-  3. async timeline — an ``AsyncShardedPipeline`` run on mesh=(1,)
-     exports a Chrome ``trace_event`` JSON (``results/obs_trace.json``,
-     loadable in Perfetto) where step t's ``host_plan`` span visibly
-     overlaps step t-1's ``device_compute`` span; disabled mode records
-     zero spans for the identical workload.
+  3. async timeline — in an ``AsyncShardedPipeline`` run on mesh=(1,)
+     the recorded spans show step t's ``host_plan`` span overlapping
+     step t-1's ``device_compute`` span; disabled mode records zero
+     spans for the identical workload.
   4. SLO panel — ``FleetSLOReport`` built from the measured step
      reports plus one simulated transport window (p50/p99 response
      delay, deadline hit rate, bytes shed, changed-tile fraction);
@@ -25,7 +24,6 @@ Four panels:
 from __future__ import annotations
 
 import collections
-import os
 import time
 
 import jax
@@ -39,14 +37,11 @@ from repro.kernels import ops
 from repro.launch.mesh import make_fleet_mesh
 from repro.net.batcher import simulate_transport
 from repro.net.encoder import CameraCoefficients
-from repro.obs import export as obs_export
 from repro.obs import metrics as obs_metrics
 from repro.obs import slo as obs_slo
 from repro.obs import trace as obs_trace
 from repro.serving.detector import (DetectorConfig, PackedActivationCache,
                                     RoIDetector)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _det():
@@ -124,14 +119,12 @@ def _transport_window():
                               coef=coef)
 
 
-def _overlap_windows(doc):
+def _overlap_windows(events):
     """(host_plan, device_compute) step pairs whose spans overlap."""
-    hosts = {e["args"].get("step"): (e["ts"], e["ts"] + e["dur"])
-             for e in doc["traceEvents"]
-             if e.get("ph") == "X" and e["name"] == "host_plan"}
-    devs = {e["args"].get("step"): (e["ts"], e["ts"] + e["dur"])
-            for e in doc["traceEvents"]
-            if e.get("ph") == "X" and e["name"] == "device_compute"}
+    hosts = {e.step: (e.t0_ns, e.t0_ns + e.dur_ns)
+             for e in events if e.name == "host_plan"}
+    devs = {e.step: (e.t0_ns, e.t0_ns + e.dur_ns)
+            for e in events if e.name == "device_compute"}
     pairs = []
     for s, (h0, h1) in hosts.items():
         d = devs.get(s - 1)
@@ -214,11 +207,8 @@ def run(verbose=True, quick=False):
         for frames in frames_list:
             pipe.submit(frames)
         pipe.drain()
-        os.makedirs(os.path.join(REPO, "results"), exist_ok=True)
-        trace_path = os.path.join(REPO, "results", "obs_trace.json")
-        doc = obs_export.chrome_trace(trace_path)
         enabled_spans = obs_trace.span_count()
-    overlapped, n_host, n_dev = _overlap_windows(doc)
+        overlapped, n_host, n_dev = _overlap_windows(obs_trace.events())
 
     obs.configure(enabled=False, reset=True)
     pipe2 = AsyncShardedPipeline(rt, rt.make_cache())
@@ -259,7 +249,6 @@ def run(verbose=True, quick=False):
         "device_compute_spans": int(n_dev),
         "overlapped_steps": overlapped,
         "pipeline_overlap_fraction": float(pipe.overlap_fraction),
-        "trace_path": os.path.relpath(trace_path, REPO),
         "slo_panel": panel,
     }
     if verbose:
@@ -282,8 +271,6 @@ def run(verbose=True, quick=False):
             ["changed-tile fraction",
              f"{panel['changed_tile_fraction']:.3f}"],
         ], ["obs", "value"]))
-        print(f"\nChrome trace -> {trace_path} "
-              f"(open in https://ui.perfetto.dev)")
     save_json("bench_obs.json", payload)
     return payload
 

@@ -401,7 +401,7 @@ def obs_quick():
     """CI smoke for the observability layer: < 2% wall overhead on the
     delta-gated fleet trace with ZERO added device dispatches, the
     ``kernel_dispatches`` metric family bit-matching the legacy
-    ``ops.count_kernels`` Counter, an async-pipeline Chrome trace whose
+    ``ops.count_kernels`` Counter, async-pipeline spans whose
     host-plan spans overlap the prior step's device-compute span,
     disabled mode recording zero spans, and a well-formed SLO panel —
     merged into BENCH_kernels.json under "obs"."""

@@ -304,14 +304,12 @@ def fleet_reuse_step(det, frames: Dict[int, List],
     * an all-static frame dispatches the gate ALONE — zero conv, zero
       scatter, 0 canvas bytes written;
     * an all-empty fleet launches nothing."""
-    t0 = time.perf_counter()
     with kops.count_kernels() as c, \
             obs_trace.span("fleet_reuse_step", step=cache.steps) as sp:
         outs, stats = det.superlaunch_forward_reuse(frames, grids, cache,
                                                     threshold, qstep)
         sp.set(computed=stats.computed, cold=stats.cold)
-    obs_metrics.observe_fleet_step(stats, time.perf_counter() - t0,
-                                   path="fleet_reuse")
+    obs_metrics.observe_fleet_step(stats)
     total: collections.Counter = collections.Counter(c)
     n_tiles = sum(int(np.count_nonzero(np.asarray(g, bool)))
                   for gs in grids.values() for g in gs)
@@ -349,13 +347,11 @@ def sharded_fleet_step(runtime, frames: Dict[int, List], cache,
     an all-empty fleet.  (The sharded path gates on cold steps too —
     SPMD uniformity: cold and warm shards share one program.)  Returns
     ({gid: head maps}, dispatch Counter, ShardedReuseStats)."""
-    t0 = time.perf_counter()
     with kops.count_kernels() as c, \
             obs_trace.span("sharded_fleet_step", step=cache.steps) as sp:
         outs, stats = runtime.step_reuse(frames, cache, threshold)
         sp.set(computed=stats.computed, cold_shards=stats.cold_shards)
-    obs_metrics.observe_fleet_step(stats, time.perf_counter() - t0,
-                                   path="sharded")
+    obs_metrics.observe_fleet_step(stats)
     total: collections.Counter = collections.Counter(c)
     if stats.total_tiles == 0:
         expected = {}

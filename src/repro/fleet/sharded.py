@@ -77,7 +77,7 @@ from repro.kernels.tile_delta import (COEF_BITS, RUN_BITS,
                                       tile_delta_gate_canvas as
                                       _raw_gate_canvas)
 from repro.launch.mesh import FLEET_AXIS
-from repro.obs import trace as obs_trace
+from repro.obs import metrics as obs_metrics, trace as obs_trace
 from repro.serving.detector import (ShardedActivationCache,
                                     gate_changed_rows, ref_advance_rows,
                                     tile_class_rows)
@@ -400,92 +400,95 @@ class ShardedSuperlaunch:
         compute).  ``threshold``: scalar, or {gid: per-camera (F_g,) or
         per-camera-per-tile-class (F_g, N_TILE_CLASSES) array} (the rate
         controller's schedule; see ``gate_threshold_schedule``)."""
-        S = self.plan.n_shards
-        n_layers = self.det.num_conv_layers
-        per_changed, per_compute = [], []
-        raw_total = changed_total = computed_total = 0
-        cold_shards = 0
-        gate_stats: List[Optional[np.ndarray]] = []
-        thr_by_shard = self._shard_thresholds(threshold)
-        for s in range(S):
-            n_s = self._n_s[s]
-            if n_s == 0:
-                per_changed.append(np.zeros(0, bool))
-                per_compute.append(np.zeros(0, bool))
-                gate_stats.append(None)
-                continue
-            rows = stats_np[s, :n_s]
-            if cache.valid[s]:
-                raw = np.asarray(gate_changed_rows(
-                    rows, thr_by_shard[s], self._idx_np[s][:, 0],
-                    self._cls_np[s]), bool)
-                gate_stats.append(rows)
-            else:
-                # cold shard: reference content is stale — force a full
-                # recompute of its rows inside the same SPMD step
-                raw = np.ones(n_s, bool)
-                gate_stats.append(None)
-                cold_shards += 1
-            changed, compute = kops.reuse_sets(raw, self._nbr_np[s],
-                                               n_layers)
-            per_changed.append(changed)
-            per_compute.append(compute)
-            raw_total += int(raw.sum())
-            changed_total += int(changed.sum())
-            computed_total += int(compute.sum())
-        k_max = _pow2(max([int(c.sum()) for c in per_compute] + [0])) \
-            if computed_total else 0
-        adv = np.zeros((S, self.n_max), bool)
-        for s in range(S):
-            n_s = self._n_s[s]
-            if n_s == 0:
-                continue
-            if not cache.valid[s]:
-                adv[s, :n_s] = True
-                continue
-            a = ref_advance_rows(thr_by_shard[s], self._idx_np[s][:, 0],
-                                 per_changed[s], self._cls_np[s])
-            adv[s, :n_s] = True if a is None else a
-        cold_mask = ~np.asarray(cache.valid, bool)
-        t = self.det.cfg.tile
-        tile_bytes = t * t * int(self.det.head.shape[-1]) * 4
-        stats = ShardedReuseStats(
-            total_tiles=self.n_total, raw_changed=raw_total,
-            changed_out=changed_total, computed=computed_total,
-            launched=S * k_max if k_max else 0, k_max=k_max,
-            cold_shards=cold_shards,
-            canvas_bytes=changed_total * tile_bytes,
-            per_shard_computed=[int(c.sum()) for c in per_compute],
-            gate_stats=gate_stats)
-        if k_max == 0:
-            return _HostPlan(0, None, None, None, None, adv, cold_mask,
+        with obs_trace.span("reuse_plan") as sp:
+            S = self.plan.n_shards
+            n_layers = self.det.num_conv_layers
+            per_changed, per_compute = [], []
+            raw_total = changed_total = computed_total = 0
+            cold_shards = 0
+            gate_stats: List[Optional[np.ndarray]] = []
+            thr_by_shard = self._shard_thresholds(threshold)
+            for s in range(S):
+                n_s = self._n_s[s]
+                if n_s == 0:
+                    per_changed.append(np.zeros(0, bool))
+                    per_compute.append(np.zeros(0, bool))
+                    gate_stats.append(None)
+                    continue
+                rows = stats_np[s, :n_s]
+                if cache.valid[s]:
+                    raw = np.asarray(gate_changed_rows(
+                        rows, thr_by_shard[s], self._idx_np[s][:, 0],
+                        self._cls_np[s]), bool)
+                    gate_stats.append(rows)
+                else:
+                    # cold shard: reference content is stale — force a full
+                    # recompute of its rows inside the same SPMD step
+                    raw = np.ones(n_s, bool)
+                    gate_stats.append(None)
+                    cold_shards += 1
+                changed, compute = kops.reuse_sets(raw, self._nbr_np[s],
+                                                   n_layers)
+                per_changed.append(changed)
+                per_compute.append(compute)
+                raw_total += int(raw.sum())
+                changed_total += int(changed.sum())
+                computed_total += int(compute.sum())
+            k_max = _pow2(max([int(c.sum()) for c in per_compute] + [0])) \
+                if computed_total else 0
+            adv = np.zeros((S, self.n_max), bool)
+            for s in range(S):
+                n_s = self._n_s[s]
+                if n_s == 0:
+                    continue
+                if not cache.valid[s]:
+                    adv[s, :n_s] = True
+                    continue
+                a = ref_advance_rows(thr_by_shard[s], self._idx_np[s][:, 0],
+                                     per_changed[s], self._cls_np[s])
+                adv[s, :n_s] = True if a is None else a
+            cold_mask = ~np.asarray(cache.valid, bool)
+            t = self.det.cfg.tile
+            tile_bytes = t * t * int(self.det.head.shape[-1]) * 4
+            stats = ShardedReuseStats(
+                total_tiles=self.n_total, raw_changed=raw_total,
+                changed_out=changed_total, computed=computed_total,
+                launched=S * k_max if k_max else 0, k_max=k_max,
+                cold_shards=cold_shards,
+                canvas_bytes=changed_total * tile_bytes,
+                per_shard_computed=[int(c.sum()) for c in per_compute],
+                gate_stats=gate_stats)
+            sp.set(raw_changed=raw_total, computed=computed_total,
+                   launched=stats.launched)
+            if k_max == 0:
+                return _HostPlan(0, None, None, None, None, adv, cold_mask,
+                                 stats)
+            cidx = np.zeros((S, k_max, 3), np.int32)
+            cidx[:, :, 0] = self.F_max                 # sacrificial padding
+            cnbr = np.full((S, k_max, 8), -1, np.int32)
+            upd = np.full((S, k_max), self.n_max, np.int32)   # n_max = drop
+            sidx = np.zeros((S, k_max, 3), np.int32)
+            sidx[:, :, 0] = self.F_max                 # sacrificial plane
+            for s in range(S):
+                compute = per_compute[s]
+                k = int(compute.sum())
+                if k == 0:
+                    continue
+                ci, cn = kops.compact_tables(self._idx_np[s], self._nbr_np[s],
+                                             compute)
+                cidx[s, :k] = ci
+                cnbr[s, :k] = cn
+                slots = np.nonzero(compute)[0]
+                ch = per_changed[s][slots]
+                upd[s, :k] = np.where(ch, slots, self.n_max).astype(np.int32)
+                # canvas targets: only changed-OUTPUT rows write their real
+                # tile; margin rows keep the cache's (still-exact) old bytes
+                # by writing the sacrificial plane instead
+                sidx[s, :k] = np.where(ch[:, None], ci,
+                                       np.array([[self.F_max, 0, 0]],
+                                                np.int32))
+            return _HostPlan(k_max, cidx, cnbr, upd, sidx, adv, cold_mask,
                              stats)
-        cidx = np.zeros((S, k_max, 3), np.int32)
-        cidx[:, :, 0] = self.F_max                 # sacrificial padding
-        cnbr = np.full((S, k_max, 8), -1, np.int32)
-        upd = np.full((S, k_max), self.n_max, np.int32)   # n_max = drop
-        sidx = np.zeros((S, k_max, 3), np.int32)
-        sidx[:, :, 0] = self.F_max                 # sacrificial plane
-        for s in range(S):
-            compute = per_compute[s]
-            k = int(compute.sum())
-            if k == 0:
-                continue
-            ci, cn = kops.compact_tables(self._idx_np[s], self._nbr_np[s],
-                                         compute)
-            cidx[s, :k] = ci
-            cnbr[s, :k] = cn
-            slots = np.nonzero(compute)[0]
-            ch = per_changed[s][slots]
-            upd[s, :k] = np.where(ch, slots, self.n_max).astype(np.int32)
-            # canvas targets: only changed-OUTPUT rows write their real
-            # tile; margin rows keep the cache's (still-exact) old bytes
-            # by writing the sacrificial plane instead
-            sidx[s, :k] = np.where(ch[:, None], ci,
-                                   np.array([[self.F_max, 0, 0]],
-                                            np.int32))
-        return _HostPlan(k_max, cidx, cnbr, upd, sidx, adv, cold_mask,
-                         stats)
 
     def _shard_thresholds(self, threshold) -> List:
         """Resolve the scalar / {gid: per-camera or per-camera-per-
@@ -548,10 +551,12 @@ class ShardedSuperlaunch:
         (S, n_max) advance mask."""
         if not plan.adv.any():
             return
-        mask = jax.device_put(
-            jnp.asarray(self._adv_canvas_mask(plan.adv)), self.sharding)
-        cache.ref_canvas = self._refadv_fn()(cache.ref_canvas, x, mask)
-        cache.epoch_np[plan.adv] = cache.steps
+        with obs_trace.span("ref_advance"):
+            mask = jax.device_put(
+                jnp.asarray(self._adv_canvas_mask(plan.adv)),
+                self.sharding)
+            cache.ref_canvas = self._refadv_fn()(cache.ref_canvas, x, mask)
+            cache.epoch_np[plan.adv] = cache.steps
 
     # -- synchronous steps -------------------------------------------------
     def step_reuse(self, frames: Dict[int, List],
@@ -574,11 +579,17 @@ class ShardedSuperlaunch:
         if self.n_total == 0:
             return self._zero_heads(frames), ShardedReuseStats(
                 0, 0, 0, 0, 0, 0, 0)
-        self._init_cache_arrays(cache)
-        x = self._ingest(frames)
-        kops.record_dispatch("tile_delta_gate")
-        stats_f = self._gate_fn()(x, cache.ref_canvas, self.idx_pad)
-        plan = self._host_plan(np.asarray(stats_f), cache, threshold)
+        with obs_trace.span("stage"):
+            self._init_cache_arrays(cache)
+            x = self._ingest(frames)
+        with obs_trace.span("gate"):
+            kops.record_dispatch("tile_delta_gate")
+            stats_f = self._gate_fn()(x, cache.ref_canvas, self.idx_pad)
+        with obs_trace.span("gate_readback") as sp:
+            stats_np = np.asarray(stats_f)
+            sp.set(bytes=stats_np.nbytes)
+        obs_metrics.READBACK_BYTES.inc(stats_np.nbytes, kind="gate")
+        plan = self._host_plan(stats_np, cache, threshold)
         heads = self._dispatch_conv(x, plan, cache)
         self._advance_refs(cache, x, plan)
         if plan.stats.cold_shards:
@@ -587,8 +598,12 @@ class ShardedSuperlaunch:
         cache.launched_tiles += plan.stats.launched
         cache.canvas_bytes_last = plan.stats.canvas_bytes
         cache.canvas_bytes_total += plan.stats.canvas_bytes
-        heads_np = np.asarray(heads)
-        return self._split_heads(heads_np, frames), plan.stats
+        with obs_trace.span("heads_out") as sp:
+            heads_np = np.asarray(heads)
+            out = self._split_heads(heads_np, frames)
+            sp.set(bytes=heads_np.nbytes)
+        obs_metrics.READBACK_BYTES.inc(heads_np.nbytes, kind="heads")
+        return out, plan.stats
 
     def step_full(self, frames: Dict[int, List]):
         """The non-reuse sharded super-launch (cold path / A-B
@@ -649,13 +664,14 @@ class ShardedSuperlaunch:
         canvas is served directly."""
         if plan.k_max == 0:
             return cache.canvas
-        kops.record_dispatch("roi_conv_entry")
-        if self.det.num_conv_layers > 1:
-            kops.record_dispatch("roi_conv_stack")
-        kops.record_dispatch("sbnet_scatter_changed")
-        slot = self._put_tables(plan, parity)
-        cache.packed, cache.canvas = self._conv_fn(plan.k_max)(
-            x, *slot, cache.packed, cache.canvas)
+        with obs_trace.span("conv_dispatch"):
+            kops.record_dispatch("roi_conv_entry")
+            if self.det.num_conv_layers > 1:
+                kops.record_dispatch("roi_conv_stack")
+            kops.record_dispatch("sbnet_scatter_changed")
+            slot = self._put_tables(plan, parity)
+            cache.packed, cache.canvas = self._conv_fn(plan.k_max)(
+                x, *slot, cache.packed, cache.canvas)
         return cache.canvas
 
     # -- output plumbing ---------------------------------------------------

@@ -4,12 +4,11 @@ Three pieces, all default-OFF and zero-dispatch by construction (host
 timestamps around already-existing sync points only — no
 ``block_until_ready`` is ever added to a hot path):
 
-* ``obs.trace`` — monotonic-clock span API (``with
-  obs.trace.span("gate", step=t): ...``), thread/contextvar-safe like
-  ``ops.count_kernels``; async begin/end handles put in-flight device
-  work on its own timeline track.  Export with
-  ``obs.export.chrome_trace(path)`` and open in chrome://tracing or
-  Perfetto.
+* ``obs.trace`` — the span API (``with obs.trace.span("gate"): ...``):
+  each span is kept in memory with its parent and step id and written
+  through ``jax.profiler.TraceAnnotation``, so a profiler session puts
+  it on the device trace's clock; async begin/end handles put in-flight
+  device work on its own track.
 * ``obs.metrics`` — typed counters/gauges/histograms with labels.
   ``kernel_dispatches`` mirrors ``ops.KERNEL_COUNTS`` bit-for-bit;
   the canonical ``KERNEL_NAMES`` frozenset makes typo'd counter names
@@ -34,8 +33,8 @@ from __future__ import annotations
 
 import contextlib
 
-from repro.obs import (export, loadgen, metrics, sentinel,  # noqa: F401
-                       slo, state, trace)
+from repro.obs import (loadgen, metrics, sentinel, slo,  # noqa: F401
+                       state, trace)
 
 
 def configure(enabled=None, reset: bool = False) -> bool:

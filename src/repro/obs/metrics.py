@@ -217,10 +217,6 @@ KERNEL_DISPATCHES = REGISTRY.counter(
     "kernel_dispatches", "Pallas kernel launches by wrapper name",
     ("kernel",))
 
-STEP_WALL = REGISTRY.histogram(
-    "step_wall_s", "Wall time of one fleet step by runtime path",
-    ("path",))
-
 TILES = REGISTRY.counter(
     "fleet_tiles", "Per-step tile accounting: total / raw_changed / "
     "changed_dilated (post neighbor-dilation compute set) / computed / "
@@ -273,6 +269,10 @@ HEARTBEAT_EVENTS = REGISTRY.counter(
     "heartbeat_events", "Transport heartbeat: dead / retry / restored",
     ("event",))
 
+READBACK_BYTES = REGISTRY.counter(
+    "readback_bytes", "Bytes a fleet step pulls to the host: gate "
+    "(the gate's stats) / heads (the head maps)", ("kind",))
+
 CANVAS_BYTES = REGISTRY.gauge(
     "canvas_bytes_written", "Bytes scattered into the persistent head-map "
     "canvas, latest step (0 on an all-static step)")
@@ -298,7 +298,7 @@ def kernel_counts() -> Dict[str, int]:
 # duck-typed recording helpers shared by the fleet runtimes
 # ---------------------------------------------------------------------------
 
-def observe_fleet_step(stats, wall_s: float, path: str) -> None:
+def observe_fleet_step(stats) -> None:
     """Record one delta-gated fleet step's tile/cache accounting.
 
     ``stats`` is duck-typed over ``serving.detector.ReuseStats`` and
@@ -307,7 +307,6 @@ def observe_fleet_step(stats, wall_s: float, path: str) -> None:
     ``cold_shards`` and optionally ``per_shard_computed``)."""
     if not state.enabled:
         return
-    STEP_WALL.observe(wall_s, path=path)
     total = int(stats.total_tiles)
     TILES.inc(total, kind="total")
     TILES.inc(int(stats.raw_changed), kind="raw_changed")

@@ -45,6 +45,7 @@ import numpy as np
 # kernel-side mirror of that model
 from repro.core.pipeline import IO_ROUND_TRIP_OVERHEAD
 from repro.kernels import ops as kops
+from repro.obs import metrics as obs_metrics, trace as obs_trace
 
 
 @dataclass
@@ -356,24 +357,25 @@ def _advance_refs(cache: "PackedActivationCache", xp: jax.Array,
     threshold <= 0 previous-frame fast path); a partial advance writes
     the advanced rows' full window regions via one masked select."""
     step = cache.steps
-    if cache.ref_mode == "packed":
+    with obs_trace.span("ref_advance"):
+        if cache.ref_mode == "packed":
+            if adv is None:
+                cache.ref_win = windows
+            elif adv.any():
+                rows = jnp.asarray(np.nonzero(adv)[0])
+                cache.ref_win = cache.ref_win.at[rows].set(windows[rows])
+        else:
+            if adv is None:
+                cache.ref_canvas = xp
+            elif adv.any():
+                mask = _window_region_mask(cache.idx_np[adv], t,
+                                           cache.ref_canvas.shape)
+                cache.ref_canvas = jnp.where(jnp.asarray(mask), xp,
+                                             cache.ref_canvas)
         if adv is None:
-            cache.ref_win = windows
+            cache.epoch_np[:] = step
         elif adv.any():
-            rows = jnp.asarray(np.nonzero(adv)[0])
-            cache.ref_win = cache.ref_win.at[rows].set(windows[rows])
-    else:
-        if adv is None:
-            cache.ref_canvas = xp
-        elif adv.any():
-            mask = _window_region_mask(cache.idx_np[adv], t,
-                                       cache.ref_canvas.shape)
-            cache.ref_canvas = jnp.where(jnp.asarray(mask), xp,
-                                         cache.ref_canvas)
-    if adv is None:
-        cache.epoch_np[:] = step
-    elif adv.any():
-        cache.epoch_np[adv] = step
+            cache.epoch_np[adv] = step
 
 
 class RoIDetector:
@@ -675,14 +677,15 @@ class RoIDetector:
         (C, N_TILE_CLASSES) per-camera-per-tile-class table (body vs
         halo rows, see ``tile_class_rows``)."""
         t = self.cfg.tile
-        idx, nbr = self._fleet_tables(grids)
-        n = int(idx.shape[0])
-        if n == 0:                        # whole fleet empty: no launches
-            return ([jnp.zeros(f.shape[:2] + (self.head.shape[-1],),
-                               f.dtype) for f in frames],
-                    ReuseStats(0, 0, 0, 0, 0, cold=False))
-        x, canvas_h, canvas_w = self._stack_frames(frames, grids)
-        xp = kops.pad_frames(x, t)
+        with obs_trace.span("stage"):
+            idx, nbr = self._fleet_tables(grids)
+            n = int(idx.shape[0])
+            if n == 0:                    # whole fleet empty: no launches
+                return ([jnp.zeros(f.shape[:2] + (self.head.shape[-1],),
+                                   f.dtype) for f in frames],
+                        ReuseStats(0, 0, 0, 0, 0, cold=False))
+            x, canvas_h, canvas_w = self._stack_frames(frames, grids)
+            xp = kops.pad_frames(x, t)
         key = (tuple(self._grid_digest(g) for g in grids),
                len(frames), canvas_h, canvas_w)
         n_layers = self.num_conv_layers
@@ -700,93 +703,105 @@ class RoIDetector:
             # rebuild the head canvas from zeros (stale canvas content
             # can never survive a re-solve)
             cache.key = key
-            cache.packed = self._stack_chain(x, idx, nbr)
-            if cache.ref_mode == "packed":
-                cache.ref_win = kops.gather_windows(xp, idx, t, t)
-                cache.ref_canvas = None
-            else:
-                cache.ref_canvas = xp      # free alias, full advance
-                cache.ref_win = None
-            cache.idx_np = np.asarray(idx)
-            cache.nbr_np = np.asarray(nbr)
-            cache.cls_np = tile_class_rows(cache.nbr_np)
-            cache.epoch_np = np.zeros(n, np.int64)
-            base = jnp.zeros((len(frames), canvas_h, canvas_w, A),
-                             self.head.dtype)
-            cache.canvas = kops.sbnet_scatter_fleet(
-                _head_rows(cache.packed, self.head), idx, base)
+            with obs_trace.span("conv_dispatch"):
+                cache.packed = self._stack_chain(x, idx, nbr)
+                if cache.ref_mode == "packed":
+                    cache.ref_win = kops.gather_windows(xp, idx, t, t)
+                    cache.ref_canvas = None
+                else:
+                    cache.ref_canvas = xp      # free alias, full advance
+                    cache.ref_win = None
+                cache.idx_np = np.asarray(idx)
+                cache.nbr_np = np.asarray(nbr)
+                cache.cls_np = tile_class_rows(cache.nbr_np)
+                cache.epoch_np = np.zeros(n, np.int64)
+                base = jnp.zeros((len(frames), canvas_h, canvas_w, A),
+                                 self.head.dtype)
+                cache.canvas = kops.sbnet_scatter_fleet(
+                    _head_rows(cache.packed, self.head), idx, base)
             cache.cold_steps += 1
             cache.launched_tiles += n
             stats = ReuseStats(n, n, n, n, n, cold=True,
                                canvas_bytes=n * tile_bytes)
         else:
-            if cache.ref_mode == "packed":
-                gate, windows = kops.tile_delta_gate(
-                    xp, cache.ref_win, idx, t, t, qstep=qstep,
-                    block=self.block)
-            else:
-                gate = kops.tile_delta_gate_canvas(
-                    xp, cache.ref_canvas, idx, t, t, qstep=qstep,
-                    block=self.block)
-                windows = None
-            s = np.asarray(gate)
-            # exact gate (threshold <= 0, possibly per camera / class):
-            # quantization rounds small deltas to zero and even an
-            # all-zero delta prices its run tokens, so bit-identity keys
-            # on the raw bitwise comparison
-            raw = gate_changed_rows(s, threshold, cache.idx_np[:, 0],
-                                    cache.cls_np)
-            changed, compute = kops.reuse_sets(raw, cache.nbr_np,
-                                               n_layers)
-            n_changed = int(changed.sum())
+            with obs_trace.span("gate"):
+                if cache.ref_mode == "packed":
+                    gate, windows = kops.tile_delta_gate(
+                        xp, cache.ref_win, idx, t, t, qstep=qstep,
+                        block=self.block)
+                else:
+                    gate = kops.tile_delta_gate_canvas(
+                        xp, cache.ref_canvas, idx, t, t, qstep=qstep,
+                        block=self.block)
+                    windows = None
+            with obs_trace.span("gate_readback") as sp:
+                s = np.asarray(gate)
+                sp.set(bytes=s.nbytes)
+            obs_metrics.READBACK_BYTES.inc(s.nbytes, kind="gate")
+            with obs_trace.span("reuse_plan") as sp:
+                # exact gate (threshold <= 0, possibly per camera /
+                # class): quantization rounds small deltas to zero and
+                # even an all-zero delta prices its run tokens, so
+                # bit-identity keys on the raw bitwise comparison
+                raw = gate_changed_rows(s, threshold, cache.idx_np[:, 0],
+                                        cache.cls_np)
+                changed, compute = kops.reuse_sets(raw, cache.nbr_np,
+                                                   n_layers)
+                n_changed = int(changed.sum())
+                k = k_pad = 0
+                if n_changed:
+                    cidx, cnbr = kops.compact_tables(cache.idx_np,
+                                                     cache.nbr_np, compute)
+                    k = cidx.shape[0]
+                    # pad the ragged compact set up to the next power of
+                    # two with inert repeats (idx) / -1 neighbors, so the
+                    # jit caches key on log-many bucketed shapes, not
+                    # every |E| (waste < 2x; the padding rows are real
+                    # GEMM work and are accounted as ``launched``)
+                    k_pad = 1
+                    while k_pad < k:
+                        k_pad *= 2
+                    if k_pad > k:
+                        cidx = np.concatenate(
+                            [cidx, np.broadcast_to(cidx[-1:],
+                                                   (k_pad - k, 3))])
+                        cnbr = np.concatenate(
+                            [cnbr, np.full((k_pad - k, 8), -1, np.int32)])
+                sp.set(raw_changed=int(raw.sum()), computed=k,
+                       launched=k_pad)
             if n_changed:
-                cidx, cnbr = kops.compact_tables(cache.idx_np,
-                                                 cache.nbr_np, compute)
-                k = cidx.shape[0]
-                # pad the ragged compact set up to the next power of two
-                # with inert repeats (idx) / -1 neighbors, so the jit
-                # caches key on log-many bucketed shapes, not every |E|
-                # (waste < 2x; the padding rows are real GEMM work and
-                # are accounted as ``launched``)
-                k_pad = 1
-                while k_pad < k:
-                    k_pad *= 2
-                if k_pad > k:
-                    cidx = np.concatenate(
-                        [cidx, np.broadcast_to(cidx[-1:],
-                                               (k_pad - k, 3))])
-                    cnbr = np.concatenate(
-                        [cnbr, np.full((k_pad - k, 8), -1, np.int32)])
-                fresh = self._stack_chain(x, jnp.asarray(cidx),
-                                          jnp.asarray(cnbr))
-                # only the changed-OUTPUT rows graduate to the cache —
-                # margin rows absorbed the zero-halo error and their
-                # cached values are still exact
-                slots = np.nonzero(compute)[0]
-                upd = changed[slots]
-                fresh_rows = fresh[jnp.asarray(np.nonzero(upd)[0])]
-                cache.packed = cache.packed.at[
-                    jnp.asarray(slots[upd])].set(fresh_rows)
-                # ... and only those rows' head tiles hit the canvas:
-                # O(changed) write bytes, pow-of-two repeat-last padding
-                # so the scatter jit buckets like the conv chain (padding
-                # stores rewrite the last real tile's bytes in place)
-                scidx = cache.idx_np[slots[upd]]
-                ph = _head_rows(fresh_rows, self.head)
-                m = scidx.shape[0]
-                m_pad = 1
-                while m_pad < m:
-                    m_pad *= 2
-                if m_pad > m:
-                    scidx = np.concatenate(
-                        [scidx, np.broadcast_to(scidx[-1:],
-                                                (m_pad - m, 3))])
-                    ph = jnp.concatenate(
-                        [ph, jnp.broadcast_to(
-                            ph[-1:], (m_pad - m,) + ph.shape[1:])])
-                cache.canvas = kops.sbnet_scatter_changed(
-                    ph, jnp.asarray(scidx), cache.canvas,
-                    donate=self._donate_canvas())
+                with obs_trace.span("conv_dispatch"):
+                    fresh = self._stack_chain(x, jnp.asarray(cidx),
+                                              jnp.asarray(cnbr))
+                    # only the changed-OUTPUT rows graduate to the cache
+                    # — margin rows absorbed the zero-halo error and
+                    # their cached values are still exact
+                    slots = np.nonzero(compute)[0]
+                    upd = changed[slots]
+                    fresh_rows = fresh[jnp.asarray(np.nonzero(upd)[0])]
+                    cache.packed = cache.packed.at[
+                        jnp.asarray(slots[upd])].set(fresh_rows)
+                    # ... and only those rows' head tiles hit the canvas:
+                    # O(changed) write bytes, pow-of-two repeat-last
+                    # padding so the scatter jit buckets like the conv
+                    # chain (padding stores rewrite the last real tile's
+                    # bytes in place)
+                    scidx = cache.idx_np[slots[upd]]
+                    ph = _head_rows(fresh_rows, self.head)
+                    m = scidx.shape[0]
+                    m_pad = 1
+                    while m_pad < m:
+                        m_pad *= 2
+                    if m_pad > m:
+                        scidx = np.concatenate(
+                            [scidx, np.broadcast_to(scidx[-1:],
+                                                    (m_pad - m, 3))])
+                        ph = jnp.concatenate(
+                            [ph, jnp.broadcast_to(
+                                ph[-1:], (m_pad - m,) + ph.shape[1:])])
+                    cache.canvas = kops.sbnet_scatter_changed(
+                        ph, jnp.asarray(scidx), cache.canvas,
+                        donate=self._donate_canvas())
                 cache.launched_tiles += k_pad
                 stats = ReuseStats(n, int(raw.sum()), n_changed, k,
                                    k_pad, cold=False, gate_stats=s,
@@ -812,8 +827,11 @@ class RoIDetector:
         cache.canvas_bytes_last = stats.canvas_bytes
         cache.canvas_bytes_total += stats.canvas_bytes
         heads = cache.canvas
-        return ([heads[c, :f.shape[0], :f.shape[1]]
-                 for c, f in enumerate(frames)], stats)
+        # the head maps stay on the device: nothing is pulled to the host
+        with obs_trace.span("heads_out", bytes=0):
+            out = [heads[c, :f.shape[0], :f.shape[1]]
+                   for c, f in enumerate(frames)]
+        return out, stats
 
     def superlaunch_forward_reuse(self, frames: Dict[int, List[jax.Array]],
                                   grids: Dict[int, List[np.ndarray]],

@@ -1,8 +1,10 @@
-"""Observability subsystem: default-off no-ops, span recording and the
-Chrome-trace export schema (golden 2-step fleet run with overlapping
-async host/device spans), the typed metrics registry, canonical
-kernel-counter-name enforcement, SLO panels, and the transport
+"""Observability subsystem: default-off no-ops, the fleet step's spans
+(parent and step id in memory, nesting on the profiler's host plane,
+overlapping async host/device spans), the typed metrics registry,
+canonical kernel-counter-name enforcement, SLO panels, and the transport
 empty-distribution guards."""
+import functools
+import glob
 import json
 import os
 import re
@@ -14,12 +16,13 @@ import jax
 
 from repro import obs
 from repro.fleet import fleet_reuse_step
+from repro.fleet.runtime import sharded_fleet_step
 from repro.fleet.sharded import AsyncShardedPipeline, ShardedSuperlaunch
 from repro.kernels import ops
 from repro.launch.mesh import make_fleet_mesh
 from repro.net.batcher import (TransportStats, empty_transport,
                                merge_transport, simulate_transport)
-from repro.obs import export, metrics, slo, trace
+from repro.obs import metrics, slo, trace
 from repro.serving.detector import (DetectorConfig, PackedActivationCache,
                                     RoIDetector)
 
@@ -182,77 +185,166 @@ def test_every_canonical_name_has_a_dispatch_site():
 
 
 # ---------------------------------------------------------------------------
-# golden trace-export schema (satellite: 2-step fleet run)
+# the step's spans: one API, two sinks (memory and the profiler's clock)
 # ---------------------------------------------------------------------------
 
-def _intervals(doc, name):
-    return [(e["ts"], e["ts"] + e["dur"])
-            for e in doc["traceEvents"] if e.get("name") == name]
+def _reuse_case():
+    rng = np.random.default_rng(1)
+    grids = {0: [rng.random((3, 3)) < 0.8], 1: [rng.random((2, 3)) < 0.9]}
+    f0 = {g: [rng.random((a.shape[0] * 8, a.shape[1] * 8, 3)
+                         ).astype(np.float32) for a in gs]
+          for g, gs in grids.items()}
+    f1 = {g: [f.copy() for f in fs] for g, fs in f0.items()}
+    f1[0][0][:8, :8] += 1.0               # one tile of one camera moves
+    return grids, f0, f1
 
 
-def test_two_step_fleet_trace_is_wellformed_chrome_json(small_det,
-                                                        tmp_path):
-    """A 2-step async-pipeline fleet run exports valid Chrome
-    ``trace_event`` JSON: pid/tid/ts/dur/name/args on every span, spans
-    on one thread properly nested or disjoint, and the step-1 host-plan
-    span OVERLAPPING the step-0 device-compute span (the pipeline's
-    host/device overlap made visible)."""
-    det = small_det
+WARM_SPANS = ("stage", "gate", "gate_readback", "reuse_plan",
+              "conv_dispatch", "ref_advance", "heads_out")
+
+
+def _self_ns(evs):
+    """{span_id: duration minus what its child spans cover}."""
+    out = {e.span_id: e.dur_ns for e in evs}
+    for e in evs:
+        if e.parent in out:
+            out[e.parent] -= e.dur_ns
+    return out
+
+
+def test_disabled_step_stores_no_event_and_makes_no_annotation(
+        small_det, monkeypatch):
+    made = []
+    real = trace.TraceAnnotation
+    monkeypatch.setattr(trace, "TraceAnnotation",
+                        lambda name: made.append(name) or real(name))
+    grids, f0, f1 = _reuse_case()
+    cache = PackedActivationCache()
+    fleet_reuse_step(small_det, f0, grids, cache)
+    fleet_reuse_step(small_det, f1, grids, cache)
+    assert trace.span_count() == 0 and made == []
+    with obs.enabled():                   # the same step, observed
+        fleet_reuse_step(small_det, f0, grids, cache)
+    assert made == [e.name for e in sorted(trace.events(),
+                                           key=lambda e: e.t0_ns)]
+    assert set(WARM_SPANS) < set(made)
+
+
+@pytest.mark.parametrize("path", ["single", "sharded"])
+def test_step_spans_carry_parent_and_step_and_self_times_add_up(
+        small_det, path):
+    grids, f0, f1 = _reuse_case()
+    if path == "single":
+        cache = PackedActivationCache()
+        run = functools.partial(fleet_reuse_step, small_det, grids=grids,
+                                cache=cache)
+        root = "fleet_reuse_step"
+    else:
+        rt = ShardedSuperlaunch(small_det, grids, make_fleet_mesh(1))
+        cache = rt.make_cache()
+        run = functools.partial(sharded_fleet_step, rt, cache=cache)
+        root = "sharded_fleet_step"
+    run(frames=f0)
+    with obs.enabled():
+        obs.configure(reset=True)
+        step = cache.steps
+        _, _, stats = run(frames=f1)
+        evs = trace.events()
+    assert all(e.step == step for e in evs)
+    assert {e.name for e in evs} <= set(trace.STEP_SPANS)
+    (top,) = [e for e in evs if e.parent == 0]
+    assert top.name == root
+    children = [e for e in evs if e.parent == top.span_id]
+    assert [e.name for e in sorted(children, key=lambda e: e.t0_ns)] == \
+        list(WARM_SPANS)
+    # siblings follow one another inside the step span
+    for e in children:
+        assert top.t0_ns <= e.t0_ns <= e.t0_ns + e.dur_ns \
+            <= top.t0_ns + top.dur_ns
+    self_ns = _self_ns(evs)
+    assert all(v >= 0 for v in self_ns.values())
+    assert sum(self_ns.values()) == top.dur_ns
+    args = {e.name: e.args for e in evs}
+    assert args["reuse_plan"] == {"raw_changed": stats.raw_changed,
+                                  "computed": stats.computed,
+                                  "launched": stats.launched}
+    readback = {k[0]: v for k, v in metrics.READBACK_BYTES.items()}
+    assert readback.get("gate") == args["gate_readback"]["bytes"] > 0
+    # single device: the heads stay on the device; sharded: the whole
+    # canvas comes back to the host
+    assert readback.get("heads", 0) == args["heads_out"]["bytes"]
+    assert (args["heads_out"]["bytes"] > 0) == (path == "sharded")
+
+
+def test_program_spans_nest_inside_the_callers_span_on_the_profiler_clock(
+        small_det, tmp_path):
+    """Under ``jax.profiler`` the program's spans land on the host plane
+    of the trace, nested inside the caller's own annotation (the
+    benchmark's ``fleet_step``), on the device ops' clock."""
+    from jax.profiler import ProfileData
+    grids, f0, f1 = _reuse_case()
+    cache = PackedActivationCache()
+    fleet_reuse_step(small_det, f0, grids, cache)
+    with obs.enabled():
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation("fleet_step"):
+                fleet_reuse_step(small_det, f1, grids, cache)
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                             "*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in trace.STEP_SPANS + ("fleet_step",):
+                    found.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    (outer,) = found.pop("fleet_step")
+    assert set(found) == {"fleet_reuse_step"} | set(WARM_SPANS)
+    (step,) = found.pop("fleet_reuse_step")
+    assert outer[0] <= step[0] <= step[1] <= outer[1]
+    for name, ivs in found.items():
+        for s, e in ivs:
+            assert step[0] <= s <= e <= step[1], name
+
+
+def test_async_pipeline_spans_nest_under_host_plan_and_overlap(small_det):
+    """The pipeline's ``host_plan`` span takes the shared helpers' spans
+    as children, and step t's planning overlaps step t-1's in-flight
+    ``device_compute`` span."""
     rng = np.random.default_rng(0)
     grids = {0: [rng.random((3, 4)) < 0.6], 1: [rng.random((2, 3)) < 0.7]}
     frames = [{g: [rng.random((a.shape[0] * 8, a.shape[1] * 8, 3)
                               ).astype(np.float32) for a in gs]
                for g, gs in grids.items()} for _ in range(2)]
-    rt = ShardedSuperlaunch(det, grids, make_fleet_mesh(1))
+    rt = ShardedSuperlaunch(small_det, grids, make_fleet_mesh(1))
     pipe = AsyncShardedPipeline(rt, rt.make_cache())
     with obs.enabled():
-        obs.configure(reset=True)
         for f in frames:
             pipe.submit(f)
         pipe.drain()
-        path = tmp_path / "trace.json"
-        doc = export.chrome_trace(str(path))
-
-    with open(path) as f:
-        on_disk = json.load(f)
-    assert on_disk == doc
-    evs = doc["traceEvents"]
-    xs = [e for e in evs if e["ph"] == "X"]
-    assert xs, "no spans recorded"
-    for e in xs:                      # golden field schema
-        assert set(e) >= {"ph", "pid", "tid", "ts", "dur", "name", "args"}
-        assert isinstance(e["pid"], int) and isinstance(e["tid"], int)
-        assert e["ts"] >= 0 and e["dur"] >= 0
-        assert isinstance(e["args"], dict)
-    assert any(e["ph"] == "M" and e["name"] == "process_name"
-               for e in evs)
-    # same-thread spans nest or are disjoint (never partially overlap)
-    by_tid = {}
-    for e in xs:
-        by_tid.setdefault(e["tid"], []).append(
-            (e["ts"], e["ts"] + e["dur"]))
-    for spans in by_tid.values():
-        for i, (a0, a1) in enumerate(spans):
-            for b0, b1 in spans[i + 1:]:
-                disjoint = a1 <= b0 or b1 <= a0
-                nested = (a0 <= b0 and b1 <= a1) or (b0 <= a0 and a1 <= b1)
-                assert disjoint or nested, (spans,)
-    # both pipeline step spans present on their own tracks...
-    hosts = {e["args"]["step"]: (e["ts"], e["ts"] + e["dur"])
-             for e in xs if e["name"] == "host_plan"}
-    devs = {e["args"]["step"]: (e["ts"], e["ts"] + e["dur"])
-            for e in xs if e["name"] == "device_compute"}
+        evs = trace.events()
+    hosts = {e.step: e for e in evs if e.name == "host_plan"}
+    devs = {e.step: e for e in evs if e.name == "device_compute"}
     assert set(hosts) == {0, 1} and set(devs) == {0, 1}
-    # ...and step 1's host planning ran INSIDE step 0's device window
-    h0, h1 = hosts[1]
-    d0, d1 = devs[0]
-    assert max(h0, d0) < min(h1, d1), (hosts, devs)
-    # the device track is a separate named row
-    dev_tid = next(e["tid"] for e in xs if e["name"] == "device_compute")
-    assert dev_tid >= trace.TRACK_TID_BASE
-    assert any(e["ph"] == "M" and e["name"] == "thread_name"
-               and e["tid"] == dev_tid
-               and e["args"]["name"] == "device" for e in evs)
+    by_id = {e.span_id: e for e in evs}
+    for e in evs:
+        if e.name in ("reuse_plan", "ref_advance"):
+            assert by_id[e.parent].name == "host_plan"
+            assert e.step == by_id[e.parent].step
+    # step 0's conv chain is dispatched inside step 1's planning, the
+    # last one by the collect() that drains the pipeline
+    convs = [e for e in evs if e.name == "conv_dispatch"]
+    assert len(convs) == 2
+    assert by_id[convs[0].parent] is hosts[1] and convs[1].parent == 0
+    h, d = hosts[1], devs[0]
+    assert max(h.t0_ns, d.t0_ns) < min(h.t0_ns + h.dur_ns,
+                                       d.t0_ns + d.dur_ns)
+    assert devs[0].tid >= trace.TRACK_TID_BASE > hosts[0].tid
 
 
 # ---------------------------------------------------------------------------

@@ -68,9 +68,11 @@ def _time_min_interleaved(fns, reps: int):
 def _dilation_bound(grids, frames_a, frames_b, tile, n_layers):
     """Independent oracle for the per-step compute bound: scatter the
     raw changed tiles (any haloed-window difference) onto each camera's
-    tile grid, 3x3-dilate 2*(n_layers-1) times with plain numpy
-    morphology (NOT the neighbor-table helper under test), and count the
-    active survivors."""
+    tile grid, 3x3-dilate it twice by the tile rings that the packed
+    layers' n_layers-1 px of halo cross, with plain numpy morphology
+    (NOT the neighbor-table helper under test), and count the active
+    survivors."""
+    rings = -(-(n_layers - 1) // tile)
     total = 0
     for g, fa, fb in zip(grids, frames_a, frames_b):
         gy, gx = g.shape
@@ -81,7 +83,7 @@ def _dilation_bound(grids, frames_a, frames_b, tile, n_layers):
                 win = d[ty * tile:ty * tile + tile + 2,
                         tx * tile:tx * tile + tile + 2]
                 diff[ty, tx] = g[ty, tx] and bool(win.any())
-        for _ in range(2 * (n_layers - 1)):
+        for _ in range(2 * rings):
             dp = np.pad(diff, 1)
             grown = np.zeros_like(diff)
             for dy in (0, 1, 2):

@@ -48,12 +48,13 @@ state:
 ``AsyncShardedPipeline`` overlaps the host and the device: the gate for
 step t is dispatched BEFORE the conv for step t-1, so pulling the gate
 stats blocks only on the gate and the host-side thresholding /
-``reuse_sets`` dilation / table compaction for step t runs WHILE the
-device executes step t-1's conv chain (double-buffered table slots keep
-the in-flight step's tables alive; the cache buffers are donated into
-each conv dispatch).  ``jax.block_until_ready`` happens only at the
-consumer edge (``collect``); the measured host/device overlap fraction
-is a first-class output.
+``reuse_sets`` dilation (``halo_rings`` rings for the changed set,
+as many again for the compute margin) / table compaction for step t
+runs WHILE the device executes step t-1's conv chain (double-buffered
+table slots keep the in-flight step's tables alive; the cache buffers
+are donated into each conv dispatch).  ``jax.block_until_ready``
+happens only at the consumer edge (``collect``); the measured
+host/device overlap fraction is a first-class output.
 """
 from __future__ import annotations
 
@@ -402,7 +403,8 @@ class ShardedSuperlaunch:
         controller's schedule; see ``gate_threshold_schedule``)."""
         with obs_trace.span("reuse_plan") as sp:
             S = self.plan.n_shards
-            n_layers = self.det.num_conv_layers
+            t = self.det.cfg.tile
+            rings = kops.halo_rings(self.det.num_conv_layers, t, t)
             per_changed, per_compute = [], []
             raw_total = changed_total = computed_total = 0
             cold_shards = 0
@@ -428,7 +430,7 @@ class ShardedSuperlaunch:
                     gate_stats.append(None)
                     cold_shards += 1
                 changed, compute = kops.reuse_sets(raw, self._nbr_np[s],
-                                                   n_layers)
+                                                   rings)
                 per_changed.append(changed)
                 per_compute.append(compute)
                 raw_total += int(raw.sum())
@@ -448,7 +450,6 @@ class ShardedSuperlaunch:
                                      per_changed[s], self._cls_np[s])
                 adv[s, :n_s] = True if a is None else a
             cold_mask = ~np.asarray(cache.valid, bool)
-            t = self.det.cfg.tile
             tile_bytes = t * t * int(self.det.head.shape[-1]) * 4
             stats = ShardedReuseStats(
                 total_tiles=self.n_total, raw_changed=raw_total,

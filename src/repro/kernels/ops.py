@@ -298,30 +298,45 @@ def dilate_changed(changed: np.ndarray, nbr: np.ndarray) -> np.ndarray:
     return changed | (changed[safe] & (nbr >= 0)).any(axis=1)
 
 
+def halo_rings(n_layers: int, th: int, tw: int) -> int:
+    """Tile rings a change can cross on its way to the final layer.
+
+    The entry layer reads haloed frame windows, not neighbor tiles, so
+    it moves nothing across tiles.  Each later stride-1 3x3 layer reads
+    a 1-px rim from its neighbor tiles, so it moves a change — and the
+    zero halo that compaction puts at the border of the compute set —
+    one pixel further.  After the ``n_layers - 1`` packed layers that
+    is ``n_layers - 1`` pixels, which crosses
+    ``ceil((n_layers - 1) / min(th, tw))`` tile rings (0 for a one-layer
+    net; 1 for a three-layer net on 16-px tiles).  A strided stage
+    needs its own rule."""
+    return -(-max(n_layers - 1, 0) // min(th, tw))
+
+
 def reuse_sets(raw_changed: np.ndarray, nbr: np.ndarray,
-               n_layers: int) -> "tuple[np.ndarray, np.ndarray]":
+               rings: int) -> "tuple[np.ndarray, np.ndarray]":
     """The delta gate's receptive-field bookkeeping.  ``raw_changed``
-    marks tiles whose ENTRY-LAYER INPUT (the haloed window) changed.
-    Returns (changed_out, compute) bool masks:
+    marks tiles whose ENTRY-LAYER INPUT (the haloed window) changed;
+    ``rings`` is ``halo_rings`` of the net.  Returns (changed_out,
+    compute) bool masks:
 
     * ``changed_out`` — tiles whose FINAL-layer output may differ: the
-      raw set dilated once per packed layer (each packed layer reads a
-      1-tile halo, so change spreads one ring per layer; a reused tile
-      is only bit-safe if its halo donors are static at every depth).
+      raw set dilated ``rings`` times (a reused tile is only bit-safe
+      if no change reaches it through the packed layers' halos).
     * ``compute`` — the tiles the compact launch must convolve:
-      ``changed_out`` dilated once more per packed layer.  The margin
-      absorbs the zero-halo error of compaction: a compact neighbor
-      table zero-halos active tiles outside the set, which corrupts the
-      launch's OUTER rings only — after N-1 packed layers the corruption
-      has walked N-1 tiles inward, so every ``changed_out`` tile (≥ N-1
-      tiles from the compute boundary by construction) is bit-exact.
-      Margin tiles are computed and DISCARDED (the cache keeps their
-      old, still-valid values)."""
+      ``changed_out`` dilated ``rings`` more times.  The margin absorbs
+      the zero-halo error of compaction: a compact neighbor table
+      zero-halos active tiles outside the set, and that error walks one
+      pixel inward per packed layer, the same distance a change walks
+      outward, so every ``changed_out`` tile (``rings`` tiles from the
+      compute boundary by construction) is bit-exact.  Margin tiles are
+      computed and DISCARDED (the cache keeps their old, still-valid
+      values)."""
     changed = np.asarray(raw_changed, bool)
-    for _ in range(max(n_layers - 1, 0)):
+    for _ in range(rings):
         changed = dilate_changed(changed, nbr)
     compute = changed
-    for _ in range(max(n_layers - 1, 0)):
+    for _ in range(rings):
         compute = dilate_changed(compute, nbr)
     return changed, compute
 
@@ -741,7 +756,7 @@ def attention_visit_bound(positions: np.ndarray, block_q: int = 128,
 
 __all__ = ["mask_to_indices", "neighbor_table", "fleet_indices",
            "fleet_neighbor_table", "superlaunch_tables", "ShardPlan",
-           "shard_plan", "record_dispatch", "dilate_changed",
+           "shard_plan", "record_dispatch", "dilate_changed", "halo_rings",
            "reuse_sets", "compact_tables", "choose_block", "sbnet_gather",
            "sbnet_scatter", "sbnet_scatter_fleet", "sbnet_scatter_changed",
            "roi_conv", "roi_conv_entry", "roi_conv_fleet",

@@ -17,10 +17,11 @@ the per-layer packed chain still exists as ``roi_forward_layers`` /
 
 ``fleet_forward_reuse`` adds the TEMPORAL axis: one ``tile_delta_gate``
 pricing dispatch thresholds each active tile's haloed entry window
-against the previous frame, the changed set is dilated per layer
-(``ops.reuse_sets``) and compacted into the launch tables, and unchanged
-tiles composite from a persistent ``PackedActivationCache`` — compute
-proportional to scene motion, bit-identical at threshold 0.
+against the previous frame, the changed set is dilated by the tile rings
+the receptive field crosses (``ops.halo_rings``, ``ops.reuse_sets``) and
+compacted into the launch tables, and unchanged tiles composite from a
+persistent ``PackedActivationCache`` — compute proportional to scene
+motion, bit-identical at threshold 0.
 
 Dense fallback (the paper loads both models and routes large-RoI frames to
 dense YOLO) selected by the density switch.
@@ -64,7 +65,7 @@ class ReuseStats:
     """Per-step accounting of the delta-gated (temporal reuse) path."""
     total_tiles: int               # active tiles across the fleet
     raw_changed: int               # tiles whose haloed input window changed
-    changed_out: int               # ... dilated once per packed layer (the
+    changed_out: int               # ... dilated by ``ops.halo_rings`` (the
     #                                tiles whose final output may differ)
     computed: int                  # compact-set tiles (changed_out + the
     #                                zero-halo margin) — the semantic
@@ -662,20 +663,20 @@ class RoIDetector:
         controller's gate-threshold schedule raises thresholds only on
         cameras it is already shedding, and cameras left at <= 0 keep
         exact-gated bit-identity.
-        The changed set is dilated once per packed layer into the
-        changed-OUTPUT set, once more per layer into the compute margin
-        (``ops.reuse_sets``), compacted into the superlaunch tables
-        (``ops.compact_tables``) and run through the blocked entry +
-        stack chain; unchanged tiles keep their bytes in the PERSISTENT
-        head-map canvas (written by the step that last computed them),
-        and one ``sbnet_scatter_changed`` writes ONLY the refreshed
-        tiles' head rows into it — both sides of a step are O(changed)
-        bytes.  An all-static frame dispatches the gate ALONE: no conv,
-        no scatter, 0 canvas bytes written.  A cache miss (first frame,
-        mask re-solve, canvas change) recomputes fully and seeds the
-        cache + canvas from zeros.  ``threshold`` may also be a
-        (C, N_TILE_CLASSES) per-camera-per-tile-class table (body vs
-        halo rows, see ``tile_class_rows``)."""
+        The changed set is dilated by the receptive field's tile rings
+        (``ops.halo_rings``) into the changed-OUTPUT set, by as many
+        again into the compute margin (``ops.reuse_sets``), compacted
+        into the superlaunch tables (``ops.compact_tables``) and run
+        through the blocked entry + stack chain; unchanged tiles keep
+        their bytes in the PERSISTENT head-map canvas (written by the
+        step that last computed them), and one ``sbnet_scatter_changed``
+        writes ONLY the refreshed tiles' head rows into it — both sides
+        of a step are O(changed) bytes.  An all-static frame dispatches
+        the gate ALONE: no conv, no scatter, 0 canvas bytes written.  A
+        cache miss (first frame, mask re-solve, canvas change)
+        recomputes fully and seeds the cache + canvas from zeros.
+        ``threshold`` may also be a (C, N_TILE_CLASSES) per-camera-per-
+        tile-class table (body vs halo rows, see ``tile_class_rows``)."""
         t = self.cfg.tile
         with obs_trace.span("stage"):
             idx, nbr = self._fleet_tables(grids)
@@ -745,8 +746,8 @@ class RoIDetector:
                 # bit-identity keys on the raw bitwise comparison
                 raw = gate_changed_rows(s, threshold, cache.idx_np[:, 0],
                                         cache.cls_np)
-                changed, compute = kops.reuse_sets(raw, cache.nbr_np,
-                                                   n_layers)
+                changed, compute = kops.reuse_sets(
+                    raw, cache.nbr_np, kops.halo_rings(n_layers, t, t))
                 n_changed = int(changed.sum())
                 k = k_pad = 0
                 if n_changed:

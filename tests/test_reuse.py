@@ -11,8 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.fleet import fleet_reuse_step
+from repro.fleet import fleet_reuse_step, sharded_fleet_step
+from repro.fleet.sharded import ShardedSuperlaunch
 from repro.kernels import ops, ref
+from repro.launch.mesh import make_fleet_mesh
 from repro.serving.detector import (DetectorConfig, PackedActivationCache,
                                     RoIDetector)
 
@@ -120,7 +122,14 @@ def test_dilate_changed_matches_grid_morphology():
     np.testing.assert_array_equal(got, dil[idx[:, 0], idx[:, 1]])
 
 
-def test_reuse_sets_growth_and_nesting():
+@pytest.mark.parametrize("n_layers,tile,rings",
+                         [(1, 16, 0), (3, 16, 1), (3, 8, 1), (6, 4, 2)])
+def test_reuse_sets_growth_and_nesting(n_layers, tile, rings):
+    """changed = raw grown by the receptive field's tile rings, compute =
+    changed grown by as many again: a one-layer net needs none (the entry
+    reads the frame), a stack whose n_layers - 1 px stay inside a tile
+    needs one, a deeper one more."""
+    assert ops.halo_rings(n_layers, tile, tile) == rings
     rng = _rng(4)
     grid = rng.random((10, 10)) < 0.7
     grid[5, 5] = True
@@ -128,20 +137,15 @@ def test_reuse_sets_growth_and_nesting():
     nbr = ops.neighbor_table(idx, grid.shape)
     raw = np.zeros(idx.shape[0], bool)
     raw[np.nonzero((idx[:, 0] == 5) & (idx[:, 1] == 5))[0]] = True
-    changed, compute = ops.reuse_sets(raw, nbr, n_layers=3)
+    changed, compute = ops.reuse_sets(raw, nbr, rings)
     assert (raw <= changed).all() and (changed <= compute).all()
-    # changed = raw dilated N-1 times, compute = changed dilated N-1 more
     d = raw
-    for _ in range(2):
+    for _ in range(rings):
         d = ops.dilate_changed(d, nbr)
     np.testing.assert_array_equal(changed, d)
-    for _ in range(2):
+    for _ in range(rings):
         d = ops.dilate_changed(d, nbr)
     np.testing.assert_array_equal(compute, d)
-    # a 1-layer net needs no dilation at all (entry reads the frame)
-    c1, e1 = ops.reuse_sets(raw, nbr, n_layers=1)
-    np.testing.assert_array_equal(c1, raw)
-    np.testing.assert_array_equal(e1, raw)
 
 
 def test_compact_tables_remap_and_zero_halo():
@@ -387,6 +391,83 @@ def test_gate_stats_shared_with_rate_controller_single_dispatch():
                                   np.asarray(frames[0][0]), grids[0][0],
                                   t, stats=st.gate_stats[idx[:, 0] == 0])
     assert sum(c2.values()) == 0 and f2 == frac0
+
+
+# ---------------------------------------------------------------------------
+# a receptive field wider than a tile: the margin takes two rings
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def deep_det():
+    """Six 3x3 layers on 4-px tiles: the five packed layers walk a change
+    5 px, past a whole tile, so ``halo_rings`` is 2.  4 px is the
+    smallest tile the kernels run bit-exactly in interpret mode (at 3 px
+    the last bits already depend on the launch's row count).  The 1-MiB
+    VMEM budget holds the block at 2 tiles, so the gate compiles in
+    seconds."""
+    det = RoIDetector(DetectorConfig(tile=4, channels=(4,) * 6,
+                                     vmem_budget_bytes=1 << 20),
+                      jax.random.PRNGKey(0))
+    assert ops.halo_rings(det.num_conv_layers, 4, 4) == 2
+    return det
+
+
+def _deep_trace_mismatches(det, path):
+    """Threshold-0 reuse steps over a 2x2-px patch that moves one pixel
+    diagonally per step across a tile corner of a 12x14-tile camera (a
+    second, static camera beside it).  Returns, per step, the number of
+    head elements that differ from a full recompute (a cold step
+    through a fresh cache), and checks that every warm step convolved a
+    margin around its changed set and no more than part of the fleet."""
+    rng = _rng(16)
+    t = det.cfg.tile
+    grids = {0: [np.ones((12, 14), bool), rng.random((4, 5)) < 0.7]}
+    base = [rng.normal(size=(g.shape[0] * t, g.shape[1] * t, 3)
+                       ).astype(np.float32) for g in grids[0]]
+    if path == "sharded":
+        rt = ShardedSuperlaunch(det, grids, make_fleet_mesh(1))
+        cache = rt.make_cache()
+    else:
+        cache = PackedActivationCache()
+    mismatches = []
+    for step in range(4):
+        cur = [f.copy() for f in base]
+        y, x = 6 * t - 1 + step, 7 * t - 1 + step
+        cur[0][y:y + 2, x:x + 2] += 5.0
+        frames = {0: cur}
+        if path == "sharded":
+            got, _, st = sharded_fleet_step(rt, frames, cache, 0.0)
+        else:
+            outs, _, st = fleet_reuse_step(det, _as_jnp(frames), grids,
+                                           cache)
+            got = {0: [np.asarray(o) for o in outs[0]]}
+        full, _ = det.superlaunch_forward_reuse(
+            _as_jnp(frames), grids, PackedActivationCache(), 0.0)
+        if step:
+            assert 0 < st.changed_out < st.computed < st.total_tiles, st
+        mismatches.append(sum(
+            int((np.asarray(a) != np.asarray(b)).sum())
+            for a, b in zip(got[0], full[0])))
+    return mismatches
+
+
+@pytest.mark.parametrize("path", ["single", "sharded"])
+def test_reuse_threshold0_bitwise_when_field_spans_two_rings(deep_det,
+                                                             path):
+    """With ``halo_rings`` == 2 every warm step's heads equal a full
+    recompute bit for bit, on the single-device and the sharded path."""
+    assert _deep_trace_mismatches(deep_det, path) == [0, 0, 0, 0]
+
+
+@pytest.mark.parametrize("path", ["single", "sharded"])
+def test_one_ring_short_margin_is_seen(deep_det, monkeypatch, path):
+    """Negative control: one ring fewer than ``halo_rings`` leaves
+    changed tiles stale or zero-halo-corrupted, and the trace above
+    sees it — so the test can tell an under-sized margin."""
+    orig = ops.halo_rings
+    monkeypatch.setattr(ops, "halo_rings",
+                        lambda n, th, tw: orig(n, th, tw) - 1)
+    assert sum(_deep_trace_mismatches(deep_det, path)[1:]) > 0
 
 
 # ---------------------------------------------------------------------------

@@ -1,20 +1,35 @@
 """Entry: every group on one device through ``fleet.runtime.fleet_reuse_step``
 with one ``PackedActivationCache`` (the delta-gated super-launch).
 
-A step puts the host frames on the device, calls the fleet step and
-waits for every head map it returned.  The heads stay device arrays.
+An entry builds the program's detector from the configuration's
+``detector`` dict and the weights the reference drew
+(``Entry(detector, params, grids, devices, threshold)``).  A step puts
+the host frames on the device, calls the fleet step and waits for every
+head map it returned.  The heads stay device arrays.
 """
 import time
 
 import jax
 
 from repro.fleet.runtime import fleet_reuse_step
-from repro.serving.detector import PackedActivationCache
+from repro.serving.detector import (DetectorConfig, PackedActivationCache,
+                                    RoIDetector)
+
+
+def roi_detector(detector, params):
+    """``RoIDetector`` of the ``detector`` dict, holding ``params`` (the
+    conv stack and head of ``references/roi_detector.py``)."""
+    cfg = DetectorConfig(**dict(detector,
+                                channels=tuple(detector["channels"])))
+    det = RoIDetector(cfg, jax.random.PRNGKey(0))
+    det.weights = list(params["convs"])
+    det.head = params["head"]
+    return det
 
 
 class Entry:
-    def __init__(self, det, grids, devices, threshold):
-        self.det = det
+    def __init__(self, detector, params, grids, devices, threshold):
+        self.det = roi_detector(detector, params)
         self.grids = grids
         self.device = devices[0]
         self.threshold = threshold

@@ -9,14 +9,16 @@ no upload or wait phase of its own.
 """
 import time
 
+from entries.fleet_reuse import roi_detector
 from repro.fleet.runtime import sharded_fleet_step
 from repro.fleet.sharded import ShardedSuperlaunch
 from repro.launch.mesh import make_fleet_mesh
 
 
 class Entry:
-    def __init__(self, det, grids, devices, threshold):
-        self.rt = ShardedSuperlaunch(det, grids, make_fleet_mesh(len(devices)))
+    def __init__(self, detector, params, grids, devices, threshold):
+        self.rt = ShardedSuperlaunch(roi_detector(detector, params), grids,
+                                     make_fleet_mesh(len(devices)))
         self.cache = self.rt.make_cache()
         self.threshold = threshold
 

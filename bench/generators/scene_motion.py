@@ -2,7 +2,9 @@
 
 A traffic mix (``bench/traffic/<mix>.json``) names its generator, a
 module ``bench/generators/<generator>.py`` whose ``Generator`` the
-harness builds as ``Generator(scenes, params, scale, tile, seed)``.  This
+harness builds as ``Generator(scenes, params, scale, tile, seed, rings)``,
+where ``rings`` is how many tile rings the detector's receptive field
+crosses (``harness.layers.rings`` of the reference's layer list).  This
 one reads these parameters:
 
 * ``span``: [first frame, frame count] of the recorded online phase to
@@ -23,9 +25,11 @@ The seed sets the pixels only: every run walks the same transitions in
 the same order from the start of the span, so the sizes the program sees
 are the same for every seed.  The generator also reports, per step, the
 tiles whose input changed and the useful tiles: active tiles whose head
-output depends on a changed pixel.  The detector's receptive field (3 px
-for three 3x3 layers) is under one tile, so those are the changed tiles
-dilated by one ring, within the active set.
+output depends on a changed pixel.  A head pixel reads at most
+``rf_px`` frame pixels beyond its own (``harness.layers.rf_px``), so
+those are the changed tiles dilated by ``rings`` = ceil(rf_px / tile)
+rings, within the active set: one ring for three 3x3 stride-1 layers
+(3 px) at 16-px tiles, several for a deep strided backbone.
 
 What the harness reads of a generator: ``frames`` ({group: [(H, W, 3)
 float32]}, edited in place), ``grids`` ({group: [tile bool grid]}),
@@ -61,13 +65,13 @@ def box_tiles(boxes, frames, n_frames, shape, px_per_tile):
     return out
 
 
-def dilate(m):
-    """One 8-neighbour ring of dilation of a 2-D bool array."""
-    p = np.pad(m, 1)
+def dilate(m, rings):
+    """``rings`` 8-neighbour rings of dilation of a 2-D bool array."""
+    p = np.pad(m, rings)
     out = np.zeros_like(m)
     h, w = m.shape
-    for dy in (0, 1, 2):
-        for dx in (0, 1, 2):
+    for dy in range(2 * rings + 1):
+        for dx in range(2 * rings + 1):
             out |= p[dy:dy + h, dx:dx + w]
     return out
 
@@ -84,9 +88,10 @@ class Generator:
     per ``advance()``.  ``frames`` holds the current host arrays, which
     ``advance`` edits in place."""
 
-    def __init__(self, scenes, params, scale, tile, seed):
+    def __init__(self, scenes, params, scale, tile, seed, rings):
         rng = np.random.default_rng(seed)
         self.tile = tile
+        self.rings = rings
         f0, n = (int(v) for v in params["span"])
         self.span_len = n
         self.period = max(2 * n - 2, 1)
@@ -139,7 +144,7 @@ class Generator:
             ch = self._boxes[cam][a] | self._boxes[cam][b]
             tiles.append(np.nonzero(ch))
             changed += int((ch & act).sum())
-            useful += int((dilate(ch) & act).sum())
+            useful += int((dilate(ch, self.rings) & act).sum())
         return tiles, changed, useful
 
     def _patches(self, step, cam, ys, xs):

@@ -7,9 +7,16 @@ under ``root/bench``:
 * ``traffic/<traffic>.json``, whose ``generator`` names
   ``generators/<generator>.py``,
 * ``scenes/<scene>.npz``,
-* ``entries/<entry>.py``, ``references/<reference>.py``,
+* ``references/<reference>.py``: the configuration's plain reference,
+  which declares its detector layer by layer (``layers``), draws its
+  weights (``init``) and computes its head maps (``forward``,
+  ``pixel_mask``),
+* ``entries/<entry>.py``: builds the program's detector from the
+  configuration's ``detector`` dict and the reference's weights, and
+  runs one fleet step,
 * ``metrics/<metric>.py`` (one reader per per-layer metric),
-* ``work/<kernel>.py`` (one work function per kernel role),
+* ``work/<kernel>.py`` (one work function per kernel role that a layer
+  list names),
 * ``peaks.json``.
 """
 import importlib.util

@@ -12,11 +12,14 @@ cannot pass 100% unless the work or the time is counted wrong.
 
 def bound(ctx, kernel):
     """(share %, "compute" or "memory"), or None when the trace holds no
-    time for the kernel."""
+    time for the kernel or the cell's layer list gives its role no
+    work."""
     seconds = ctx.kernel_seconds(kernel)
     if seconds <= 0:
         return None
     flops, nbytes = ctx.work(kernel)
+    if flops <= 0 and nbytes <= 0:
+        return None
     t_flops = flops / ctx.peak["bf16_flops_per_s"]
     t_bytes = nbytes / ctx.peak["hbm_bytes_per_s"]
     which = "compute" if t_flops >= t_bytes else "memory"

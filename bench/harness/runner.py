@@ -9,6 +9,17 @@ one whole period of the traffic, so every transition the window can meet
 has been run once.  The traffic mix names its generator, a module under
 ``bench/generators/``.
 
+What a configuration brings, all found by name: its reference
+(``bench/references/``), which declares the detector layer by layer
+(``layers``), draws the weights (``init``) and computes the plain head
+map (``forward``, ``pixel_mask``); its entry (``bench/entries/``), which
+builds the program's detector from the ``detector`` dict and those
+weights and runs a fleet step; and a work file (``bench/work/``) for
+each kernel role its layers name that no configuration has named
+before.  The FLOPs of ``mfu.step``, each role's work and the tile rings
+of the generator's useful tiles are counted from the layer list
+(``harness/layers.py``); nothing here knows the architecture.
+
 ``correct`` compares head maps the timed steps returned against the
 plain reference on the same frames: a sample of steps drawn from the
 seed, the step with the most changed tiles among the first
@@ -30,6 +41,7 @@ import time
 
 import numpy as np
 
+from harness import layers as ly
 from harness import trace as tr
 from harness.catalog import Catalog
 
@@ -124,29 +136,27 @@ class Run:
 
     # -- set-up ------------------------------------------------------------
     def setup(self):
-        from repro.serving.detector import DetectorConfig, RoIDetector
-        jax, cfg = self.jax, self.cfg
-        det_cfg = dict(cfg["detector"])
-        det_cfg["channels"] = tuple(det_cfg["channels"])
-        dcfg = DetectorConfig(**det_cfg)
+        cfg = self.cfg
+        detector = cfg["detector"]
+        tile = detector["tile"]
+        self.ref = self.cat.module("references", cfg["reference"])
+        layers = self.ref.layers(detector)
+        ly.check(layers)
         scenes = [self.cat.scene(g["scene"]) for g in cfg["groups"]]
         gen = self.cat.module("generators", self.traffic["generator"])
         self.motion = gen.Generator(scenes, self.traffic, cfg["scale"],
-                                    dcfg.tile, self.seed)
-        self.ref = self.cat.module("references", cfg["reference"])
-        key = jax.random.PRNGKey(self.seed)
-        self.params = jax.jit(self.ref.init, static_argnums=(1, 2))(
-            key, dcfg.channels, dcfg.num_anchors)
-        det = RoIDetector(dcfg, key)
-        det.weights = list(self.params["convs"])
-        det.head = self.params["head"]
-        self.dims = {"tile": dcfg.tile, "cin": 3,
-                     "channels": list(dcfg.channels),
-                     "heads": int(self.params["head"].shape[-1]),
-                     "n_active": self.motion.n_active}
+                                    tile, self.seed, ly.rings(layers, tile))
+        self.params = self.ref.init(self.jax.random.PRNGKey(self.seed),
+                                    detector)
+        self.dims = {"tile": tile, "cin": layers[0]["cin"],
+                     "channels": [layer["cout"] for layer in layers
+                                  if layer["op"] == "conv"],
+                     "heads": sum(h["cout"] for h in ly.heads(layers)),
+                     "n_active": self.motion.n_active,
+                     "layers": layers, "rf_px": ly.rf_px(layers)}
         entry = self.cat.module("entries", cfg["entry"]).Entry
-        self.entry = entry(det, self.motion.grids, self.devices,
-                           cfg["gate_threshold"])
+        self.entry = entry(detector, self.params, self.motion.grids,
+                           self.devices, cfg["gate_threshold"])
 
     def warmup(self, steps=None):
         """The cold step, then ``steps`` warm steps (default: one whole
@@ -284,7 +294,8 @@ class Context:
     """What a per-layer metric reader may read: the measured window's
     step records, wall length and lowerings; the warm-up's lowerings;
     the traced window's step records and trace reduction; the chip's
-    peaks and the kernels' work functions."""
+    peaks and the kernels' work functions; the cell's ``dims``: tile,
+    the reference's layer list and its receptive field ``rf_px``."""
 
     def __init__(self, run, traced_steps, trace, peak):
         self.run = run

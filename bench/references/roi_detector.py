@@ -1,5 +1,20 @@
 """Plain reference of the RoI detector, and the weights both sides use.
 
+What a configuration's reference gives the harness (the contract the
+next configuration's reference keeps too), each from the configuration's
+``detector`` dict:
+
+* ``layers(detector)``: the detector layer by layer, as
+  ``bench/harness/layers.py`` describes a layer: the FLOPs of
+  ``mfu.step``, each kernel role's work and the receptive field that
+  sets the generator's useful tiles are counted from it;
+* ``init(key, detector)``: the weights, drawn from ``key`` on the device
+  in one jitted call; the program runs with these very arrays;
+* ``forward(params, frame, mask, passes="highest")``: the head map of
+  one frame under a pixel mask, with no kernel, cache or batching;
+* ``pixel_mask(grid, tile, shape)``: a camera's detector-tile grid as
+  the (H, W, 1) pixel mask ``forward`` takes.
+
 A conv stack of 3x3 SAME convolutions with ReLU (3 input channels, then
 the widths of ``detector.channels``) and a 1x1 head of
 ``num_anchors * 5`` outputs (objectness and 4 box regressors per
@@ -29,10 +44,34 @@ import numpy as np
 HI = jax.lax.Precision.HIGHEST
 
 
-def init(key, channels, num_anchors):
+def layers(detector):
+    """3x3 stride-1 convs from 3 channels through ``channels`` (the first
+    in the entry kernel, the rest in the stack megakernel), then the 1x1
+    head of ``num_anchors * 5`` outputs, whose rows the changed-only
+    scatter writes into the head canvas."""
+    chans = (3,) + tuple(detector["channels"])
+    out, prev = [], "frame"
+    for i, (ci, co) in enumerate(zip(chans[:-1], chans[1:])):
+        out.append({"name": f"conv{i}", "op": "conv", "k": 3, "stride": 1,
+                    "cin": ci, "cout": co, "stride_in": 1, "inputs": [prev],
+                    "role": "roi_conv_entry" if i == 0 else "roi_conv_stack"})
+        prev = f"conv{i}"
+    out.append({"name": "head", "op": "head", "k": 1, "stride": 1,
+                "cin": chans[-1], "cout": detector["num_anchors"] * 5,
+                "stride_in": 1, "inputs": [prev],
+                "role": "sbnet_scatter_changed"})
+    return out
+
+
+def init(key, detector):
     """Weights from ``key``: conv layer i from ``fold_in(key, i)``, the
     head from ``fold_in(key, 99)``, each normal / sqrt(fan-in)."""
-    chans = (3,) + tuple(channels)
+    return _init(key, tuple(detector["channels"]), detector["num_anchors"])
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _init(key, channels, num_anchors):
+    chans = (3,) + channels
     ws = []
     for i, (ci, co) in enumerate(zip(chans[:-1], chans[1:])):
         w = jax.random.normal(jax.random.fold_in(key, i), (3, 3, ci, co),

@@ -1,5 +1,6 @@
 """The yardstick's arithmetic: kernel work against hand counts, the
-scene-motion generator, the device check and the trace reduction."""
+layer-list counts against the formulas they replaced, the scene-motion
+generator, the device check and the trace reduction."""
 import json
 import os
 import subprocess
@@ -9,14 +10,20 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from harness import runner, trace as tr
+import strided_res
+from harness import layers as ly, runner, trace as tr
 from harness.catalog import Catalog
 from generators.scene_motion import Generator, dilate, pingpong
 from tiny import REPO, tiny_scene
 
-DIMS = {"tile": 16, "cin": 3, "channels": [8, 16, 16], "heads": 10}
+REF = Catalog(REPO).module("references", "roi_detector")
+DETECTOR = {"channels": [8, 16, 16], "tile": 16, "num_anchors": 2}
+DIMS = {"tile": 16, "cin": 3, "channels": [8, 16, 16], "heads": 10,
+        "layers": REF.layers(DETECTOR)}
 STEP = {"n_active": 100, "useful": 10}
 MOTION = {"generator": "scene_motion", "span": [0, 4], "patches": 64}
+WORK = ("tile_delta_gate", "roi_conv_entry", "roi_conv_stack",
+        "sbnet_scatter_changed")
 
 
 @pytest.mark.parametrize("kernel,flops,nbytes", [
@@ -47,9 +54,152 @@ def test_mfu_counts_the_detector_flops_per_pixel():
     assert mfu.read(ctx) == pytest.approx(100 * 20 * 256 * per_px / 2e12)
 
 
+# strided_res's layers at 16-px tiles: the stem at stride 1 (256 px a
+# tile), the rest at stride 2 (64 px a tile)
+STRIDED = {"tile": 16, "layers": strided_res.layers(
+    {"widths": [8, 16], "num_anchors": 2})}
+
+
+@pytest.mark.parametrize("kernel,flops,nbytes", [
+    ("tile_delta_gate", 2 * 100 * 972, 4 * (2 * 100 * 972 + 800)),
+    # stem: the 18x18 window in, 8 channels out at stride 1
+    ("roi_conv_entry", 10 * 256 * 2 * 27 * 8,
+     4 * (10 * (972 + 256 * 8) + 27 * 8)),
+    # down (3x3) and proj (1x1) at stride 2, their sum adds no FLOPs;
+    # the stem's 8 channels in at stride 1, the sum's 16 out at stride 2
+    ("roi_conv_stack", 10 * 64 * 2 * (9 * 8 * 16 + 8 * 16),
+     4 * (10 * (256 * 8 + 64 * 16) + 9 * 8 * 16 + 8 * 16)),
+    # the head's 10 channels at stride 2, read and written
+    ("sbnet_scatter_changed", 0, 4 * 2 * 10 * 64 * 10),
+])
+def test_work_counts_each_layer_at_its_stride(kernel, flops, nbytes):
+    work = Catalog(REPO).module("work", kernel)
+    assert work.work(STEP, STRIDED) == (flops, nbytes)
+
+
+def test_mfu_counts_each_layer_at_its_stride():
+    mfu = Catalog(REPO).module("metrics", "mfu.step")
+    ctx = SimpleNamespace(dims=STRIDED, steps=[STEP], window_s=1.0,
+                          chips=1, peak={"bf16_flops_per_s": 1e12})
+    per_tile = (256 * 2 * 27 * 8 + 64 * 2 * 9 * 8 * 16 + 64 * 2 * 8 * 16
+                + 64 * 2 * 9 * 16 * 10)
+    assert mfu.read(ctx) == pytest.approx(100 * 10 * per_tile / 1e12)
+
+
+@pytest.mark.parametrize("layers,rf,tile,rings", [
+    # three 3x3 stride-1 convs and a 1x1 head: 1 px each
+    (REF.layers(DETECTOR), 3, 16, 1),
+    # stem 1 | 1; down 1 | 2 (pads 0 before, 1 after at stride 2);
+    # proj 1 | 0; head 3x3 at stride 2: 1 + 2 | 2 + 2
+    (STRIDED["layers"], 4, 16, 1),
+    (STRIDED["layers"], 4, 2, 2),
+])
+def test_receptive_field_and_rings(layers, rf, tile, rings):
+    ly.check(layers)
+    assert ly.rf_px(layers) == rf
+    assert ly.rings(layers, tile) == rings
+
+
+@pytest.mark.parametrize("edit", [
+    lambda ls: ls[1].update(stride_in=2),        # stride_in off its input
+    lambda ls: ls[3].update(inputs=["stem", "down"]),  # add across strides
+    lambda ls: ls[2].update(inputs=["head"]),    # a later layer as input
+    lambda ls: ls.pop(),                         # no head
+])
+def test_layer_list_check_refuses(edit):
+    layers = strided_res.layers({"widths": [8, 16], "num_anchors": 2})
+    edit(layers)
+    with pytest.raises(ValueError):
+        ly.check(layers)
+
+
+# the counts as they stood before the layer list, for the one detector
+# they described: three 3x3 stride-1 convs (3 -> channels) and a 1x1 head
+def _old_dilate(m):
+    p = np.pad(m, 1)
+    out = np.zeros_like(m)
+    h, w = m.shape
+    for dy in (0, 1, 2):
+        for dx in (0, 1, 2):
+            out |= p[dy:dy + h, dx:dx + w]
+    return out
+
+
+def _old_work(kernel, step, dims):
+    u, n, t, cin, ch, a = step["useful"], step["n_active"], dims["tile"], \
+        dims["cin"], list(dims["channels"]), dims["heads"]
+    if kernel == "tile_delta_gate":
+        win = (t + 2) ** 2 * cin
+        return 2 * n * win, 4 * (2 * n * win + 8 * n)
+    if kernel == "roi_conv_entry":
+        c1 = ch[0]
+        return (u * t * t * 2 * 9 * cin * c1,
+                4 * (u * ((t + 2) ** 2 * cin + t * t * c1) + 9 * cin * c1))
+    if kernel == "roi_conv_stack":
+        pairs = list(zip(ch[:-1], ch[1:]))
+        return (u * t * t * sum(2 * 9 * a * b for a, b in pairs),
+                4 * (u * t * t * (ch[0] + ch[-1])
+                     + sum(9 * a * b for a, b in pairs)))
+    return 0.0, 4.0 * 2 * u * t * t * a
+
+
+def _old_mfu(ctx):
+    d = ctx.dims
+    chans = [d["cin"]] + list(d["channels"])
+    per_px = sum(2 * 9 * a * b for a, b in zip(chans[:-1], chans[1:]))
+    per_px += 2 * chans[-1] * d["heads"]
+    flops = sum(s["useful"] for s in ctx.steps) * d["tile"] ** 2 * per_px
+    return 100.0 * flops / (ctx.window_s * ctx.chips
+                            * ctx.peak["bf16_flops_per_s"])
+
+
+def _walk(name):
+    """(generator, dims) of one whole walk period: the tiny scene under
+    the tiny cells' traffic, or district4's recorded scenes under
+    ``scene_motion``, each at its configuration's detector."""
+    cat = Catalog(REPO)
+    if name == "tiny":
+        scenes, traffic, scale = [tiny_scene()], MOTION, 0.5
+        detector = DETECTOR
+    else:
+        cfg = cat.config(name)
+        scenes = [cat.scene(g["scene"]) for g in cfg["groups"]]
+        traffic, scale = cat.traffic("scene_motion"), cfg["scale"]
+        detector = cfg["detector"]
+    layers = REF.layers(detector)
+    tile = detector["tile"]
+    gen = Generator(scenes, traffic, scale, tile, 11, ly.rings(layers, tile))
+    dims = {"tile": tile, "cin": 3, "channels": list(detector["channels"]),
+            "heads": detector["num_anchors"] * 5, "layers": layers}
+    return gen, dims
+
+
+@pytest.mark.parametrize("name", ["tiny", "district4"])
+def test_layer_counts_equal_the_old_formulas(name):
+    gen, dims = _walk(name)
+    cat = Catalog(REPO)
+    mfu = cat.module("metrics", "mfu.step")
+    peak = {"bf16_flops_per_s": 197e12}
+    for j in range(gen.period):
+        tiles, changed, useful = gen.transition(j)
+        old_useful = 0
+        for (ys, xs), act in zip(tiles, gen._active):
+            ch = np.zeros_like(act)
+            ch[ys, xs] = True
+            old_useful += int((_old_dilate(ch) & act).sum())
+        assert useful == old_useful
+        step = {"useful": useful, "n_active": gen.n_active}
+        for kernel in WORK:
+            assert cat.module("work", kernel).work(step, dims) == \
+                _old_work(kernel, step, dims)
+        ctx = SimpleNamespace(dims=dims, steps=[step, step], window_s=0.37,
+                              chips=1, peak=peak)
+        assert mfu.read(ctx) == _old_mfu(ctx)
+
+
 def _motion(seed, span=(0, 4)):
     return Generator([tiny_scene()], dict(MOTION, span=list(span)), 0.5,
-                     16, seed)
+                     16, seed, 1)
 
 
 def test_scene_motion_is_deterministic_per_seed():
@@ -83,7 +233,7 @@ def test_changed_set_is_the_box_tiles_and_the_rest_is_static():
             assert np.array_equal(tiles, boxes)
             act = m.grids[0][cam]
             n_changed += int((boxes & act).sum())
-            n_useful += int((dilate(boxes) & act).sum())
+            n_useful += int((dilate(boxes, 1) & act).sum())
         assert (changed, useful) == (n_changed, n_useful)
 
 

@@ -1,6 +1,9 @@
-"""Work of the layer-stack megakernel: every 3x3 conv after the first
-and its ReLU over the useful tiles, reading the entry's activations and
-writing the last layer's (the layers between stay on chip)."""
+"""Work of the layer-stack megakernel: its layers (role
+``roi_conv_stack`` in the reference's layer list: every conv after the
+first and its ReLU) over the useful tiles, reading the first layer's
+input activations and writing the last layer's (the layers between stay
+on chip), each at its own stride."""
+from harness import layers as ly
 
 TRACE_NAMES = (
     r"^%_roi_conv_stack_jit(\.\d+)? = .*custom-call\(",
@@ -8,9 +11,13 @@ TRACE_NAMES = (
 
 
 def work(step, dims):
-    u, t, ch = step["useful"], dims["tile"], list(dims["channels"])
-    pairs = list(zip(ch[:-1], ch[1:]))
-    flops = u * t * t * sum(2 * 9 * a * b for a, b in pairs)
-    nbytes = 4 * (u * t * t * (ch[0] + ch[-1])
-                  + sum(9 * a * b for a, b in pairs))
+    mine = ly.of_role(dims["layers"], "roi_conv_stack")
+    if not mine:
+        return 0.0, 0.0
+    u, t = step["useful"], dims["tile"]
+    first, last = mine[0], mine[-1]
+    io = (ly.px_per_tile(t, first["stride_in"]) * first["cin"]
+          + ly.px_per_tile(t, ly.stride_out(last)) * last["cout"])
+    flops = u * sum(ly.flops_per_tile(layer, t) for layer in mine)
+    nbytes = 4 * (u * io + sum(map(ly.weights, mine)))
     return flops, nbytes

@@ -85,8 +85,9 @@ from repro.serving.detector import (ShardedActivationCache,
 
 
 def _pow2(n: int) -> int:
-    """Smallest power of two >= max(n, 1) — the shape-bucketing rule the
-    single-device compact path uses, applied per shard dimension."""
+    """Smallest power of two >= max(n, 1) — the shape-bucketing rule of
+    each shard dimension (the single-device path pads its conv rows to
+    ``ops.launch_rows`` and its scatter rows to this)."""
     p = 1
     while p < n:
         p *= 2

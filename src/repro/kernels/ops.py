@@ -390,6 +390,22 @@ def compact_tables(idx: np.ndarray, nbr: np.ndarray, keep: np.ndarray
     return idx[keep].astype(np.int32), cnbr[keep]
 
 
+def launch_rows(k: int, n: int) -> int:
+    """The rows a warm launch of ``k`` compacted tiles is padded to: the
+    smallest of four evenly spaced sizes per octave at or above ``k``
+    (``p/2 + j*p/8``, j = 1..4, with ``p`` the next power of two >= k;
+    below 8 rows, ``p`` itself), at most ``n``, the active tile count,
+    so a compute set near the whole fleet reuses the cold step's shapes.
+    The padding is under k/4 from 8 rows up, the jit caches key on at
+    most four shapes an octave, and from 512 rows up every bucket is a
+    multiple of 64 (``MAX_STAGE_BLOCK``), so no kernel pads it again."""
+    p = 1 << max(k - 1, 0).bit_length()
+    if p > 8:
+        step = p // 8
+        p = -(-k // step) * step
+    return min(p, max(k, n))
+
+
 def choose_block(th: int, tw: int, c: int, n_layers: int,
                  vmem_bytes: int = VMEM_LIMIT_BYTES * 3 // 4,
                  dtype_bytes: int = 4) -> int:

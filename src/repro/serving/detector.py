@@ -138,10 +138,10 @@ class ReuseStats:
     #                                quantity the dilation bound describes;
     #                                0 = all-static, gate-only step
     launched: int                  # tiles the launch ACTUALLY convolved:
-    #                                ``computed`` padded to its power-of-
-    #                                two shape bucket (inert rows are real
-    #                                GEMM work — honest perf accounting
-    #                                uses this one)
+    #                                ``computed`` padded to its shape
+    #                                bucket (``ops.launch_rows``; inert
+    #                                rows are real GEMM work — honest
+    #                                perf accounting uses this one)
     cold: bool                     # cache miss: full recompute, no gate
     # the step's shared tile_delta_gate stats rows ((n, STATS_WIDTH)
     # int32 in fleet packing order, None on a cold step) — hand these to
@@ -990,14 +990,12 @@ class RoIDetector:
                     cidx, cnbr = kops.compact_tables(cache.idx_np,
                                                      cache.nbr_np, compute)
                     k = cidx.shape[0]
-                    # pad the ragged compact set up to the next power of
-                    # two with inert repeats (idx) / -1 neighbors, so the
-                    # jit caches key on log-many bucketed shapes, not
-                    # every |E| (waste < 2x; the padding rows are real
+                    # pad the ragged compact set up to its quarter-octave
+                    # bucket with inert repeats (idx) / -1 neighbors, so
+                    # the jit caches key on four shapes an octave, not
+                    # every |E| (waste < 1.25x; the padding rows are real
                     # GEMM work and are accounted as ``launched``)
-                    k_pad = 1
-                    while k_pad < k:
-                        k_pad *= 2
+                    k_pad = kops.launch_rows(k, n)
                     if k_pad > k:
                         cidx = np.concatenate(
                             [cidx, np.broadcast_to(cidx[-1:],
@@ -1020,10 +1018,10 @@ class RoIDetector:
                     cache.packed = cache.packed.at[
                         jnp.asarray(slots[upd])].set(fresh_rows)
                     # ... and only those rows' head tiles hit the canvas:
-                    # O(changed) write bytes, pow-of-two repeat-last
-                    # padding so the scatter jit buckets like the conv
-                    # chain (padding stores rewrite the last real tile's
-                    # bytes in place)
+                    # O(changed) write bytes, power-of-two repeat-last
+                    # padding so the scatter jit keys on log-many shapes
+                    # (padding stores rewrite the last real tile's bytes
+                    # in place)
                     scidx = cache.idx_np[slots[upd]]
                     ph = self._head_rows(fresh_rows)
                     m = scidx.shape[0]

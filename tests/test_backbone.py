@@ -239,8 +239,9 @@ def test_two_rings_are_too_few(ring_det, monkeypatch):
 def test_district_walk_launches_the_rows_it_did():
     """On a fixed walk of the district's detector (three 3x3 layers,
     16-px tiles, three cameras in two groups) the rows
-    ``fleet_forward_reuse`` plans and launches each step are the ones it
-    launched when its rings came from the layer count: (raw changed,
+    ``fleet_forward_reuse`` plans each step are the ones it planned when
+    its rings came from the layer count, and it launches them padded to
+    their quarter-octave bucket (``ops.launch_rows``): (raw changed,
     changed out, computed, launched) per step."""
     det = RoIDetector(DetectorConfig(), jax.random.PRNGKey(0))
     rng = np.random.default_rng(7)
@@ -264,9 +265,9 @@ def test_district_walk_launches_the_rows_it_did():
                   for g, fs in frames.items()}, grids, cache)
         rows.append((st.raw_changed, st.changed_out, st.computed,
                      st.launched))
-    assert rows == [(96, 96, 96, 96), (3, 6, 12, 16), (2, 9, 16, 16),
-                    (8, 21, 40, 64), (6, 16, 28, 32), (4, 14, 24, 32),
-                    (9, 26, 47, 64), (6, 17, 31, 32)]
+    assert rows == [(96, 96, 96, 96), (3, 6, 12, 12), (2, 9, 16, 16),
+                    (8, 21, 40, 40), (6, 16, 28, 28), (4, 14, 24, 24),
+                    (9, 26, 47, 48), (6, 17, 31, 32)]
 
 
 def test_a_backbone_refuses_what_it_cannot_run():

@@ -171,6 +171,67 @@ def test_compact_tables_remap_and_zero_halo():
                 assert kept_slots[cnbr[r, j]] == src
 
 
+@pytest.mark.parametrize("n", [1, 100, 3791, 15164])
+def test_launch_rows_quarter_octave_buckets(n):
+    """``launch_rows(k, n)`` for every k from 1 to 5000: at least k and
+    at most max(k, n), under 1.25 k from 8 rows up, non-decreasing, at
+    most four buckets an octave, a multiple of 64 from 512 up (or the
+    cap n), and the backbone cell's compute sets 2237-2537 all in 2560."""
+    ks = np.arange(1, 5001)
+    rows = np.array([ops.launch_rows(int(k), n) for k in ks])
+    assert (rows >= ks).all() and (rows <= np.maximum(ks, n)).all()
+    assert (rows[ks >= 8] < 1.25 * ks[ks >= 8]).all()
+    assert (np.diff(rows) >= 0).all()
+    under = ks <= n
+    octave = np.ceil(np.log2(ks)).astype(int)
+    for o in np.unique(octave[under]):
+        assert len(set(rows[under & (octave == o)])) <= 4
+    big = under & (ks >= 512) & (rows != n)
+    assert (rows[big] % 64 == 0).all()
+    if n >= 2537:
+        assert {ops.launch_rows(k, n) for k in (2237, 2400, 2537)} == {2560}
+    assert ops.launch_rows(5, n) == min(8, max(5, n))
+
+
+@pytest.mark.parametrize("kind", ["chain", "backbone"])
+def test_reuse_threshold0_bitwise_at_a_quarter_octave_bucket(kind):
+    """A patch moving inside tile (1, 2) of one camera grows to a compute
+    set of 20 tiles (the chain: 1 ring, 4x5 tiles against the frame's
+    corner) or 72 (a small Darknet-53 pattern on 8-px tiles: 3 rings,
+    8x9 tiles): warm steps launch 20 = 5*4 and 80 = 5*16 rows, buckets
+    that are not powers of two, and their heads equal a full recompute
+    bit for bit."""
+    if kind == "chain":
+        det = RoIDetector(DetectorConfig(), jax.random.PRNGKey(0))
+        shape, computed, launched = (6, 8), 20, 20
+    else:
+        det = RoIDetector(DetectorConfig(
+            tile=8, stem=8, stages=((16, 1), (32, 1), (64, 1)),
+            num_anchors=3, num_classes=2, vmem_budget_bytes=1 << 20),
+            jax.random.PRNGKey(3))
+        shape, computed, launched = (9, 10), 72, 80
+    t = det.cfg.tile
+    grids = {0: [np.ones(shape, bool)]}
+    base = _rng(17).normal(size=(shape[0] * t, shape[1] * t, 3)
+                           ).astype(np.float32)
+    cache = PackedActivationCache()
+    for step in range(3):
+        cur = base.copy()
+        y, x = t + t // 2 - 1, 2 * t + t // 2 - 1 + (step % 2)
+        cur[y:y + 2, x:x + 2] += 5.0
+        frames = {0: [jnp.asarray(cur)]}
+        outs, _, st = fleet_reuse_step(det, frames, grids, cache)
+        if not step:
+            continue
+        assert (st.raw_changed, st.computed, st.launched) == \
+            (1, computed, launched)
+        assert launched == ops.launch_rows(computed, st.total_tiles)
+        full, _ = det.superlaunch_forward_reuse(
+            frames, grids, PackedActivationCache(), 0.0)
+        np.testing.assert_array_equal(np.asarray(outs[0][0]),
+                                      np.asarray(full[0][0]))
+
+
 # ---------------------------------------------------------------------------
 # choose_block: VMEM-budgeted tile-block sizing
 # ---------------------------------------------------------------------------

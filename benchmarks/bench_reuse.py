@@ -1,7 +1,7 @@
 """Temporal delta-gated inference benchmark: changed-tile compact
 super-launch + persistent packed-activation cache vs full recompute.
 
-Four panels:
+Five panels:
 
   1. compute proportionality — over a mostly-static fleet trace (per
      step, a couple of cameras move one tile each; the rest are static)
@@ -9,7 +9,7 @@ Four panels:
      not the active set; the reduction vs full recompute is the
      acceptance number (floor 40%).
   2. correctness — at threshold 0 every step's head maps are
-     bit-identical to ``fleet_forward_layers`` full recompute, and the
+     bit-identical to ``superlaunch_forward`` full recompute, and the
      per-step compute count never exceeds the receptive-field dilation
      bound computed by an INDEPENDENT 2D grid-morphology oracle.
   3. dispatch structure — warm changed steps: gate + entry + stack +
@@ -24,9 +24,7 @@ Four panels:
      entry/stack/scatter walks run at.
   5. persistent-canvas accounting — per-step canvas bytes written are
      exactly ``changed_out * tile_bytes`` (bytes ∝ changed fraction, 0
-     on all-static steps), and at a representative dense-RoI config the
-     canvas-resident reference storage is ≤ 1.0x the packed duplicated
-     reference windows it replaced.
+     on all-static steps).
   6. per-tile-class gate-threshold schedule — shed cameras' body tiles
      stop relaunching tiny deltas under a (C, 2) [body, halo] schedule
      while the head-map accuracy floor vs exact recompute holds.
@@ -43,7 +41,6 @@ import numpy as np
 
 from benchmarks.common import save_json, table
 from repro.fleet.runtime import fleet_inference_step, fleet_reuse_step
-from repro.kernels import ops
 from repro.net.encoder import gate_threshold_schedule
 from repro.serving.detector import (DetectorConfig, PackedActivationCache,
                                     RoIDetector)
@@ -153,10 +150,9 @@ def run(verbose: bool = True, quick: bool = False):
         outs, counts, st = fleet_reuse_step(det, as_jnp(frames), grids,
                                             cache)
         assert not st.cold
+        full = det.superlaunch_forward(as_jnp(frames), grids)
         for gid in grids:
-            legacy = det.fleet_forward_layers(
-                [jnp.asarray(f) for f in frames[gid]], grids[gid])
-            for a, b in zip(outs[gid], legacy):
+            for a, b in zip(outs[gid], full[gid]):
                 max_diff = max(max_diff, float(jnp.abs(a - b).max()))
         computed.append(st.computed)
         launched.append(st.launched)
@@ -228,27 +224,7 @@ def run(verbose: bool = True, quick: bool = False):
     fleet_reuse_step(det, flip["cur"], grids, wall_cache)   # settle static
     static_wall = _time_min_interleaved([static_step], max(reps, 3))[0]
 
-    # --- panel 5: reference storage, canvas-resident vs packed ----------
-    # at a dense RoI config (merged cross-camera masks are dense — the
-    # regime the packed duplication tax was paid in) the canvas-resident
-    # reference must cost no more than the (t+2)^2-per-tile duplicated
-    # windows it replaced
-    dense_grids = {gid: [rng.random(gshape) < 0.85 for _ in range(cams)]
-                   for gid in range(K)}
-    for gs in dense_grids.values():
-        for g in gs:
-            g[1, 1] = True
-    fd = as_jnp(mk_frames())
-    ref_bytes = {}
-    for mode in ("canvas", "packed"):
-        c = PackedActivationCache(ref_mode=mode)
-        fleet_reuse_step(det, fd, dense_grids, c)           # cold seed
-        fleet_reuse_step(det, fd, dense_grids, c)           # warm refs
-        ref = c.ref_canvas if mode == "canvas" else c.ref_win
-        ref_bytes[mode] = int(np.asarray(ref).nbytes)
-    ref_storage_ratio = ref_bytes["canvas"] / max(ref_bytes["packed"], 1)
-
-    # --- panel 6: per-tile-class gate-threshold schedule ----------------
+    # --- panel 5: per-tile-class gate-threshold schedule ----------------
     # every other camera shed; its BODY tiles get a high byte threshold,
     # its HALO (mask-boundary) tiles half that — boundary content stays
     # fresher under the same shedding.  Tiny sub-threshold drift must
@@ -302,9 +278,6 @@ def run(verbose: bool = True, quick: bool = False):
         "canvas_bytes_prop_ok": bool(canvas_prop_ok),
         "static_canvas_bytes": static_canvas_bytes,
         "canvas_bytes_total": cache.canvas_bytes_total,
-        "ref_storage_canvas_bytes": ref_bytes["canvas"],
-        "ref_storage_packed_bytes": ref_bytes["packed"],
-        "ref_storage_ratio": ref_storage_ratio,
         "tileclass_accuracy_floor": tileclass_accuracy_floor,
         "tileclass_max_abs_diff": tc_worst,
         "tileclass_sheds_suppressed": bool(tileclass_sheds_suppressed),
@@ -328,8 +301,6 @@ def run(verbose: bool = True, quick: bool = False):
             ["all-static step wall (s)", f"{static_wall:.4f}", "-"],
             ["canvas bytes / step", f"{np.mean(canvas_bytes):.0f}",
              f"{n_active * tile_bytes}"],
-            ["reference storage (bytes)", str(ref_bytes["canvas"]),
-             str(ref_bytes["packed"])],
         ]
         print(f"== delta-gated reuse: {K} groups x {cams} cams, "
               f"{gshape[0]}x{gshape[1]} grids, {n_active} active tiles, "
@@ -341,9 +312,8 @@ def run(verbose: bool = True, quick: bool = False):
         print(f"static step: {static_counts} "
               f"({static_canvas_bytes} canvas bytes); "
               f"changed step: {changed_counts}")
-        print(f"canvas prop ok: {canvas_prop_ok}; ref storage ratio "
-              f"{ref_storage_ratio:.2f}x; tile-class accuracy floor "
-              f"{tileclass_accuracy_floor:.4f} (sheds suppressed: "
+        print(f"canvas prop ok: {canvas_prop_ok}; tile-class accuracy "
+              f"floor {tileclass_accuracy_floor:.4f} (sheds suppressed: "
               f"{tileclass_sheds_suppressed})")
     save_json("bench_reuse.json", payload)
     return payload

@@ -50,7 +50,7 @@ class ServerModel:
 
     The paper's SBNet pays the gather/scatter round-trip (moving ~2x the
     active bytes) once *per conv layer*; our packed-resident kernel chain
-    (kernels/roi_conv.roi_conv_packed) pays it once *per stack* — gather is
+    (kernels/roi_conv.roi_conv_stack) pays it once *per stack* — gather is
     fused into the first conv, layers stay packed via neighbor-table halos,
     and a single scatter materializes the output.  The structural overhead
     is therefore the round-trip constant amortized over ``num_layers``

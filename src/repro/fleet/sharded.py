@@ -79,9 +79,74 @@ from repro.kernels.tile_delta import (COEF_BITS, RUN_BITS,
                                       _raw_gate_canvas)
 from repro.launch.mesh import FLEET_AXIS
 from repro.obs import metrics as obs_metrics, trace as obs_trace
-from repro.serving.detector import (ShardedActivationCache,
-                                    gate_changed_rows, ref_advance_rows,
+from repro.serving.detector import (gate_changed_rows, ref_advance_rows,
                                     tile_class_rows)
+
+
+class ShardedActivationCache:
+    """The ``PackedActivationCache`` sharded along the group axis.
+
+    State for ``ShardedSuperlaunch``: the packed final-layer activations,
+    the persistent head canvas and the canvas-resident gate references
+    live as (S, ...) STACKED arrays, shard axis split over the fleet mesh
+    (``distributed.shardings.fleet_state_sharding``), padded rows
+    pointing at a sacrificial camera slot so SPMD shapes stay uniform
+    across ragged shards.  Validity is PER SHARD: a drift re-solve on
+    one group invalidates only the owning shard (``invalidate_group``,
+    fan-out wired by ``fleet/drift.wire_shard_invalidation``), and the
+    next sharded step recomputes that shard's rows while every other
+    shard keeps serving warm — the single-device cache would have gone
+    fleet-wide cold on the same event.  Mixed cold/warm shards run in
+    the SAME SPMD program: a cold shard's rows are simply all marked
+    raw-changed on the host side."""
+
+    def __init__(self, plan: kops.ShardPlan, gids=None):
+        self.plan = plan
+        self.gids = list(gids) if gids is not None else None
+        self.valid = np.zeros(plan.n_shards, bool)
+        self.packed = None      # (S, n_max, th, tw, C_last) mesh-sharded
+        self.canvas = None      # (S, F_max+1, H, W, A) persistent heads
+        self.ref_canvas = None  # (S, F_max+1, H+2, W', 3) references
+        self.epoch_np = None    # (S, n_max) per-tile last-refresh step
+        self.canvas_bytes_last = 0
+        self.canvas_bytes_total = 0
+        self.invalidations = 0
+        self.shard_invalidations = np.zeros(plan.n_shards, np.int64)
+        self.steps = 0
+        self.cold_steps = 0          # steps with >= 1 cold shard
+        self.launched_tiles = 0
+        self.total_tiles = 0
+
+    def owner_shard(self, group) -> int:
+        """Shard owning ``group`` (a gid when the cache was built with
+        ``gids``, else a plan position)."""
+        pos = self.gids.index(group) if self.gids is not None else int(group)
+        return int(self.plan.assignment[pos])
+
+    def invalidate_group(self, group) -> None:
+        """Mark ONLY the shard owning ``group`` cold; every other
+        shard's cached rows stay valid and keep serving."""
+        s = self.owner_shard(group)
+        self.valid[s] = False
+        self.shard_invalidations[s] += 1
+        self.invalidations += 1
+
+    def invalidate(self, _adapter=None) -> None:
+        """Fleet-wide drop (the PackedActivationCache-compatible hook);
+        accepts and ignores a DriftAdapter argument so it can be
+        registered as a mask listener directly."""
+        self.valid[:] = False
+        self.packed = None
+        self.canvas = None
+        self.ref_canvas = None
+        self.epoch_np = None
+        self.invalidations += 1
+
+    @property
+    def compute_fraction(self) -> float:
+        """Lifetime convolved-tile fraction vs full recompute (padding
+        rows included — they are real launched GEMM work)."""
+        return self.launched_tiles / max(self.total_tiles, 1)
 
 
 def _pow2(n: int) -> int:
@@ -370,11 +435,10 @@ class ShardedSuperlaunch:
                 return jnp.where(mask[0], pad_frames(x[0], t), ref[0])[None]
 
             # pure jnp reference advancement (not a counted kernel
-            # dispatch, like ops.gather_windows): advanced rows' full
-            # window regions take the current frame's content (all
-            # writes carry the SAME frame, so window overlap between
-            # simultaneously-advanced tiles is harmless); donates the
-            # old reference canvas
+            # dispatch): advanced rows' full window regions take the
+            # current frame's content (all writes carry the SAME frame,
+            # so window overlap between simultaneously-advanced tiles is
+            # harmless); donates the old reference canvas
             self._fns[key] = self._shard_map(local, 3, 1, donate=(0,))
         return self._fns[key]
 

@@ -31,10 +31,7 @@ from repro.obs import metrics as obs_metrics
 from repro.kernels.roi_attention import (PAD_POS, block_min_positions,
                                          roi_attention as _roi_attn)
 from repro.kernels.roi_conv import (MAX_WINDOWS_PER_STEP, NEIGHBOR_OFFSETS,
-                                    roi_conv as _roi_conv,
                                     roi_conv_entry as _roi_conv_entry,
-                                    roi_conv_fleet as _roi_conv_fleet,
-                                    roi_conv_packed as _roi_conv_packed,
                                     roi_conv_stack as _roi_conv_stack,
                                     roi_conv_stage as _roi_conv_stage)
 from repro.kernels.sbnet import sbnet_gather as _gather, \
@@ -44,7 +41,6 @@ from repro.kernels.tile_delta import (COEF_BITS, GATE_BODY_BYTES,
                                       GATE_WIN_BYTES, GATE_WIN_EXACT,
                                       RUN_BITS, STATS_WIDTH,
                                       tile_delta as _tile_delta,
-                                      tile_delta_gate as _tile_delta_gate,
                                       tile_delta_gate_canvas as
                                       _tile_delta_gate_canvas,
                                       tile_delta_halo as _tile_delta_halo)
@@ -503,46 +499,6 @@ def sbnet_scatter(packed: jax.Array, idx: jax.Array,
     return _sbnet_scatter_jit(packed, idx, base, interpret_mode())
 
 
-@functools.partial(jax.jit, static_argnames=("th", "tw", "interpret"))
-def _roi_conv_jit(x, w, idx, th, tw, interpret):
-    return _roi_conv(x, w, idx, th, tw, interpret=interpret)
-
-
-def roi_conv(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
-             tw: int) -> jax.Array:
-    """Fused gather+3x3 conv on active tiles -> packed (n, th, tw, Cout)."""
-    record_dispatch("roi_conv")
-    return _roi_conv_jit(x, w, idx, th, tw, interpret_mode())
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _roi_conv_packed_jit(packed, w, nbr, interpret):
-    return _roi_conv_packed(packed, w, nbr, interpret=interpret)
-
-
-def roi_conv_packed(packed: jax.Array, w: jax.Array,
-                    nbr: jax.Array) -> jax.Array:
-    """Packed-resident conv layer: (n, th, tw, Cin) -> (n, th, tw, Cout)
-    with halos pulled from neighbor tiles (``neighbor_table``); no
-    full-frame materialization between layers."""
-    record_dispatch("roi_conv_packed")
-    return _roi_conv_packed_jit(packed, w, nbr, interpret_mode())
-
-
-@functools.partial(jax.jit, static_argnames=("th", "tw", "interpret"))
-def _roi_conv_fleet_jit(x, w, idx, th, tw, interpret):
-    return _roi_conv_fleet(x, w, idx, th, tw, interpret=interpret)
-
-
-def roi_conv_fleet(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
-                   tw: int) -> jax.Array:
-    """Cross-camera fused gather+conv: (C, H, W, Cin) stacked frames +
-    (n, 3) (cam, ty, tx) coords -> packed (n, th, tw, Cout) for the whole
-    camera group in ONE launch (see ``fleet_indices``)."""
-    record_dispatch("roi_conv_fleet")
-    return _roi_conv_fleet_jit(x, w, idx, th, tw, interpret_mode())
-
-
 @functools.partial(jax.jit, static_argnames=("th", "tw", "block", "act",
                                              "interpret"))
 def _roi_conv_entry_jit(x, w, idx, th, tw, block, interpret, bias=None,
@@ -582,7 +538,7 @@ def roi_conv_stack(packed: jax.Array, ws, nbr: jax.Array,
     """The fused layer-stack megakernel: the whole packed conv chain
     (conv + relu per layer, halos DMA'd from the neighbor rows, weight
     prefetch for layer l+1 during layer l) in ONE dispatch —
-    bit-identical to N-1 ``roi_conv_packed`` + relu rounds."""
+    bit-identical to N-1 successive one-layer launches."""
     record_dispatch("roi_conv_stack")
     return _roi_conv_stack_jit(packed, tuple(ws), nbr, int(block),
                                interpret_mode())
@@ -648,7 +604,7 @@ def sbnet_scatter_changed(packed: jax.Array, idx: jax.Array,
     tiles) returns the canvas with NO launch and NO counter bump.
     ``donate=True`` donates the canvas buffer to the launch (in-place
     update, double-buffer-free) — caller must not reuse ``base`` after;
-    only ask for it off-CPU (see ``serving.engine.ring_donate_argnums``)."""
+    only ask for it off-CPU (see ``RoIDetector._donate_canvas``)."""
     if packed.shape[0] == 0:
         return base
     record_dispatch("sbnet_scatter_changed")
@@ -680,38 +636,6 @@ def tile_delta(cur: jax.Array, prev: jax.Array, idx: jax.Array, th: int,
 @functools.partial(jax.jit, static_argnames=("th", "tw", "qstep",
                                              "coef_bits", "run_bits",
                                              "block", "interpret"))
-def _tile_delta_gate_jit(cur_p, ref_win, idx, th, tw, qstep, coef_bits,
-                         run_bits, block, interpret):
-    return _tile_delta_gate(cur_p, ref_win, idx, th, tw, qstep, coef_bits,
-                            run_bits, block=block, interpret=interpret)
-
-
-def tile_delta_gate(cur_p: jax.Array, ref_win: jax.Array, idx: jax.Array,
-                    th: int, tw: int, qstep: float = 8.0,
-                    coef_bits: int = COEF_BITS, run_bits: int = RUN_BITS,
-                    block: int = 1):
-    """The reuse gate's shared delta dispatch: (C, H+2, W', Cin)
-    zero-padded stacked fleet frames + (n, th+2, tw+2, Cin) PACKED
-    per-tile reference windows + (n, 3) (cam, ty, tx) coords ->
-    (stats (n, STATS_WIDTH) int32, windows (n, th+2, tw+2, Cin)).
-    Stats cols 0..3 are the BODY stats (identical to ``tile_delta`` when
-    the references hold the previous frame, feeding the rate
-    controller), col GATE_WIN_EXACT the exact bitwise change count of
-    the haloed entry window, col GATE_WIN_BYTES its quantized byte
-    estimate (bit-exact vs ``ref.tile_delta_gate``); ``windows`` holds
-    the CURRENT haloed windows for on-device reference advancement.
-    ONE launch per fleet step serves both the reuse gate and the
-    encoder's static-tile calibration.  ``block`` tiles share a grid
-    step like the blocked entry kernel."""
-    record_dispatch("tile_delta_gate")
-    return _tile_delta_gate_jit(cur_p, ref_win, idx, th, tw, float(qstep),
-                                int(coef_bits), int(run_bits),
-                                int(block), interpret_mode())
-
-
-@functools.partial(jax.jit, static_argnames=("th", "tw", "qstep",
-                                             "coef_bits", "run_bits",
-                                             "block", "interpret"))
 def _tile_delta_gate_canvas_jit(cur_p, ref_c, idx, th, tw, qstep,
                                 coef_bits, run_bits, block, interpret):
     return _tile_delta_gate_canvas(cur_p, ref_c, idx, th, tw, qstep,
@@ -724,37 +648,23 @@ def tile_delta_gate_canvas(cur_p: jax.Array, ref_c: jax.Array,
                            qstep: float = 8.0, coef_bits: int = COEF_BITS,
                            run_bits: int = RUN_BITS,
                            block: int = 1) -> jax.Array:
-    """The reuse gate against a CANVAS-RESIDENT reference: same stats
-    rows as ``tile_delta_gate`` but the reference side is a second
-    padded canvas of the same shape addressed through the same tile
-    rows — no (n, th+2, tw+2) per-tile window duplication (~1.3x the
-    canvas bytes on overlap-heavy masks) and no windows output (reference
-    advancement writes canvas regions instead).  Counted under the same
-    ``tile_delta_gate`` dispatch name: it IS the gate, structurally —
-    per-step dispatch ceilings stay mode-independent."""
+    """The reuse gate's shared delta dispatch: (C, H+2, W', Cin)
+    zero-padded stacked fleet frames ``cur_p`` against a reference
+    canvas ``ref_c`` of the same shape, both addressed through the same
+    (n, 3) (cam, ty, tx) rows -> (n, STATS_WIDTH) int32 stats rows.
+    Cols 0..3 are the BODY stats (identical to ``tile_delta`` when the
+    reference holds the previous frame, feeding the rate controller),
+    col GATE_WIN_EXACT the exact bitwise change count of the haloed
+    entry window, col GATE_WIN_BYTES its quantized byte estimate
+    (bit-exact vs ``ref.tile_delta_gate``).  ONE launch per fleet step
+    serves both the reuse gate and the encoder's static-tile
+    calibration; ``block`` tiles share a grid step like the blocked
+    entry kernel.  Counted as ``tile_delta_gate``."""
     record_dispatch("tile_delta_gate")
     return _tile_delta_gate_canvas_jit(cur_p, ref_c, idx, th, tw,
                                        float(qstep), int(coef_bits),
                                        int(run_bits), int(block),
                                        interpret_mode())
-
-
-@functools.partial(jax.jit, static_argnames=("th", "tw"))
-def gather_windows(xp: jax.Array, idx: jax.Array, th: int,
-                   tw: int) -> jax.Array:
-    """Gather the packed (n, th+2, tw+2, Cin) haloed windows of the
-    active tiles from a zero-padded (C, H+2, W', Cin) stacked canvas —
-    the seed of the gate's per-tile reference windows (pure jnp table
-    plumbing, not a counted kernel dispatch; warm steps advance
-    references from the gate's own windows output instead)."""
-    cin = xp.shape[-1]
-
-    def take(row):
-        return jax.lax.dynamic_slice(
-            xp, (row[0], row[1] * th, row[2] * tw, 0),
-            (1, th + 2, tw + 2, cin))[0]
-
-    return jax.vmap(take)(idx)
 
 
 @functools.partial(jax.jit, static_argnames=("th", "tw", "qstep",
@@ -779,15 +689,6 @@ def tile_delta_halo(cur: jax.Array, prev: jax.Array, idx: jax.Array,
     return _tile_delta_halo_jit(cur, prev, idx, th, tw, float(qstep),
                                 int(coef_bits), int(run_bits),
                                 interpret_mode())
-
-
-def roi_conv_batched(x: jax.Array, w: jax.Array, idx: jax.Array,
-                     th: int, tw: int) -> jax.Array:
-    """(B, H, W, Cin) -> (B, n, th, tw, Cout), shared active set."""
-    record_dispatch("roi_conv")
-    interpret = interpret_mode()
-    return jax.vmap(lambda xi: _roi_conv_jit(xi, w, idx, th, tw,
-                                             interpret))(x)
 
 
 def pack_tokens(x: jax.Array, keep: jax.Array, block: int = 128):
@@ -870,10 +771,9 @@ __all__ = ["mask_to_indices", "neighbor_table", "fleet_indices",
            "halo_rings", "stage_block", "roi_conv_stage",
            "reuse_sets", "compact_tables", "choose_block", "sbnet_gather",
            "sbnet_scatter", "sbnet_scatter_fleet", "sbnet_scatter_changed",
-           "roi_conv", "roi_conv_entry", "roi_conv_fleet",
-           "roi_conv_packed", "roi_conv_stack", "roi_conv_batched",
-           "tile_delta", "tile_delta_gate", "tile_delta_gate_canvas",
-           "gather_windows", "pad_frames", "tile_delta_halo",
+           "roi_conv_entry", "roi_conv_stack",
+           "tile_delta", "tile_delta_gate_canvas",
+           "pad_frames", "tile_delta_halo",
            "interpret_mode",
            "GATE_BODY_BYTES",
            "GATE_WIN_BYTES", "GATE_WIN_EXACT", "STATS_WIDTH", "pack_tokens",

@@ -154,11 +154,12 @@ def tile_delta(cur, prev, idx, th: int, tw: int, qstep: float = 8.0,
 
 def tile_delta_gate(cur, prev, idx, th: int, tw: int, qstep: float = 8.0,
                     coef_bits: int = 6, run_bits: int = 10):
-    """Numpy oracle for ``kernels/tile_delta.tile_delta_gate``: per active
-    tile of a stacked fleet, the BODY delta stats (cols 0..3, identical
-    to ``tile_delta`` on that camera) plus the HALOED-WINDOW stats the
-    temporal reuse gate thresholds — col 4 the exact bitwise change count
-    of the (th+2, tw+2, C) window, col 5 its quantized byte estimate.
+    """Numpy oracle for ``kernels/tile_delta.tile_delta_gate_canvas``
+    with ``prev`` as the reference canvas: per active tile of a stacked
+    fleet, the BODY delta stats (cols 0..3, identical to ``tile_delta``
+    on that camera) plus the HALOED-WINDOW stats the temporal reuse gate
+    thresholds — col 4 the exact bitwise change count of the (th+2,
+    tw+2, C) window, col 5 its quantized byte estimate.
 
     cur, prev: UNPADDED (C, H, W, Cin) stacked frames (the oracle applies
     the same zero padding the kernel's callers do); idx: (n, 3) int32

@@ -7,9 +7,7 @@ Cin) windows, one element-indexed BlockSpec per window whose index map
 reads the scalar-prefetched (cam, ty, tx) rows, so Mosaic's pipeline
 DMAs the next block's windows while this one computes.  The 3x3 conv is
 9 shifted (block*th*tw, Cin) @ (Cin, Cout) MXU matmuls.  This fuses
-SBNet's gather into the first conv.  ``roi_conv_fleet`` and ``roi_conv``
-are the same kernel without the fused ReLU (one camera group / one
-camera).
+SBNet's gather into the first conv.
 
 ``roi_conv_stack`` — every *subsequent* layer in ONE launch: grid =
 (layer, tile_block).  A layer's packed (n, th, tw, C) output stays in
@@ -26,9 +24,6 @@ conv, residual add), each conv with a bias and the activation.  grid =
 (phase, tile_block), phase 0 the down conv, phase j the j-th block;
 between phases the activations ping-pong through HBM as in the stack,
 so every tile at one stride sees its neighbors' rims at the same layer.
-
-``roi_conv_packed`` is the per-layer form of one stack layer, kept as
-the bit-identical baseline the megakernel is tested against.
 """
 from __future__ import annotations
 
@@ -66,11 +61,9 @@ def contraction_width(cin: int) -> int:
 BACKBONE_ACT = "leaky0.1"
 
 
-def activate(x: jax.Array, act) -> jax.Array:
-    """A conv output through ``act``: None (linear), ``"relu"`` or
-    ``"leaky<slope>"`` (``"leaky0.1"``: Darknet's leaky ReLU)."""
-    if act is None:
-        return x
+def activate(x: jax.Array, act: str) -> jax.Array:
+    """A conv output through ``act``: ``"relu"`` or ``"leaky<slope>"``
+    (``"leaky0.1"``: Darknet's leaky ReLU)."""
     if act == "relu":
         return jnp.maximum(x, 0.0)
     if act.startswith("leaky"):
@@ -131,10 +124,27 @@ def _entry_kernel(idx_ref, *refs, th: int, tw: int, tb: int, act,
     o_ref[...] = o.astype(o_ref.dtype)
 
 
-def _entry_call(x, w, idx, th, tw, *, block, act, interpret, bias=None):
-    """Gather + 3x3 conv (+ bias, + ``act``) of the (n, 3) (cam, ty, tx)
-    tiles of (C, H, W, Cin) stacked frames -> packed (n, th, tw,
-    Cout)."""
+def roi_conv_entry(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
+                   tw: int, *, block: int, interpret: bool, bias=None,
+                   act="relu") -> jax.Array:
+    """The fused backbone's entry layer: gather + 3x3 conv + ReLU in ONE
+    launch for any number of cameras (and camera groups — the (n, 3)
+    (flat_cam, ty, tx) index space is oblivious to how cameras are
+    grouped).  x: (C, H, W, Cin) stacked frames; w: (3, 3, Cin, Cout);
+    idx: (n, 3).  Returns relu'd packed (n, th, tw, Cout) — relu is
+    idempotent, so callers may re-apply it bit-identically.  The packed
+    output feeds ``roi_conv_stack`` for every remaining layer.
+
+    ``block`` tiles (at most ``MAX_WINDOWS_PER_STEP``) share a grid step
+    and one (block*th*tw, Cin) GEMM per tap — bit-identical to the
+    per-tile walk at ``block=1``.  The index list is padded up with
+    repeats of its last row; the duplicate rows' outputs land past ``n``
+    and are sliced off.  An EMPTY tile set returns a zero-row packed
+    tensor with no pallas_call at all.
+
+    A backbone's stem gives a (Cout,) ``bias`` (folded batch norm) and
+    its activation ``act`` (``"leaky0.1"``); both are static options of
+    the kernel."""
     n = idx.shape[0]
     cout = w.shape[-1]
     if n == 0:
@@ -168,49 +178,6 @@ def _entry_call(x, w, idx, th, tw, *, block, act, interpret, bias=None):
         interpret=interpret,
     )(idx_p.reshape(-1), *([xp] * tb), w, *extra)
     return out[:n]
-
-
-def roi_conv_entry(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
-                   tw: int, *, block: int, interpret: bool, bias=None,
-                   act="relu") -> jax.Array:
-    """The fused backbone's entry layer: gather + 3x3 conv + ReLU in ONE
-    launch for any number of cameras (and camera groups — the (n, 3)
-    (flat_cam, ty, tx) index space is oblivious to how cameras are
-    grouped).  x: (C, H, W, Cin) stacked frames; w: (3, 3, Cin, Cout);
-    idx: (n, 3).  Returns relu'd packed (n, th, tw, Cout) — relu is
-    idempotent, so callers may re-apply it bit-identically.  The packed
-    output feeds ``roi_conv_stack`` for every remaining layer.
-
-    ``block`` tiles (at most ``MAX_WINDOWS_PER_STEP``) share a grid step
-    and one (block*th*tw, Cin) GEMM per tap — bit-identical to the
-    per-tile walk at ``block=1``.  The index list is padded up with
-    repeats of its last row; the duplicate rows' outputs land past ``n``
-    and are sliced off.  An EMPTY tile set returns a zero-row packed
-    tensor with no pallas_call at all.
-
-    A backbone's stem gives a (Cout,) ``bias`` (folded batch norm) and
-    its activation ``act`` (``"leaky0.1"``); both are static options of
-    the kernel."""
-    return _entry_call(x, w, idx, th, tw, block=block, act=act,
-                       interpret=interpret, bias=bias)
-
-
-def roi_conv_fleet(x: jax.Array, w: jax.Array, idx: jax.Array, th: int,
-                   tw: int, *, interpret: bool) -> jax.Array:
-    """Cross-camera fused gather+conv (no ReLU), one launch for a camera
-    group: x (C, H, W, Cin) stacked frames, idx (n, 3) (cam, ty, tx).
-    Per-camera zero padding reproduces each camera's own SAME-conv frame
-    boundary, so the output equals per-camera launches."""
-    return _entry_call(x, w, idx, th, tw, block=1, act=None,
-                       interpret=interpret)
-
-
-def roi_conv(x: jax.Array, w: jax.Array, idx: jax.Array, th: int, tw: int,
-             *, interpret: bool) -> jax.Array:
-    """x: (H, W, Cin); w: (3, 3, Cin, Cout); idx: (n, 2) int32 tile coords.
-    Returns packed SAME-conv outputs on active tiles: (n, th, tw, Cout)."""
-    idx3 = jnp.concatenate([jnp.zeros_like(idx[:, :1]), idx], axis=1)
-    return roi_conv_fleet(x[None], w, idx3, th, tw, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -326,13 +293,15 @@ def roi_conv_stack(packed: jax.Array, ws, nbr: jax.Array, *, block: int,
                    interpret: bool) -> jax.Array:
     """The fused layer-stack megakernel: the ENTIRE packed conv chain
     (3x3 conv + ReLU per layer) in ONE ``pallas_call`` with grid =
-    (layer, tile_block), replacing N-1 ``roi_conv_packed`` dispatches.
+    (layer, tile_block).
 
     packed: (n, th, tw, C0) the entry layer's (relu'd) packed output;
     ws: list of (3, 3, C_l, C_{l+1}) weights; nbr: (n, 8) neighbor table
     (``neighbor_table`` / ``fleet_neighbor_table``, -1 = zero halo).
     Returns the last layer's packed (n, th, tw, C_last), bit-identical to
-    the per-layer ``relu(roi_conv_packed(...))`` chain:
+    N-1 successive one-layer launches (each layer is the SAME conv an
+    active tile sees on the scattered full frame, inactive tiles zero —
+    ``ref.roi_conv_packed`` — then ReLU):
 
     * the layer axis is OUTER, so every tile of layer l completes before
       layer l+1 starts; intermediate layers live in two HBM ping-pong
@@ -610,58 +579,3 @@ def roi_conv_stage(packed: jax.Array, down, blocks, nbr: jax.Array, *,
         interpret=interpret,
     )(nbr_p.reshape(-1), x0, wd, bd.reshape(1, cout), w1, b1, w2, b2)
     return out[0][:n]
-
-
-def roi_conv_packed(packed: jax.Array, w: jax.Array, nbr: jax.Array,
-                    *, interpret: bool) -> jax.Array:
-    """One packed-resident conv layer (no ReLU): packed (n, th, tw, Cin)
-    previous layer's output; w: (3, 3, Cin, Cout); nbr: (n, 8) int32
-    neighbor slots (-1 = zero halo, NEIGHBOR_OFFSETS order).  Returns
-    packed (n, th, tw, Cout) — the SAME conv each active tile would see
-    on the scattered full frame where inactive tiles are zero.  One tile
-    per grid step, halo strips read from the neighbor rows; the layers
-    of ``roi_conv_stack`` compute exactly this."""
-    n, th, tw, cin = packed.shape
-    cout = w.shape[-1]
-    k = contraction_width(cin)
-    w = jnp.pad(w, ((0, 0), (0, 0), (0, k - cin), (0, 0)))
-
-    def kernel(nbr_ref, p_ref, w_ref, o_ref):
-        i = pl.program_id(0)
-
-        def strip(k, ys, ny, xs, nx):
-            slot = nbr_ref[i, k]
-            s = p_ref[pl.ds(jnp.maximum(slot, 0), 1), pl.ds(ys, ny),
-                      pl.ds(xs, nx), :][0]
-            return jnp.where(slot >= 0, s, jnp.zeros_like(s))
-
-        center = p_ref[pl.ds(i, 1)][0]
-        top = jnp.concatenate([strip(0, th - 1, 1, tw - 1, 1),
-                               strip(1, th - 1, 1, 0, tw),
-                               strip(2, th - 1, 1, 0, 1)], axis=1)
-        mid = jnp.concatenate([strip(3, 0, th, tw - 1, 1), center,
-                               strip(4, 0, th, 0, 1)], axis=1)
-        bot = jnp.concatenate([strip(5, 0, 1, tw - 1, 1),
-                               strip(6, 0, 1, 0, tw),
-                               strip(7, 0, 1, 0, 1)], axis=1)
-        win = jnp.concatenate([top, mid, bot], axis=0)[None]
-        win = jnp.pad(win, ((0, 0), (0, 0), (0, 0), (0, k - cin)))
-        o_ref[...] = _conv_taps(win, w_ref[...], th, tw, k).astype(
-            o_ref.dtype)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((3, 3, k, cout), lambda i, nbr_ref: (0, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, th, tw, cout),
-                               lambda i, nbr_ref: (i, 0, 0, 0)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, th, tw, cout), packed.dtype),
-        interpret=interpret,
-    )(nbr, packed, w)

@@ -44,7 +44,7 @@ RUN_BITS = 10
 
 STATS_WIDTH = 8          # output lane padding; cols 0..5 are live
 
-# ``tile_delta_gate`` stats-row columns.  Cols 0..3 are the BODY stats and
+# The gate's stats-row columns.  Cols 0..3 are the BODY stats and
 # match ``tile_delta`` / ``ref.tile_delta`` bit for bit (so the rate
 # controller can threshold the shared dispatch exactly as before); cols
 # 4..5 are the HALOED-WINDOW stats the temporal reuse gate thresholds.
@@ -184,64 +184,6 @@ def tile_delta_gate_canvas(cur_p: jax.Array, ref_c: jax.Array,
         interpret=interpret,
     )(idx.reshape(-1), *([cur_p] * tb), *([ref_c] * tb))
     return stats.reshape(n_pad, STATS_WIDTH)[:n]
-
-
-def _gate_packed_kernel(idx_ref, *refs, th: int, tw: int, tb: int,
-                        qstep: float, coef_bits: int, run_bits: int):
-    ref_ref, o_ref, w_ref = refs[tb:]
-    for j in range(tb):
-        cur = refs[j][...][:, :, :tw + 2]
-        o_ref[j:j + 1] = _window_stats(cur, ref_ref[j:j + 1], th, tw, qstep,
-                                       coef_bits, run_bits)
-        w_ref[j:j + 1] = cur                 # current windows, packed
-
-
-def tile_delta_gate(cur_p: jax.Array, ref_win: jax.Array, idx: jax.Array,
-                    th: int, tw: int, qstep: float = 8.0,
-                    coef_bits: int = COEF_BITS, run_bits: int = RUN_BITS,
-                    *, block: int, interpret: bool):
-    """The gate against PACKED per-tile references: same stats rows as
-    ``tile_delta_gate_canvas``, but the comparison side is ref_win (n,
-    th+2, tw+2, Cin) — each tile's haloed window content as of that
-    tile's last refresh — so one tile's reference can never alias a
-    neighbor's through the window overlap.  Returns (stats, windows):
-    windows (n, th+2, tw+2, Cin) are the CURRENT haloed windows, so
-    callers advance references with a pure on-device
-    ``.at[rows].set(windows[rows])``.  Bit-exact vs
-    ``ref.tile_delta_gate``."""
-    n = idx.shape[0]
-    cur_p = widen_padded(cur_p, tw)
-    cin = cur_p.shape[-1]
-    _, tb, n_pad = balanced_split(n, min(max(block, 1),
-                                         MAX_WINDOWS_PER_STEP))
-    idx = pad_repeat_last(idx, n_pad)
-    ref_win = pad_repeat_last(ref_win, n_pad)
-    kernel = functools.partial(_gate_packed_kernel, th=th, tw=tw, tb=tb,
-                               qstep=qstep, coef_bits=coef_bits,
-                               run_bits=run_bits)
-    win_spec = pl.BlockSpec((tb, th + 2, tw + 2, cin),
-                            lambda b, idx_ref: (b, 0, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_pad // tb,),
-        in_specs=window_specs(tb, th, tw, cin) + [win_spec],
-        out_specs=[
-            pl.BlockSpec((tb, 1, 1, STATS_WIDTH),
-                         lambda b, idx_ref: (b, 0, 0, 0)),
-            win_spec,
-        ],
-    )
-    stats, wins = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad, 1, 1, STATS_WIDTH), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad, th + 2, tw + 2, cin),
-                                 cur_p.dtype),
-        ],
-        interpret=interpret,
-    )(idx.reshape(-1), *([cur_p] * tb), ref_win)
-    return stats.reshape(n_pad, STATS_WIDTH)[:n], wins[:n]
 
 
 def tile_delta(cur: jax.Array, prev: jax.Array, idx: jax.Array, th: int,

@@ -33,7 +33,6 @@ from repro.obs import state
 KERNEL_NAMES = frozenset({
     "sbnet_gather", "sbnet_scatter", "sbnet_scatter_fleet",
     "sbnet_scatter_changed",
-    "roi_conv", "roi_conv_packed", "roi_conv_fleet",
     "roi_conv_entry", "roi_conv_stack", "roi_conv_stage",
     "tile_delta", "tile_delta_gate", "tile_delta_halo",
     "roi_attention",

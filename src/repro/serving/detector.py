@@ -19,17 +19,16 @@ prefetch), and a *single* scatter materializes the full-frame head maps.
 Every RoI forward — one camera, one group, or the WHOLE FLEET via
 ``superlaunch_forward`` — is exactly 3 dispatches (2 for a 1-layer
 stack), independent of camera count, group count and layer count.  The
-old SBNet formulation paid a full-frame scatter + HBM re-slice per layer;
-the per-layer packed chain still exists as ``roi_forward_layers`` /
-``fleet_forward_layers`` (the bit-identical A/B baseline).
+old SBNet formulation paid a full-frame scatter + HBM re-slice per layer.
 
-``fleet_forward_reuse`` adds the TEMPORAL axis: one ``tile_delta_gate``
-pricing dispatch thresholds each active tile's haloed entry window
-against the previous frame, the changed set is dilated by the tile rings
-the receptive field crosses (``ops.halo_rings``, ``ops.reuse_sets``) and
-compacted into the launch tables, and unchanged tiles composite from a
-persistent ``PackedActivationCache`` — compute proportional to scene
-motion, bit-identical at threshold 0.
+``fleet_forward_reuse`` adds the TEMPORAL axis: one
+``tile_delta_gate_canvas`` pricing dispatch thresholds each active
+tile's haloed entry window against its reference content, the changed
+set is dilated by the tile rings the receptive field crosses
+(``ops.halo_rings``, ``ops.reuse_sets``) and compacted into the launch
+tables, and unchanged tiles composite from a persistent
+``PackedActivationCache`` — compute proportional to scene motion,
+bit-identical at threshold 0.
 
 Dense fallback (the paper loads both models and routes large-RoI frames to
 dense YOLO) selected by the density switch.
@@ -42,7 +41,7 @@ benchmarks:
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -249,47 +248,32 @@ class PackedActivationCache:
     ``RoIDetector._head_canvas`` — device-resident across steps
     — warm steps scatter only this step's changed tiles into it, an
     all-static step writes 0 canvas bytes with no scatter launch), and
-    the delta gate's reference content in one of two modes:
+    the delta gate's reference content: a second padded canvas
+    (``ref_canvas``, same shape as the padded stacked frames the gate
+    reads, ``ops.pad_frames``) holding each tile's window content as of
+    its last refresh, plus an (n,) per-tile refresh-EPOCH vector
+    advanced by ``ref_advance_rows``.  Reference advancement writes the
+    advanced rows' FULL haloed window regions from the current frame,
+    so overlap writes between simultaneously-advanced neighbors carry
+    identical content; at threshold <= 0 the wholesale assignment is a
+    free alias of the current padded frame (previous-frame semantics).
 
-    * ``ref_mode="canvas"`` (default): a second padded canvas
-      (``ref_canvas``, same shape as the padded stacked frames the
-      gate reads, ``ops.pad_frames``) holding each tile's window content as of
-      its last refresh, plus an (n,) per-tile refresh-EPOCH vector
-      advanced by ``ref_advance_rows`` — no per-tile window duplication
-      (packed windows store every overlap rim twice, ~1.3x the canvas
-      bytes on halo-heavy masks).  Reference advancement writes the
-      advanced rows' FULL haloed window regions from the current frame,
-      so overlap writes between simultaneously-advanced neighbors carry
-      identical content; at threshold <= 0 the wholesale assignment is
-      a free alias of the current padded frame (previous-frame
-      semantics, bit-identical to the packed mode by construction).
-    * ``ref_mode="packed"``: the legacy PACKED per-tile windows
-      (``ref_win``, (n, th+2, tw+2, 3)) — each tile's reference is
-      private, so one tile's advance can never alias a neighbor's
-      reference through the window overlap.  Kept as the semantics
-      oracle the canvas mode is asserted bit-exact against at every
-      threshold (tests/test_canvas.py).
+    Under a lossy threshold only refreshed rows advance, so each tile's
+    sub-threshold drift ACCUMULATES against its own reference and trips
+    the gate once it crosses the threshold instead of creeping into the
+    cache unboundedly.  Content-keyed on the fleet's grid digests and
+    canvas shape, so any mask change — a drift re-solve, a shrink
+    adoption, a different camera set — misses the key and forces a full
+    recompute (cold scatter rebuilds the canvas from zeros: stale canvas
+    content can never leak across a re-solve); ``invalidate`` is the
+    explicit hook ``fleet/drift.DriftAdapter`` mask listeners call for
+    the same effect (belt and braces: the digest key alone already
+    invalidates)."""
 
-    Under a lossy threshold only refreshed rows advance in either mode,
-    so each tile's sub-threshold drift ACCUMULATES against its own
-    reference and trips the gate once it crosses the threshold instead
-    of creeping into the cache unboundedly.  Content-keyed on the
-    fleet's grid digests and canvas shape, so any mask change — a drift
-    re-solve, a shrink adoption, a different camera set — misses the
-    key and forces a full recompute (cold scatter rebuilds the canvas
-    from zeros: stale canvas content can never leak across a re-solve);
-    ``invalidate`` is the explicit hook ``fleet/drift.DriftAdapter``
-    mask listeners call for the same effect (belt and braces: the
-    digest key alone already invalidates)."""
-
-    def __init__(self, ref_mode: str = "canvas"):
-        if ref_mode not in ("canvas", "packed"):
-            raise ValueError(f"unknown ref_mode {ref_mode!r}")
-        self.ref_mode = ref_mode
+    def __init__(self):
         self.key: Optional[tuple] = None
         self.packed: Optional[jax.Array] = None   # (n, th, tw, C_last)
         self.canvas: Optional[jax.Array] = None   # (C, H, W, A) head maps
-        self.ref_win: Optional[jax.Array] = None  # (n, th+2, tw+2, 3)
         self.ref_canvas: Optional[jax.Array] = None  # (C, H+2, W', 3)
         self.epoch_np: Optional[np.ndarray] = None   # (n,) last refresh
         self.idx_np: Optional[np.ndarray] = None  # (n, 3) static tables
@@ -308,80 +292,11 @@ class PackedActivationCache:
         self.key = None
         self.packed = None
         self.canvas = None
-        self.ref_win = None
         self.ref_canvas = None
         self.epoch_np = None
         self.idx_np = None
         self.nbr_np = None
         self.cls_np = None
-        self.invalidations += 1
-
-    @property
-    def compute_fraction(self) -> float:
-        """Lifetime convolved-tile fraction vs full recompute (padding
-        rows included — they are real launched GEMM work)."""
-        return self.launched_tiles / max(self.total_tiles, 1)
-
-
-class ShardedActivationCache:
-    """The ``PackedActivationCache`` sharded along the group axis.
-
-    State for ``fleet/sharded.ShardedSuperlaunch``: the packed final-
-    layer activations and per-tile reference windows live as (S, n_max,
-    ...) STACKED arrays, shard axis split over the fleet mesh
-    (``distributed.shardings.fleet_state_sharding``), padded rows
-    pointing at a sacrificial camera slot so SPMD shapes stay uniform
-    across ragged shards.  Validity is PER SHARD: a drift re-solve on
-    one group invalidates only the owning shard (``invalidate_group``,
-    fan-out wired by ``fleet/drift.wire_shard_invalidation``), and the
-    next sharded step recomputes that shard's rows while every other
-    shard keeps serving warm — the single-device cache would have gone
-    fleet-wide cold on the same event.  Mixed cold/warm shards run in
-    the SAME SPMD program: a cold shard's rows are simply all marked
-    raw-changed on the host side."""
-
-    def __init__(self, plan: "kops.ShardPlan", gids=None):
-        self.plan = plan
-        self.gids = list(gids) if gids is not None else None
-        self.valid = np.zeros(plan.n_shards, bool)
-        self.packed = None      # (S, n_max, th, tw, C_last) mesh-sharded
-        self.ref_win = None     # (S, n_max, th+2, tw+2, 3) mesh-sharded
-        self.canvas = None      # (S, F_max+1, H, W, A) persistent heads
-        self.ref_canvas = None  # (S, F_max+1, H+2, W', 3) references
-        self.epoch_np = None    # (S, n_max) per-tile last-refresh step
-        self.canvas_bytes_last = 0
-        self.canvas_bytes_total = 0
-        self.invalidations = 0
-        self.shard_invalidations = np.zeros(plan.n_shards, np.int64)
-        self.steps = 0
-        self.cold_steps = 0          # steps with >= 1 cold shard
-        self.launched_tiles = 0
-        self.total_tiles = 0
-
-    def owner_shard(self, group) -> int:
-        """Shard owning ``group`` (a gid when the cache was built with
-        ``gids``, else a plan position)."""
-        pos = self.gids.index(group) if self.gids is not None else int(group)
-        return int(self.plan.assignment[pos])
-
-    def invalidate_group(self, group) -> None:
-        """Mark ONLY the shard owning ``group`` cold; every other
-        shard's cached rows stay valid and keep serving."""
-        s = self.owner_shard(group)
-        self.valid[s] = False
-        self.shard_invalidations[s] += 1
-        self.invalidations += 1
-
-    def invalidate(self, _adapter=None) -> None:
-        """Fleet-wide drop (the PackedActivationCache-compatible hook);
-        accepts and ignores a DriftAdapter argument so it can be
-        registered as a mask listener directly."""
-        self.valid[:] = False
-        self.packed = None
-        self.ref_win = None
-        self.canvas = None
-        self.ref_canvas = None
-        self.epoch_np = None
         self.invalidations += 1
 
     @property
@@ -398,7 +313,7 @@ def _head_rows(packed: jax.Array, head: jax.Array) -> jax.Array:
     head-then-scatter is bit-identical to scatter-then-head — which is
     what lets the persistent canvas hold HEAD-space values and a warm
     step write only the changed tiles' head rows (pure jnp, not a
-    counted kernel dispatch, like ``ops.gather_windows``)."""
+    counted kernel dispatch)."""
     n, th, tw, c = packed.shape
     return jnp.dot(packed.reshape(n * th * tw, c), head,
                    precision=jax.lax.Precision.HIGHEST).reshape(
@@ -439,33 +354,23 @@ def _window_region_mask(idx_rows, t: int, shape) -> np.ndarray:
     return m
 
 
-def _advance_refs(cache: "PackedActivationCache", xp: jax.Array,
-                  adv: Optional[np.ndarray], windows: Optional[jax.Array],
-                  t: int) -> None:
+def _advance_refs(cache: PackedActivationCache, xp: jax.Array,
+                  adv: Optional[np.ndarray], t: int) -> None:
     """Advance the gate references per ``ref_advance_rows``'s verdict and
-    stamp the per-tile refresh epochs.  ``adv is None`` = every row: in
-    canvas mode that is a FREE alias of the current padded frame (the
-    threshold <= 0 previous-frame fast path); a partial advance writes
-    the advanced rows' full window regions via one masked select."""
+    stamp the per-tile refresh epochs.  ``adv is None`` = every row: a
+    FREE alias of the current padded frame (the threshold <= 0
+    previous-frame fast path); a partial advance writes the advanced
+    rows' full window regions via one masked select."""
     step = cache.steps
     with obs_trace.span("ref_advance"):
-        if cache.ref_mode == "packed":
-            if adv is None:
-                cache.ref_win = windows
-            elif adv.any():
-                rows = jnp.asarray(np.nonzero(adv)[0])
-                cache.ref_win = cache.ref_win.at[rows].set(windows[rows])
-        else:
-            if adv is None:
-                cache.ref_canvas = xp
-            elif adv.any():
-                mask = _window_region_mask(cache.idx_np[adv], t,
-                                           cache.ref_canvas.shape)
-                cache.ref_canvas = jnp.where(jnp.asarray(mask), xp,
-                                             cache.ref_canvas)
         if adv is None:
+            cache.ref_canvas = xp
             cache.epoch_np[:] = step
         elif adv.any():
+            mask = _window_region_mask(cache.idx_np[adv], t,
+                                       cache.ref_canvas.shape)
+            cache.ref_canvas = jnp.where(jnp.asarray(mask), xp,
+                                         cache.ref_canvas)
             cache.epoch_np[adv] = step
 
 
@@ -565,23 +470,16 @@ class RoIDetector:
         self.strides = sorted({layer["stride_in"] * layer["stride"]
                                for layer in self.layers
                                if layer["op"] == "conv"})
-        # whether the persistent head canvas is donated to the changed-
-        # only scatter (resolved lazily from the serving engine's shared
-        # ring-donation idiom: in-place off-CPU, copy on CPU)
-        self._donate_canvas_flag: Optional[bool] = None
 
-    def _donate_canvas(self) -> bool:
+    @staticmethod
+    def _donate_canvas() -> bool:
         """Donate the head-canvas buffer to ``sbnet_scatter_changed``?
-        Same rule as ``ServingEngine``'s group-cache ring
-        (``engine.ring_donate_argnums``): donate off-CPU so the warm-step
-        canvas update is in-place (O(changed) traffic), never on CPU
-        (donation is ignored there).  Callers keep what a step returned:
-        its per-camera heads are slices copied out of the canvas, so a
-        later step's donation never deletes them."""
-        if self._donate_canvas_flag is None:
-            from repro.serving.engine import ring_donate_argnums
-            self._donate_canvas_flag = bool(ring_donate_argnums(0))
-        return self._donate_canvas_flag
+        Off the CPU, so the warm-step canvas update is in place
+        (O(changed) traffic); never on the CPU, which ignores donation.
+        Callers keep what a step returned: its per-camera heads are
+        slices copied out of the canvas, so a later step's donation never
+        deletes them."""
+        return jax.default_backend() != "cpu"
 
     # -- dense path ----------------------------------------------------------
     def dense_forward(self, x: jax.Array,
@@ -764,26 +662,6 @@ class RoIDetector:
         full = kops.sbnet_scatter(packed, idx, base)   # the scatter
         return full @ self.head
 
-    def roi_forward_layers(self, x: jax.Array, grid: np.ndarray
-                           ) -> jax.Array:
-        """The per-layer packed chain (one ``roi_conv_packed`` dispatch
-        per layer after the fused gather) — kept as the bit-identical A/B
-        baseline for the megakernel; K×(N+1)-dispatch regime."""
-        self._plain_chain_only()
-        t = self.cfg.tile
-        idx, _, nbr = self._mask_tables(grid)
-        packed = None
-        for li, w in enumerate(self.weights):
-            if li == 0:
-                # the gather: haloed windows sliced straight off the frame
-                packed = kops.roi_conv(x, w, idx, t, t)
-            else:
-                packed = kops.roi_conv_packed(packed, w, nbr)
-            packed = jax.nn.relu(packed)
-        base = jnp.zeros(x.shape[:2] + (packed.shape[-1],), packed.dtype)
-        full = kops.sbnet_scatter(packed, idx, base)
-        return full @ self.head
-
     def _plain_chain_only(self) -> None:
         if self.cfg.stages:
             raise ValueError("a backbone runs on fleet_forward_reuse "
@@ -830,28 +708,6 @@ class RoIDetector:
         return [heads[c, :f.shape[0], :f.shape[1]]
                 for c, f in enumerate(frames)]
 
-    def fleet_forward_layers(self, frames: List[jax.Array],
-                             grids: List[np.ndarray]) -> List[jax.Array]:
-        """Per-layer fleet chain (1 + (N-1) + 1 dispatches per call) —
-        the bit-identical A/B baseline for the fused path."""
-        self._plain_chain_only()
-        t = self.cfg.tile
-        idx, nbr = self._fleet_tables(grids)
-        x, canvas_h, canvas_w = self._stack_frames(frames, grids)
-        packed = None
-        for li, w in enumerate(self.weights):
-            if li == 0:
-                packed = kops.roi_conv_fleet(x, w, idx, t, t)
-            else:
-                packed = kops.roi_conv_packed(packed, w, nbr)
-            packed = jax.nn.relu(packed)
-        base = jnp.zeros((len(frames), canvas_h, canvas_w,
-                          packed.shape[-1]), packed.dtype)
-        full = kops.sbnet_scatter_fleet(packed, idx, base)
-        heads = full @ self.head
-        return [heads[c, :f.shape[0], :f.shape[1]]
-                for c, f in enumerate(frames)]
-
     def superlaunch_forward(self, frames: Dict[int, List[jax.Array]],
                             grids: Dict[int, List[np.ndarray]]
                             ) -> Dict[int, List[jax.Array]]:
@@ -884,8 +740,8 @@ class RoIDetector:
                             ) -> Tuple[List[jax.Array], ReuseStats]:
         """``fleet_forward`` with compute proportional to CHANGED tiles.
 
-        One shared ``tile_delta_gate`` dispatch prices every active
-        tile's haloed entry window against the cached previous frame; a
+        One shared ``tile_delta_gate_canvas`` dispatch prices every
+        active tile's haloed entry window against the reference canvas; a
         tile is *changed* when its window byte estimate exceeds
         ``threshold`` (at threshold <= 0 the exact bitwise change count
         gates instead, making reuse BIT-IDENTICAL to full recompute).
@@ -931,9 +787,7 @@ class RoIDetector:
         tile_bytes = (ht * ht * self.head.shape[-1]
                       * jnp.dtype(self.head.dtype).itemsize)
         cold = (cache.key != key or cache.packed is None
-                or cache.canvas is None
-                or (cache.ref_win is None if cache.ref_mode == "packed"
-                    else cache.ref_canvas is None))
+                or cache.canvas is None or cache.ref_canvas is None)
         if cold:
             # miss: mask/canvas changed (or first frame) — recompute all
             # tiles through the fused chain, seed the cache tables and
@@ -943,12 +797,7 @@ class RoIDetector:
             with obs_trace.span("conv_dispatch") as sp:
                 self._count_rows(sp, n)
                 cache.packed = self._stack_chain(x, idx, nbr)
-                if cache.ref_mode == "packed":
-                    cache.ref_win = kops.gather_windows(xp, idx, t, t)
-                    cache.ref_canvas = None
-                else:
-                    cache.ref_canvas = xp      # free alias, full advance
-                    cache.ref_win = None
+                cache.ref_canvas = xp          # free alias, full advance
                 cache.idx_np = np.asarray(idx)
                 cache.nbr_np = np.asarray(nbr)
                 cache.cls_np = tile_class_rows(cache.nbr_np)
@@ -962,15 +811,9 @@ class RoIDetector:
                                canvas_bytes=n * tile_bytes)
         else:
             with obs_trace.span("gate"):
-                if cache.ref_mode == "packed":
-                    gate, windows = kops.tile_delta_gate(
-                        xp, cache.ref_win, idx, t, t, qstep=qstep,
-                        block=self.block)
-                else:
-                    gate = kops.tile_delta_gate_canvas(
-                        xp, cache.ref_canvas, idx, t, t, qstep=qstep,
-                        block=self.block)
-                    windows = None
+                gate = kops.tile_delta_gate_canvas(
+                    xp, cache.ref_canvas, idx, t, t, qstep=qstep,
+                    block=self.block)
             with obs_trace.span("gate_readback") as sp:
                 s = np.asarray(gate)
                 sp.set(bytes=s.nbytes)
@@ -1042,21 +885,20 @@ class RoIDetector:
                 stats = ReuseStats(n, int(raw.sum()), n_changed, k,
                                    k_pad, cold=False, gate_stats=s,
                                    canvas_bytes=m * tile_bytes)
-                # advance the references of the REFRESHED tiles —
-                # packed mode row-for-row from the gate's own windows
-                # output, canvas mode by masked window-region writes
-                # from the current frame (threshold 0 advances every
-                # row: previous-frame semantics, one free assignment)
+                # advance the references of the REFRESHED tiles by
+                # masked window-region writes from the current frame
+                # (threshold 0 advances every row: previous-frame
+                # semantics, one free assignment)
                 adv = ref_advance_rows(threshold, cache.idx_np[:, 0],
                                        changed, cache.cls_np)
-                _advance_refs(cache, xp, adv, windows, t)
+                _advance_refs(cache, xp, adv, t)
             else:
                 # ALL-STATIC: the gate dispatch is the whole step — no
                 # conv, no scatter, the canvas is served as-is with 0
                 # bytes written
                 adv = ref_advance_rows(threshold, cache.idx_np[:, 0],
                                        np.zeros(n, bool), cache.cls_np)
-                _advance_refs(cache, xp, adv, windows, t)
+                _advance_refs(cache, xp, adv, t)
                 stats = ReuseStats(n, int(raw.sum()), 0, 0, 0,
                                    cold=False, gate_stats=s,
                                    canvas_bytes=0)

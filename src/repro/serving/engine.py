@@ -21,7 +21,6 @@ Plain text serving works through the same engine with roi_sparsity=False.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -79,12 +78,12 @@ def _round_up(x: int, block: int) -> int:
 
 
 def ring_donate_argnums(*argnums: int) -> Tuple[int, ...]:
-    """The donation idiom shared by every persistent device-resident ring
-    in the system (the engine's group-cache ring, the detector's head-map
-    canvas): donate the named positional args so on hardware the update
-    is in-place (O(written) traffic, not O(buffer)), but donate NOTHING
-    on CPU — the CPU backend ignores donation and warns, and tests read
-    pre-update buffers the donation would have poisoned."""
+    """The donation idiom of the engine's persistent device-resident
+    group-cache ring: donate the named positional args so on hardware
+    the update is in-place (O(written) traffic, not O(buffer)), but
+    donate NOTHING on CPU — the CPU backend ignores donation and warns,
+    and tests read pre-update buffers the donation would have
+    poisoned."""
     return () if jax.default_backend() == "cpu" else tuple(argnums)
 
 

@@ -281,9 +281,8 @@ def test_a_backbone_refuses_what_it_cannot_run():
     with pytest.raises(ValueError):
         det.fleet_forward_reuse([jnp.zeros((60, 64, 3))], grids,
                                 PackedActivationCache())
-    for path in (det.fleet_forward, det.fleet_forward_layers):
-        with pytest.raises(ValueError):
-            path([jnp.zeros((64, 64, 3))], grids)
+    with pytest.raises(ValueError):
+        det.fleet_forward([jnp.zeros((64, 64, 3))], grids)
     for path in (det.dense_forward, det.roi_forward):
         with pytest.raises(ValueError):
             path(jnp.zeros((64, 64, 3)), grids[0])

@@ -1,8 +1,9 @@
 """Persistent output canvas: changed-only scatter == composite scatter
 (property, incl. empty/full extremes and a drift re-solve shrinking the
-active set mid-sequence), canvas-resident references bit-equivalent to
-the packed-window oracle at every threshold, zero-copy all-static
-steps, per-tile epoch tracking, and per-tile-class gate thresholds."""
+active set mid-sequence), canvas-resident references held to a model of
+private per-tile reference windows at every threshold, zero-copy
+all-static steps, per-tile epoch tracking, and per-tile-class gate
+thresholds."""
 import numpy as np
 import pytest
 
@@ -14,7 +15,7 @@ from hypothesis import given, settings, strategies as st
 from repro.fleet import sharded_fleet_step
 from repro.fleet.runtime import fleet_reuse_step
 from repro.fleet.sharded import ShardedSuperlaunch
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.launch.mesh import make_fleet_mesh
 from repro.serving.detector import (DetectorConfig, N_TILE_CLASSES,
                                     PackedActivationCache, RoIDetector,
@@ -111,7 +112,7 @@ def test_empty_compute_set_short_circuits_to_zero_dispatches():
 
 
 # ---------------------------------------------------------------------------
-# canvas-resident references == packed-window oracle, every threshold
+# canvas-resident references == private per-tile windows, every threshold
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -139,50 +140,136 @@ def _as_jnp(frames):
     return {g: [jnp.asarray(f) for f in fs] for g, fs in frames.items()}
 
 
+class _PrivateWindowGate:
+    """The gate's reference semantics in numpy: every active tile keeps
+    a PRIVATE copy of its haloed (t+2, t+2, 3) entry window as of its
+    last refresh, priced with ``ref.tile_delta_gate`` (column 4, the
+    exact change count, at threshold <= 0; column 5, the window byte
+    estimate, above), and advanced when the tile refreshes: every tile
+    at threshold <= 0, else the changed-output tiles.  The changed set
+    grows by the receptive field's rings over each camera's tile grid,
+    and as many again for the compute margin."""
+
+    def __init__(self, grids, t, rings):
+        self.grids, self.t, self.rings = grids, t, rings
+        self.idx, _ = ops.fleet_indices(grids)
+        self.pos = {tuple(r): i for i, r in enumerate(self.idx.tolist())}
+        self.win = None
+
+    def _stack(self, frames):
+        t = self.t
+        h = max(max(f.shape[0] for f in frames),
+                max(g.shape[0] * t for g in self.grids))
+        w = max(max(f.shape[1] for f in frames),
+                max(g.shape[1] * t for g in self.grids))
+        x = np.zeros((len(frames), h, w, 3), np.float32)
+        for c, f in enumerate(frames):
+            x[c, :f.shape[0], :f.shape[1]] = f
+        return x
+
+    def _windows(self, x):
+        t = self.t
+        xp = np.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
+        return np.stack([xp[c, ty * t:ty * t + t + 2, tx * t:tx * t + t + 2]
+                         for c, ty, tx in self.idx])
+
+    def _grow(self, rows, times):
+        out = rows.copy()
+        for _ in range(times):
+            grown = out.copy()
+            for i, (c, ty, tx) in enumerate(self.idx.tolist()):
+                if out[i]:
+                    for dy in (-1, 0, 1):
+                        for dx in (-1, 0, 1):
+                            j = self.pos.get((c, ty + dy, tx + dx))
+                            if j is not None:
+                                grown[j] = True
+            out = grown
+        return out
+
+    def step(self, frames, threshold):
+        """frames: the flat camera list -> (raw_changed, changed_out,
+        computed) of a warm step, or None on the cold (first) step."""
+        x = self._stack(frames)
+        cur = self._windows(x)
+        if self.win is None:
+            self.win = cur
+            return None
+        t = self.t
+        stats = np.zeros((len(self.idx), 8), np.int64)
+        for i, (c, ty, tx) in enumerate(self.idx):
+            # the private window as a one-tile "previous frame": the
+            # current frame with this tile's window region replaced
+            prev = np.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
+            prev[c, ty * t:ty * t + t + 2, tx * t:tx * t + t + 2] = \
+                self.win[i]
+            stats[i] = ref.tile_delta_gate(x, prev[:, 1:-1, 1:-1],
+                                           self.idx[i:i + 1], t, t)[0]
+        raw = (stats[:, 4] > 0 if threshold <= 0
+               else stats[:, 5] > threshold)
+        changed = self._grow(raw, self.rings)
+        compute = self._grow(changed, self.rings)
+        adv = np.ones_like(raw) if threshold <= 0 else changed
+        self.win[adv] = cur[adv]
+        return int(raw.sum()), int(changed.sum()), int(compute.sum())
+
+
 @pytest.mark.parametrize("threshold", [0.0, 40.0, 1e9])
 def test_ref_modes_bit_equal_at_every_threshold(det, threshold):
-    """Canvas-resident references + epoch tracking serve BIT-identical
-    heads to the legacy packed-window path at exact (0), lossy (40
-    bytes) and everything-reused (1e9) thresholds, over a trace whose
-    motion stays in tile interiors (the regime where the two reference
-    layouts are defined to agree) plus all-static repeats."""
+    """The canvas-resident references + epoch tracking gate exactly as
+    private per-tile reference windows would (``_PrivateWindowGate``) at
+    exact (0), lossy (40 bytes) and everything-reused (1e9) thresholds,
+    over a trace whose motion stays in tile interiors (the regime where
+    a shared canvas and private windows are defined to agree) plus
+    all-static repeats.  At 0 the heads equal a full recompute; at 1e9
+    they stay the cold step's."""
     t = det.cfg.tile
     rng = _rng(3)
     frames, grids = _mk_fleet(rng, t, {0: [(3, 4), (2, 2)], 1: [(4, 3)]})
-    c_canvas = PackedActivationCache(ref_mode="canvas")
-    c_packed = PackedActivationCache(ref_mode="packed")
+    cache = PackedActivationCache()
+    model = _PrivateWindowGate([g for gs in grids.values() for g in gs], t,
+                               ops.halo_rings(det.layers, t))
     cur = frames
-    for step in range(6):
+    cold_heads = None
+    for step in range(8):
         if step % 3 == 2:
             pass                                # all-static repeat
         else:
             cur = {g: [f.copy() for f in fs] for g, fs in cur.items()}
             gid = int(rng.integers(2))
             f = cur[gid][0]
-            ty = int(rng.integers(f.shape[0] // t))
-            tx = int(rng.integers(f.shape[1] // t))
-            # interior bump: the tile's rim pixels stay bit-static
+            # an active tile of a few, so that bumps land on tiles again
+            # and sub-threshold drift accumulates against a reference
+            act = np.argwhere(grids[gid][0])[:4]
+            ty, tx = act[int(rng.integers(len(act)))]
+            # interior bump: the tile's rim pixels stay bit-static; its
+            # window byte estimate lands on both sides of 40 bytes
             f[ty * t + 2:ty * t + t - 2,
-              tx * t + 2:tx * t + t - 2, :] += \
+              tx * t + 2:tx * t + t - 2, :] += 4 * \
                 rng.normal(size=(t - 4, t - 4, 3)).astype(np.float32)
-        got_c, _, st_c = fleet_reuse_step(det, _as_jnp(cur), grids,
-                                          c_canvas, threshold)
-        got_p, _, st_p = fleet_reuse_step(det, _as_jnp(cur), grids,
-                                          c_packed, threshold)
-        assert (st_c.raw_changed, st_c.computed) == \
-            (st_p.raw_changed, st_p.computed)
-        for gid in grids:
-            for a, b in zip(got_c[gid], got_p[gid]):
-                np.testing.assert_array_equal(np.asarray(a),
-                                              np.asarray(b))
-        if threshold == 0.0 and step > 0:
+        got, _, st = fleet_reuse_step(det, _as_jnp(cur), grids, cache,
+                                      threshold)
+        expect = model.step([f for fs in cur.values() for f in fs],
+                            threshold)
+        if expect is None:
+            assert st.cold
+            cold_heads = {g: [np.asarray(h) for h in hs]
+                          for g, hs in got.items()}
+            continue
+        assert (st.raw_changed, st.changed_out, st.computed) == expect
+        if threshold == 0.0:
             # threshold 0 == full recompute, bit for bit
             for gid in grids:
-                legacy = det.fleet_forward_layers(
+                full = det.fleet_forward(
                     [jnp.asarray(f) for f in cur[gid]], grids[gid])
-                for a, b in zip(got_c[gid], legacy):
+                for a, b in zip(got[gid], full):
                     np.testing.assert_array_equal(np.asarray(a),
                                                   np.asarray(b))
+        if threshold == 1e9:
+            assert st.computed == 0
+            for gid in grids:
+                for a, b in zip(got[gid], cold_heads[gid]):
+                    np.testing.assert_array_equal(np.asarray(a), b)
 
 
 def test_epoch_tracking_advances_only_refreshed_tiles(det):
@@ -289,7 +376,7 @@ def test_drift_shrink_does_not_leak_stale_canvas(det):
     small[0][0][0, 0] = True
     got, _, st = fleet_reuse_step(det, _as_jnp(frames), small, cache)
     assert st.cold
-    legacy = det.fleet_forward_layers(
+    legacy = det.fleet_forward(
         [jnp.asarray(f) for f in frames[0]], small[0])
     np.testing.assert_array_equal(np.asarray(got[0][0]),
                                   np.asarray(legacy[0]))

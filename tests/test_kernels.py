@@ -79,8 +79,15 @@ def test_sbnet_gather_property(seed):
 
 
 # ---------------------------------------------------------------------------
-# roi conv
+# roi conv: the entry kernel (gather + 3x3 conv + ReLU) vs relu(ref.roi_conv)
 # ---------------------------------------------------------------------------
+
+def _entry(x, w, idx, th, tw, block=1):
+    """``ops.roi_conv_entry`` on one (H, W, Cin) frame and (n, 2) tile
+    coords (camera 0 of a one-camera stack)."""
+    idx3 = jnp.concatenate([jnp.zeros_like(idx[:, :1]), idx], axis=1)
+    return ops.roi_conv_entry(x[None], w, idx3, th, tw, block=block)
+
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
                                        (jnp.bfloat16, 0.15)])
@@ -95,8 +102,9 @@ def test_roi_conv_sweep(dtype, tol, th, tw, Cin, Cout):
     x = jnp.asarray(rng.normal(size=(H, W, Cin)), dtype)
     w = jnp.asarray(rng.normal(size=(3, 3, Cin, Cout)) * 0.2, dtype)
     idx = jnp.asarray(np.array([[0, 0], [1, 2], [2, 3], [1, 1]], np.int32))
-    out = ops.roi_conv(x, w, idx, th, tw)
-    expect = ref.roi_conv(x, w, idx, th, tw)
+    out = _entry(x, w, idx, th, tw, block=2)
+    expect = jax.nn.relu(ref.roi_conv(x, w, idx, th, tw))
+    assert out.dtype == x.dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32),
                                atol=tol, rtol=tol)
@@ -110,23 +118,30 @@ def test_roi_conv_interior_tile_matches_dense():
     x = jnp.asarray(rng.normal(size=(48, 48, 4)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(3, 3, 4, 4)) * 0.3, jnp.float32)
     idx = jnp.asarray(np.array([[1, 1]], np.int32))
-    out = ops.roi_conv(x, w, idx, th, tw)[0]
-    dense = jax.lax.conv_general_dilated(
+    out = _entry(x, w, idx, th, tw)[0]
+    dense = jax.nn.relu(jax.lax.conv_general_dilated(
         x[None], w, (1, 1), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))[0]
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))[0])
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(dense[16:32, 16:32]), atol=2e-4)
 
 
 def test_roi_conv_batched():
+    """A batch of frames with one shared active set is one entry launch
+    over the stacked frames, camera by camera."""
     rng = _rng(11)
     x = jnp.asarray(rng.normal(size=(2, 32, 32, 4)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(3, 3, 4, 8)) * 0.2, jnp.float32)
     idx = jnp.asarray(np.array([[0, 0], [1, 1]], np.int32))
-    out = ops.roi_conv_batched(x, w, idx, 16, 16)
+    idx3 = jnp.asarray(np.array([[b, 0, 0] for b in range(2)]
+                                + [[b, 1, 1] for b in range(2)], np.int32))
+    with ops.count_kernels() as c:
+        out = ops.roi_conv_entry(x, w, idx3, 16, 16, block=4)
+    assert dict(c) == {"roi_conv_entry": 1}
+    out = out.reshape(2, 2, 16, 16, 8).transpose(1, 0, 2, 3, 4)
     assert out.shape == (2, 2, 16, 16, 8)
     for b in range(2):
-        expect = ref.roi_conv(x[b], w, idx, 16, 16)
+        expect = jax.nn.relu(ref.roi_conv(x[b], w, idx, 16, 16))
         np.testing.assert_allclose(np.asarray(out[b]), np.asarray(expect),
                                    atol=1e-4)
 

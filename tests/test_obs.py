@@ -139,7 +139,7 @@ def test_kernel_dispatch_mirror_bitmatches_legacy_counter():
 PANEL_KEYS = frozenset({
     "tile_delta_dispatches", "tile_delta_bit_exact",
     "tile_delta_static_frac", "roi_conv_interior_err",
-    "roi_conv_checked_tiles", "roi_conv_batched",
+    "roi_conv_checked_tiles",
     # ops.__all__ export: the canvas-reference gate variant dispatches
     # under the ONE "tile_delta_gate" counter (structurally the same
     # gate), so its function name is not itself a counter

@@ -60,11 +60,16 @@ def test_pack_non_multiple_block_positions_monotone():
 
 
 # ---------------------------------------------------------------------------
-# packed-resident conv
+# packed-resident conv: one layer of ``roi_conv_stack`` (conv + ReLU)
 # ---------------------------------------------------------------------------
 
+def _one_layer(packed, w, nbr):
+    return ops.roi_conv_stack(packed, [w], nbr, block=4)
+
+
 def test_roi_conv_packed_matches_scatter_oracle():
-    """Packed chain == scatter-to-zeros -> conv -> gather, any mask."""
+    """A packed layer == relu(scatter-to-zeros -> conv -> gather)
+    (``ref.roi_conv_packed``), any mask."""
     rng = _rng(1)
     grid = rng.random((5, 7)) < 0.45
     grid[2, 3] = True
@@ -74,8 +79,9 @@ def test_roi_conv_packed_matches_scatter_oracle():
     packed = jnp.asarray(rng.normal(size=(idx.shape[0], th, tw, 4)),
                          jnp.float32)
     w = jnp.asarray(rng.normal(size=(3, 3, 4, 6)) * 0.2, jnp.float32)
-    out = ops.roi_conv_packed(packed, w, nbr)
-    expect = ref.roi_conv_packed(packed, jnp.asarray(idx), grid.shape, w)
+    out = _one_layer(packed, w, nbr)
+    expect = jax.nn.relu(ref.roi_conv_packed(packed, jnp.asarray(idx),
+                                             grid.shape, w))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-4)
 
@@ -94,10 +100,10 @@ def test_roi_conv_packed_interior_matches_dense():
     # dense oracle over the scattered frame
     base = jnp.zeros((32, 32, 4), jnp.float32)
     full = ref.sbnet_scatter(packed, jnp.asarray(idx), base, th, tw)
-    dense = jax.lax.conv_general_dilated(
+    dense = jax.nn.relu(jax.lax.conv_general_dilated(
         full[None], w, (1, 1), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))[0]
-    out = ops.roi_conv_packed(packed, w, nbr)
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))[0])
+    out = _one_layer(packed, w, nbr)
     slot = {(int(y), int(x)): i for i, (y, x) in enumerate(idx)}
     i11 = slot[(1, 1)]
     np.testing.assert_allclose(np.asarray(out[i11]),
@@ -115,12 +121,12 @@ def test_roi_conv_packed_isolated_tile_zero_halo():
     th = tw = 8
     packed = jnp.asarray(rng.normal(size=(1, th, tw, 3)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(3, 3, 3, 5)) * 0.3, jnp.float32)
-    out = ops.roi_conv_packed(packed, w, jnp.asarray(nbr_np))
+    out = _one_layer(packed, w, jnp.asarray(nbr_np))
     # oracle: zero-pad the lone tile and convolve
     xp = jnp.pad(packed[0], ((1, 1), (1, 1), (0, 0)))
-    expect = jax.lax.conv_general_dilated(
+    expect = jax.nn.relu(jax.lax.conv_general_dilated(
         xp[None], w, (1, 1), "VALID",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))[0]
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))[0])
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(expect),
                                atol=1e-4)
 
@@ -156,7 +162,6 @@ def test_roi_forward_one_gather_one_scatter():
     assert counts.get("roi_conv_stack", 0) == 1      # ALL remaining layers
     assert counts.get("sbnet_scatter", 0) == 1       # the scatter
     assert counts.get("sbnet_gather", 0) == 0        # no per-layer re-slice
-    assert counts.get("roi_conv_packed", 0) == 0     # no per-layer launches
     assert sum(counts.values()) <= 3                 # constant dispatches
     # packed output matches the dense path on interior tiles to <= 1e-4
     dense = det.dense_forward(x)
@@ -181,8 +186,9 @@ def test_roi_forward_one_gather_one_scatter():
 
 
 def test_roi_forward_matches_legacy_scatter_chain():
-    """The packed chain must equal the old per-layer scatter/gather chain
-    on EVERY tile (inactive-neighbor halos are zero in both regimes)."""
+    """The packed chain must equal the per-layer scatter/gather chain of
+    the ``ref.py`` oracles on EVERY tile (inactive-neighbor halos are
+    zero in both regimes)."""
     det = RoIDetector(DetectorConfig(), jax.random.PRNGKey(1))
     rng = _rng(5)
     gy, gx = 4, 5
@@ -190,15 +196,14 @@ def test_roi_forward_matches_legacy_scatter_chain():
     grid[2, 2] = True
     x = jnp.asarray(rng.normal(size=(gy * 16, gx * 16, 3)), jnp.float32)
     roi = det.roi_forward(x, grid)
-    # legacy chain: per-layer fused conv + full-frame scatter
+    # legacy chain: per-layer conv + gather, full-frame scatter
     idx = jnp.asarray(ops.mask_to_indices(grid))
     t = det.cfg.tile
     xl = x
     for w in det.weights:
-        packed = ops.roi_conv(xl, w, idx, t, t)
-        packed = jax.nn.relu(packed)
+        packed = jax.nn.relu(ref.roi_conv(xl, w, idx, t, t))
         base = jnp.zeros(x.shape[:2] + (w.shape[-1],), packed.dtype)
-        xl = ops.sbnet_scatter(packed, idx, base)
+        xl = ref.sbnet_scatter(packed, idx, base, t, t)
     legacy = xl @ det.head
     np.testing.assert_allclose(np.asarray(roi), np.asarray(legacy),
                                atol=1e-4)

@@ -38,6 +38,9 @@ def _fleet_pack(rng, shapes, density=0.5):
 
 @pytest.mark.parametrize("qstep", [8.0, 2.0, 16.0])
 def test_tile_delta_gate_bit_exact_vs_reference(qstep):
+    """The served gate, ``tile_delta_gate_canvas`` with the previous
+    frame as its reference canvas, equals ``ref.tile_delta_gate`` bit
+    for bit, at one tile and at four tiles a grid step."""
     rng = _rng(1)
     th = tw = 8
     grids, idx, _ = _fleet_pack(rng, [(4, 5), (3, 3)])
@@ -45,19 +48,13 @@ def test_tile_delta_gate_bit_exact_vs_reference(qstep):
     prev = cur + (rng.random(cur.shape) < 0.02) * \
         rng.normal(size=cur.shape).astype(np.float32) * 20
     prev = prev.astype(np.float32)
-    pad = ((0, 0), (1, 1), (1, 1), (0, 0))
-    cur_p = jnp.asarray(np.pad(cur, pad))
-    ref_win = ops.gather_windows(jnp.asarray(np.pad(prev, pad)),
-                                 jnp.asarray(idx), th, tw)
-    stats, wins = ops.tile_delta_gate(cur_p, ref_win, jnp.asarray(idx),
-                                      th, tw, qstep=qstep)
+    cur_p = ops.pad_frames(jnp.asarray(cur), tw)
+    ref_c = ops.pad_frames(jnp.asarray(prev), tw)
     expect = ref.tile_delta_gate(cur, prev, idx, th, tw, qstep=qstep)
-    np.testing.assert_array_equal(np.asarray(stats), expect)
-    # the windows output IS the current packed windows (the reference
-    # advance source)
-    np.testing.assert_array_equal(
-        np.asarray(wins),
-        np.asarray(ops.gather_windows(cur_p, jnp.asarray(idx), th, tw)))
+    for block in (1, 4):
+        stats = ops.tile_delta_gate_canvas(cur_p, ref_c, jnp.asarray(idx),
+                                           th, tw, qstep=qstep, block=block)
+        np.testing.assert_array_equal(np.asarray(stats), expect)
 
 
 def test_tile_delta_gate_body_cols_match_tile_delta():
@@ -88,12 +85,12 @@ def test_tile_delta_gate_sees_inactive_neighbor_halo_change():
     cur = np.zeros((1, 3 * th, 3 * tw, 2), np.float32)
     prev = cur.copy()
     prev[0, th - 1, tw + 3, 0] = 7.0       # inactive N tile, bottom row
-    pad = ((0, 0), (1, 1), (1, 1), (0, 0))
-    ref_win = ops.gather_windows(jnp.asarray(np.pad(prev, pad)),
-                                 jnp.asarray(idx), th, tw)
-    out, _ = ops.tile_delta_gate(jnp.asarray(np.pad(cur, pad)), ref_win,
-                                 jnp.asarray(idx), th, tw)
+    out = ops.tile_delta_gate_canvas(ops.pad_frames(jnp.asarray(cur), tw),
+                                     ops.pad_frames(jnp.asarray(prev), tw),
+                                     jnp.asarray(idx), th, tw)
     out = np.asarray(out)
+    np.testing.assert_array_equal(out,
+                                  ref.tile_delta_gate(cur, prev, idx, th, tw))
     assert out[0, ops.GATE_WIN_EXACT] == 1     # window sees it
     assert out[0, 1] == 0                      # body nnz does not
 
@@ -322,7 +319,7 @@ def _as_jnp(frames):
 def test_reuse_threshold0_bitwise_on_ragged_fleet_trace():
     """The acceptance contract: over a trace of sparse changes on a
     ragged multi-group fleet, every step's outputs are bit-identical to
-    ``fleet_forward_layers`` full recompute, while convolving only the
+    ``fleet_forward`` full recompute, while convolving only the
     dilated changed sets."""
     det = RoIDetector(DetectorConfig(), jax.random.PRNGKey(0))
     rng = _rng(8)
@@ -338,7 +335,7 @@ def test_reuse_threshold0_bitwise_on_ragged_fleet_trace():
         outs, counts, st = fleet_reuse_step(det, _as_jnp(cur), grids,
                                             cache)
         for gid in grids:
-            legacy = det.fleet_forward_layers(
+            legacy = det.fleet_forward(
                 [jnp.asarray(f) for f in cur[gid]], grids[gid])
             for a, b in zip(outs[gid], legacy):
                 assert (np.asarray(a) == np.asarray(b)).all(), \
@@ -398,7 +395,7 @@ def test_dilation_never_leaks_across_cameras_or_groups():
     n0 = int(np.count_nonzero(grids[0][0]))
     assert st.computed <= n0, "dilation leaked past the changed camera"
     for gid in grids:
-        legacy = det.fleet_forward_layers(
+        legacy = det.fleet_forward(
             [jnp.asarray(f) for f in cur[gid]], grids[gid])
         for a, b in zip(outs[gid], legacy):
             assert (np.asarray(a) == np.asarray(b)).all()

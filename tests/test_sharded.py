@@ -21,13 +21,14 @@ import jax
 from hypothesis import given, settings, strategies as st
 
 from repro.fleet import sharded_fleet_step, wire_shard_invalidation
-from repro.fleet.sharded import AsyncShardedPipeline, ShardedSuperlaunch
+from repro.fleet.sharded import (AsyncShardedPipeline,
+                                 ShardedActivationCache, ShardedSuperlaunch)
 from repro.kernels import ops
 from repro.launch.mesh import make_fleet_mesh
 from repro.net.batcher import DeadlineGroupFormer
 from repro.net.encoder import gate_threshold_schedule
 from repro.serving.detector import (DetectorConfig, PackedActivationCache,
-                                    RoIDetector, ShardedActivationCache)
+                                    RoIDetector)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -278,7 +279,7 @@ def test_count_kernels_regions_are_thread_isolated():
     gate = threading.Barrier(4)
     # distinct CANONICAL names (record_dispatch validates against
     # obs.metrics.KERNEL_NAMES), one per worker thread
-    names = ["sbnet_gather", "roi_conv", "tile_delta", "roi_attention"]
+    names = ["sbnet_gather", "roi_conv_stage", "tile_delta", "roi_attention"]
 
     def worker(name, n):
         try:

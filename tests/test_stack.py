@@ -1,9 +1,10 @@
 """One-launch fleet backbone: the fused layer-stack megakernel, coalesced
 rim halos, the cross-group super-launch, and the per-grid digest cache.
 
-The contract everywhere is BIT-identity with the per-layer / per-group
-chain (``roi_conv_packed`` rounds, per-group ``fleet_forward``): the
-fused path changes the dispatch structure, never the math.
+The contract everywhere is BIT-identity with successive one-layer
+launches and per-group ``fleet_forward``: the fused path changes the
+dispatch structure, never the math; the detector paths are held to the
+plain ``jax.numpy`` reference ``dense_forward``.
 """
 import jax
 import jax.numpy as jnp
@@ -30,13 +31,21 @@ def _mk_group(rng, shapes, t, ensure=True):
 
 
 # ---------------------------------------------------------------------------
-# the megakernel alone: bitwise vs the per-layer packed chain
+# the megakernel alone: bitwise vs one launch per layer
 # ---------------------------------------------------------------------------
+
+def _layer_by_layer(packed, ws, nbr, block=16):
+    """The chain as successive one-layer ``roi_conv_stack`` launches."""
+    for w in ws:
+        packed = ops.roi_conv_stack(packed, [w], nbr, block=block)
+    return packed
+
 
 @pytest.mark.parametrize("chans", [(3, 4, 6, 6, 5), (3, 8), (3, 5, 7)])
 def test_stack_kernel_bitwise_vs_per_layer_chain(chans):
-    """roi_conv_stack == relu(roi_conv_packed(...)) rounds, bit for bit,
-    including ragged channel widths across layers."""
+    """One ``roi_conv_stack`` launch == successive one-layer launches,
+    bit for bit, including ragged channel widths across layers; the
+    entry kernel's blocked walk == its one-tile walk."""
     rng = _rng(1)
     th = tw = 8
     grids = [rng.random((4, 5)) < 0.5, rng.random((3, 3)) < 0.4]
@@ -50,18 +59,16 @@ def test_stack_kernel_bitwise_vs_per_layer_chain(chans):
     ws = [jnp.asarray(rng.normal(size=(3, 3, ci, co)) * 0.3, jnp.float32)
           for ci, co in zip(chans[:-1], chans[1:])]
 
-    legacy = jax.nn.relu(ops.roi_conv_fleet(x, ws[0], idx, th, tw))
-    p0 = ops.roi_conv_entry(x, ws[0], idx, th, tw)
-    assert (np.asarray(p0) == np.asarray(legacy)).all(), \
-        "entry kernel must equal relu(roi_conv_fleet)"
+    p0 = ops.roi_conv_entry(x, ws[0], idx, th, tw, block=1)
+    p4 = ops.roi_conv_entry(x, ws[0], idx, th, tw, block=4)
+    assert (np.asarray(p4) == np.asarray(p0)).all(), \
+        "the entry kernel's blocked walk must equal its one-tile walk"
     if len(ws) == 1:
         return
-    for w in ws[1:]:
-        legacy = jnp.asarray(jax.nn.relu(ops.roi_conv_packed(legacy, w,
-                                                             nbr)))
     fused = ops.roi_conv_stack(p0, ws[1:], nbr)
+    legacy = _layer_by_layer(p0, ws[1:], nbr)
     assert (np.asarray(fused) == np.asarray(legacy)).all(), \
-        "megakernel must be bit-identical to the per-layer chain"
+        "megakernel must be bit-identical to one launch per layer"
 
 
 def test_stack_matches_scatter_conv_oracle():
@@ -91,7 +98,7 @@ def test_stack_matches_scatter_conv_oracle():
 @pytest.mark.parametrize("block", [1, 3, 16, 256])
 def test_stack_block_raggedness_bitwise(block):
     """Any tile-block size (including non-dividing and over-sized ones)
-    keeps the megakernel bit-identical to the per-layer chain."""
+    keeps the megakernel bit-identical to one launch per layer."""
     rng = _rng(3)
     th = tw = 8
     grid = rng.random((5, 7)) < 0.45
@@ -104,17 +111,22 @@ def test_stack_block_raggedness_bitwise(block):
     ws = [jnp.asarray(rng.normal(size=(3, 3, 4, 6)) * 0.2, jnp.float32),
           jnp.asarray(rng.normal(size=(3, 3, 6, 5)) * 0.2, jnp.float32)]
     fused = ops.roi_conv_stack(packed, ws, nbr, block=block)
-    legacy = packed
-    for w in ws:
-        legacy = jax.nn.relu(ops.roi_conv_packed(legacy, w, nbr))
+    legacy = _layer_by_layer(packed, ws, nbr)
     assert (np.asarray(fused) == np.asarray(legacy)).all()
 
 
 # ---------------------------------------------------------------------------
-# detector paths: fused == per-layer == per-camera
+# detector paths: fused == the plain jax.numpy reference == per-camera
 # ---------------------------------------------------------------------------
 
+# float32 tolerance of the fused chain against ``dense_forward``: both
+# sum the same products in float32, in different orders
+DENSE_TOL = 1e-5
+
+
 def test_roi_forward_bitwise_vs_per_layer_path():
+    """``roi_forward`` equals ``dense_forward(x, grid)`` on every pixel,
+    the zero outside the mask included."""
     det = RoIDetector(DetectorConfig(), jax.random.PRNGKey(0))
     rng = _rng(4)
     t = det.cfg.tile
@@ -122,8 +134,9 @@ def test_roi_forward_bitwise_vs_per_layer_path():
     grid[2, 2] = True
     x = jnp.asarray(rng.normal(size=(5 * t, 6 * t, 3)), jnp.float32)
     fused = det.roi_forward(x, grid)
-    layers = det.roi_forward_layers(x, grid)
-    assert (np.asarray(fused) == np.asarray(layers)).all()
+    dense = det.dense_forward(x, grid)
+    np.testing.assert_allclose(np.asarray(fused), np.asarray(dense),
+                               atol=DENSE_TOL, rtol=DENSE_TOL)
 
 
 def test_roi_forward_empty_mask_no_launches():
@@ -139,7 +152,7 @@ def test_roi_forward_empty_mask_no_launches():
 
 def test_fleet_forward_bitwise_vs_per_layer_fleet():
     """Unequal frame sizes + an empty-mask camera + a single-tile camera:
-    the fused chain equals the per-layer fleet chain bit for bit."""
+    every camera's heads equal ``dense_forward`` on that camera alone."""
     det = RoIDetector(DetectorConfig(), jax.random.PRNGKey(1))
     rng = _rng(5)
     t = det.cfg.tile
@@ -149,9 +162,10 @@ def test_fleet_forward_bitwise_vs_per_layer_fleet():
     grids[3][:] = False
     grids[3][4, 1] = True                   # single-tile camera
     fused = det.fleet_forward(frames, grids)
-    layers = det.fleet_forward_layers(frames, grids)
-    for o, l in zip(fused, layers):
-        assert (np.asarray(o) == np.asarray(l)).all()
+    for o, x, g in zip(fused, frames, grids):
+        np.testing.assert_allclose(np.asarray(o),
+                                   np.asarray(det.dense_forward(x, g)),
+                                   atol=DENSE_TOL, rtol=DENSE_TOL)
     # the empty-mask camera ships an all-zero head map
     assert float(jnp.abs(fused[2]).max()) == 0.0
 
